@@ -517,12 +517,12 @@ class ServingEngine:
         pool = P(None, None, None, self._tp_axis, None)
         in_specs = (self._target_param_specs(), pool, pool) + (P(),) * n_rest
         out_specs = (pool, pool) + (P(),) * n_out
-        # check_vma=False: replication of the sampled outputs holds by
-        # construction (inputs replicated, every cross-head contraction is
-        # psummed) but jax 0.4's check_rep can't always prove it
+        # check_vma=True: JAX proves what the P() out_specs claim — the
+        # sampled outputs are replicated because the inputs are and every
+        # cross-head contraction is psummed
         return compat.shard_map(
             fn, self._mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False)
+            check_vma=True)
 
     def _prefill_for(self, width: int, role: str = "target"):
         """The jitted prefill program for one bucket width, compiled on

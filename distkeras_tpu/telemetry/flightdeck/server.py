@@ -80,8 +80,8 @@ _LOCK = threading.Lock()
 _EXTRA: Dict[str, Callable] = {}
 
 # Free-form string/scalar vars surfaced under /vars "vars": the place for
-# one-off facts that are not metric-shaped (e.g. bench's
-# bench_backend_init_reason — *why* the device backend fell back).
+# one-off facts that are not metric-shaped (a reason string, a
+# configuration name).
 _VARS: Dict[str, object] = {}
 _VARS_LOCK = threading.Lock()
 

@@ -1,57 +1,25 @@
-"""JAX version compatibility shims.
+"""The one surface the engines write ``shard_map`` and ``axis_size`` against.
 
-The codebase targets current JAX, where ``shard_map`` is a top-level API
-taking ``check_vma`` and ``axis_names``.  On older installs (< 0.5) the same
-machinery lives in ``jax.experimental.shard_map`` with the previous spelling
-— ``check_rep``, and ``auto`` (the *complement* of the manual axis set).
-Every engine routes through this wrapper so the rest of the code is written
-against one surface.
+Thin pass-throughs to ``jax.shard_map`` and ``lax.axis_size``: the wrapper
+keeps every engine's call sites (and dkshape's resolution through them) on
+one name and one keyword spelling — ``axis_names=None`` means "manual over
+every mesh axis", which ``jax.shard_map`` spells by omitting the argument.
 """
 
 from __future__ import annotations
 
 import jax
+from jax import lax
 
 __all__ = ["shard_map", "axis_size"]
 
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma=True, axis_names=None):
-    if hasattr(jax, "shard_map"):
-        kwargs = {"check_vma": check_vma}
-        if axis_names:
-            kwargs["axis_names"] = axis_names
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    auto = (
-        frozenset(mesh.axis_names) - frozenset(axis_names)
-        if axis_names
-        else frozenset()
-    )
-    if auto:
-        # Partial-manual (some axes left to the partitioner) aborts the whole
-        # process on old XLA (hlo_sharding_util CHECK sharding.IsManualSubgroup
-        # on jaxlib 0.4.x) — the experimental ``auto=`` never hardened.  Fail
-        # as a catchable error instead of a SIGABRT that takes pytest with it.
-        raise NotImplementedError(
-            "partial-manual shard_map (axis_names=%r over mesh axes %r) "
-            "requires jax >= 0.5; this install's experimental 'auto=' path "
-            "crashes XLA" % (tuple(axis_names), tuple(mesh.axis_names))
-        )
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma, auto=auto,
-    )
+    kwargs = {"axis_names": axis_names} if axis_names else {}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kwargs)
 
 
 def axis_size(axis_name):
-    """Static size of a named mapped axis (``lax.axis_size`` on current JAX;
-    ``psum(1)`` over the axis on older installs, which XLA folds to the same
-    constant)."""
-    from jax import lax
-
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
+    """Static size of a named mapped axis."""
+    return lax.axis_size(axis_name)

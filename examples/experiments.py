@@ -114,6 +114,9 @@ def main():
     parser.add_argument("--cpu", type=int, default=0, metavar="N",
                         help="force an N-device CPU mesh (offline / no TPU)")
     args = parser.parse_args()
+    from distkeras_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache(os.path.join(os.path.dirname(__file__), ".."))
 
     import jax
 

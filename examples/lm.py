@@ -65,6 +65,9 @@ def main():
     parser = argparse.ArgumentParser()
     parser.add_argument("--epochs", type=int, default=12)
     args = parser.parse_args()
+    from distkeras_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache(os.path.join(os.path.dirname(__file__), ".."))
 
     import distkeras_tpu as dk
     from distkeras_tpu.models import FlaxModel, StagedLM, TransformerLM

@@ -54,6 +54,9 @@ def main():
                         help="pin the sklearn digits fallback regardless of "
                              "any cached MNIST (machine-independent runs)")
     args = parser.parse_args()
+    from distkeras_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache(os.path.join(os.path.dirname(__file__), ".."))
 
     import jax
 

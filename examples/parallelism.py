@@ -33,6 +33,9 @@ import numpy as np
 
 
 def main():
+    from distkeras_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache(os.path.join(os.path.dirname(__file__), ".."))
     import distkeras_tpu as dk
     from distkeras_tpu.models import FlaxModel, MLP, TransformerClassifier
 

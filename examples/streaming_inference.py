@@ -45,6 +45,9 @@ def main():
                         help="tcp://host:port of a running kafka_producer.py "
                              "(default: in-process producer thread)")
     args = parser.parse_args()
+    from distkeras_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache(os.path.join(os.path.dirname(__file__), ".."))
     import distkeras_tpu as dk
     from distkeras_tpu.models import MLP, FlaxModel
     from distkeras_tpu.predictors import ModelPredictor

@@ -12,10 +12,13 @@ setup(
     license="MIT",
     packages=find_packages(include=["distkeras_tpu", "distkeras_tpu.*"]),
     python_requires=">=3.10",
+    # the versions the code is written and tested against (utils/compat.py
+    # calls jax.shard_map / lax.axis_size directly — no older spelling)
     install_requires=[
-        "jax",
-        "flax",
-        "optax",
+        "jax>=0.9.0",
+        "jaxlib>=0.9.0",
+        "flax>=0.12.3",
+        "optax>=0.2.6",
         "numpy",
     ],
     extras_require={

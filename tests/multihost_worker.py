@@ -111,19 +111,11 @@ def main(coordinator: str, num_processes: int, process_id: int,
     import os
 
     devices_per_proc = 8 // num_processes
-    # set before the backend initialises; jax_num_cpu_devices is the
-    # modern spelling, XLA_FLAGS the fallback for older installs
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "")
-        + f" --xla_force_host_platform_device_count={devices_per_proc}"
-    )
     import jax
 
+    # set before the backend initialises
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", devices_per_proc)
-    except AttributeError:
-        pass  # XLA_FLAGS above already forces the device count
+    jax.config.update("jax_num_cpu_devices", devices_per_proc)
     jax.distributed.initialize(
         coordinator_address=coordinator,
         num_processes=num_processes,

@@ -1,17 +1,14 @@
-"""Accuracy-proof harness (examples/accuracy.py; VERDICT r2 item 4,
-hardened per VERDICT r3 item 1).
+"""Accuracy-proof harness (examples/accuracy.py): the proxy datasets.
 
-The real floors are enforced on the committed TPU artifact
-(ACCURACY_r05.json — ALL SIX trainer families on both benchmark-model
-proxies): this 1-core CI box cannot train CIFAR-scale convs in test time,
-so CI asserts (a) the proxy datasets are deterministic, class-informative,
-and GENUINELY HARD (their Bayes-style oracles land mid-80s/low-90s, so a
-saturated artifact would mean the task regressed to trivial), and (b) the
-committed artifact is non-saturated, complete, and within the async-gap
-bound — the discriminative "matched final accuracy" contract.
+The floors themselves (every trainer family within its gap of the
+SingleTrainer yardstick) are judged on a chip run of ``examples/accuracy.py``
+— CIFAR-scale convs do not train in test time on the CPU, and that run is
+not measured on today's code.  What the suite pins here is that the proxy
+datasets are deterministic, class-informative, and GENUINELY HARD (their
+Bayes-style oracles land mid-80s/low-90s, so a saturated accuracy table
+would mean the task regressed to trivial).
 """
 
-import json
 import os
 import sys
 
@@ -21,10 +18,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "..", "examples"))
 
 from accuracy import make_cifar_proxy, make_imdb_proxy
-
-ARTIFACT = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
-                        "ACCURACY_r05.json")
-
 
 def test_cifar_proxy_deterministic_and_shaped():
     x1, y1 = make_cifar_proxy(64, seed=0)
@@ -77,96 +70,3 @@ def test_imdb_proxy_counting_oracle_is_non_saturating():
     assert 0.15 < (other > 0).mean() < 0.75
     # every sequence plants at least one own-lexicon token
     assert own.min() >= 1
-
-
-TRAINERS = ("single", "single_momentum", "downpour", "aeasgd", "eamsgd",
-            "adag", "dynsgd")
-# SingleTrainer must sit in the discriminative band: high enough to prove
-# learning, below saturation so async gaps are measurable.
-SINGLE_BAND = (0.78, 0.97)
-MAX_GAP = 0.025  # VERDICT r3 item 1's bound, in accuracy points
-# Characterized exception (examples/accuracy.py::run_accuracy): AEASGD on
-# the sparse-embedding task.  Elastic coupling is the ONLY consensus force
-# (workers never pull — reference semantics), and across the probed surface
-# (rho 1-10, tau 1-16, adam lr 1e-3..3e-3, epochs 16..96, TPU round 5) its
-# center plateaus well under the adam single on imdb_proxy while MATCHING
-# single on the dense conv task.  These bounds are the regression guard on
-# that measured plateau (best e16 point: 0.7158, gap 0.0913) — they do NOT
-# relax the 2.5-point contract for any other family or dataset.
-AEASGD_IMDB_FLOOR = 0.68
-AEASGD_IMDB_MAX_GAP = 0.12
-# On imdb the whole momentum-SGD column (control AND eamsgd) is optimizer-
-# limited near chance — the control row documents that.  The gap bound alone
-# would then pass an eamsgd that learns NOTHING, so (a) the control must
-# itself prove learning on the dense task (band below), making the cifar
-# eamsgd cell a real learning proof, and (b) eamsgd/imdb gets a collapse
-# floor under its measured 0.4976.
-EAMSGD_IMDB_FLOOR = 0.45
-
-
-def test_accuracy_artifact_six_trainers_nonsaturated_and_gap_bounded():
-    """The committed TPU artifact: every trainer family, both datasets,
-    SingleTrainer off ceiling, every async trainer within 2.5 points of its
-    yardstick — the adam single for the adam-worker families, the
-    matched-optimizer momentum control for EAMSGD (whose momentum-SGD
-    worker's deficit on the embedding task is the optimizer's, not the
-    asynchrony's) — with AEASGD/imdb's characterized plateau guarded by
-    explicit floor+gap bounds instead of a widened contract."""
-    with open(ARTIFACT) as fh:
-        artifact = json.load(fh)
-    rows = {r["metric"]: r for r in artifact["results"]}
-    datasets = {r["dataset"] for r in rows.values()}
-    assert any(d.startswith("cifar") for d in datasets), datasets
-    assert any(d.startswith("imdb") for d in datasets), datasets
-    for dataset in datasets:
-        by_trainer = {r["trainer"]: r for r in rows.values()
-                      if r["dataset"] == dataset}
-        missing = [t for t in TRAINERS if t not in by_trainer]
-        assert not missing, f"{dataset}: no rows for {missing}"
-        single = by_trainer["single"]["value"]
-        control = by_trainer["single_momentum"]["value"]
-        assert SINGLE_BAND[0] <= single <= SINGLE_BAND[1], (
-            f"{dataset}: SingleTrainer {single} outside the discriminative "
-            f"band {SINGLE_BAND} — saturated artifacts can't detect "
-            "async-accuracy regressions"
-        )
-        if dataset.startswith("cifar"):
-            # the momentum control must itself learn the dense task, so the
-            # eamsgd-vs-control gap there is a real learning proof
-            assert SINGLE_BAND[0] <= control <= SINGLE_BAND[1], (
-                f"cifar momentum control {control} outside {SINGLE_BAND}"
-            )
-        for t in ("downpour", "adag", "dynsgd", "aeasgd", "eamsgd"):
-            row = by_trainer[t]
-            assert row.get("gap_to_single") is not None
-            gap = single - row["value"]
-            if t == "eamsgd":
-                # matched-optimizer yardstick; the artifact must carry the
-                # explicit control gap the bound is judged on
-                assert row.get("gap_to_control") is not None
-                gap_c = control - row["value"]
-                assert gap_c <= MAX_GAP, (
-                    f"{dataset}/eamsgd: {row['value']} is {gap_c:.4f} below "
-                    f"its momentum control {control}"
-                )
-                if dataset.startswith("imdb"):
-                    assert row["value"] >= EAMSGD_IMDB_FLOOR, (
-                        f"eamsgd/imdb collapsed: {row['value']} < "
-                        f"{EAMSGD_IMDB_FLOOR}"
-                    )
-            elif t == "aeasgd" and dataset.startswith("imdb"):
-                assert row["value"] >= AEASGD_IMDB_FLOOR, (
-                    f"aeasgd/imdb regressed below its characterized "
-                    f"plateau: {row['value']} < {AEASGD_IMDB_FLOOR}"
-                )
-                assert gap <= AEASGD_IMDB_MAX_GAP, (
-                    f"aeasgd/imdb gap {gap:.4f} exceeds the characterized "
-                    f"plateau bound {AEASGD_IMDB_MAX_GAP}"
-                )
-            else:
-                assert gap <= MAX_GAP, (
-                    f"{dataset}/{t}: accuracy {row['value']} is "
-                    f"{gap:.4f} below SingleTrainer's {single}"
-                )
-        for row in by_trainer.values():
-            assert row["backend"] == "tpu"

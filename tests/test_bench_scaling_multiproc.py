@@ -3,8 +3,8 @@
 VERDICT r3 item 5: the 8->64 harness had never executed multi-process, so
 the first pod attempt would have been its first run.  This launches bench.py
 itself (not a stub) in two jax.distributed processes over a combined
-8-device CPU mesh with rehearsal shapes: the full path — preflight
-skip, coordination-service join, global-mesh engines, per-point chip
+8-device CPU mesh with rehearsal shapes: the full path —
+coordination-service join, global-mesh engines, per-point chip
 counting, process-0-only printing — executes end to end.
 """
 

@@ -1,0 +1,88 @@
+"""The main path's Pallas kernels must compile for the chip they run on.
+
+The CPU suite runs the flash-attention kernel in interpret mode only, which
+checks its arithmetic but none of what the TPU compiler refuses (unaligned
+slices, too much VMEM, a kernel that cannot be batched).  The TPU compiler is
+installed without a chip attached, so these tests ask it directly: each case
+lowers the kernel for a *described* ``v5e:2x2`` device at a shape the LM path
+really uses and checks that a ``tpu_custom_call`` is in the compiled program.
+Nothing runs — results are ``chip_smoke.py``'s business.
+
+The topology is described inside a module-scoped fixture (never at import):
+only one process may hold the TPU library, and under ``pytest -n`` every
+worker imports this file but only one runs it.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from distkeras_tpu.ops.pallas import flash_attention
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe = skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described device is written to the persistent cache
+    # but cannot be read back without a chip; keep these compiles out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _kernel(q, k, v):
+    # interpret=False: the compiled kernel, whatever backend the test
+    # process itself sits on
+    return flash_attention(q, k, v, True, 256, 512, False)
+
+
+def _loss(q, k, v):
+    return _kernel(q, k, v).astype(jnp.float32).sum()
+
+
+def _compiled_text(fn, shape, dtype, sharding):
+    arg = jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    return jax.jit(fn).lower(arg, arg, arg).compile().as_text()
+
+
+# [batch, seq, heads, head_dim]: GPT-2 small's attention at chip_smoke's LM
+# batch in both dtypes, a 128-wide head, and two lengths the kernel must pad
+@pytest.mark.parametrize("shape,dtype", [
+    ((8, 1024, 12, 64), jnp.bfloat16),
+    ((8, 1024, 12, 64), jnp.float32),
+    ((2, 1024, 8, 128), jnp.bfloat16),
+    ((2, 100, 12, 64), jnp.bfloat16),
+    ((2, 48, 4, 32), jnp.float32),
+])
+def test_flash_attention_compiles_for_v5e(one_chip, shape, dtype):
+    fwd = _compiled_text(_kernel, shape, dtype, one_chip)
+    assert "tpu_custom_call" in fwd
+    bwd = _compiled_text(jax.grad(_loss, argnums=(0, 1, 2)), shape, dtype,
+                         one_chip)
+    # forward (recomputed residuals) + the dq and dk/dv kernels
+    assert bwd.count("tpu_custom_call") >= 3
+
+
+def test_flash_attention_compiles_under_vmap_for_v5e(one_chip):
+    """Virtual workers put the kernel under ``vmap`` (a leading worker axis)
+    inside the engine's shard_map and scan."""
+    shape = (2, 8, 1024, 12, 64)
+    fwd = _compiled_text(jax.vmap(_kernel), shape, jnp.bfloat16, one_chip)
+    assert "tpu_custom_call" in fwd
+    bwd = _compiled_text(jax.vmap(jax.grad(_loss, argnums=(0, 1, 2))), shape,
+                         jnp.bfloat16, one_chip)
+    assert bwd.count("tpu_custom_call") >= 3

@@ -1068,11 +1068,11 @@ def test_dk123_no_fp_and_suppression():
 
 
 def test_dk123_compat_partial_manual_fixture():
-    """The jax<0.5 shim's NotImplementedError, statically (satellite: the
-    pipeline x tensor-parallel composition documented in CHANGES PR 1)."""
+    """compat.shard_map sites get the direct call's axis checks, and a
+    partial-manual map (axis_names a strict subset of the mesh axes, the
+    pipeline x tensor-parallel composition) is valid: not a finding."""
     got, _ = _run("dk123_compat_partial.py", ["DK123"])
     assert got == [
-        ("DK123", 14),  # axis_names strict subset of mesh axes
         ("DK123", 37),  # compat path runs the same axis checks as direct
         ("DK123", 44),  # ... including through an import alias
     ]

@@ -15,9 +15,8 @@ import os
 import re
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-# mixed-case names too: `BENCH_full_r05.json` slipped through the original
-# all-caps pattern while PERF.md claimed it (exactly the r4 failure class
-# this file exists to catch)
+# mixed-case names too (a `BENCH_full_rNN` sweep file slips through an
+# all-caps pattern — exactly the failure class this file exists to catch)
 ARTIFACT_RE = re.compile(r"\b([A-Z][A-Za-z0-9_]*_r\d+\.json)\b")
 
 

@@ -608,8 +608,13 @@ def test_serve_tier_respawns_crashed_replicas_up_to_cap(punchcard):
 def test_serve_tier_idempotent_retry(punchcard, monkeypatch):
     """A lost serve_tier reply must not double-spawn the fleet: the retry
     replays the original tier (same id, same job_ids)."""
+    # The daemon replays a retry that comes AFTER it answered (a lost reply;
+    # ``job_deployment`` guards the sequential case only).  The injected drop
+    # fires as soon as the request is sent, so the backoff has to outlast the
+    # daemon's two process spawns: at 10 ms a loaded box let the retry in
+    # first and the tier was spawned twice.
     job = Job("127.0.0.1", punchcard.port, secret="s3cret",
-              script="import time\ntime.sleep(60)\n", rpc_backoff=0.01)
+              script="import time\ntime.sleep(60)\n", rpc_backoff=0.5)
     chaos.configure("5:drop_reply=1")
     tier_id = job.serve_tier(replicas=2)
     chaos.configure("")

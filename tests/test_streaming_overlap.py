@@ -18,8 +18,8 @@ estimate discriminates cleanly while tolerating host jitter.
 Sizing note: only *device compute* overlaps the source; the synchronous
 per-dispatch host work (~20 ms of jit-call machinery on this box) does not.
 The model/window here is sized so compute per window is ~10x the dispatch
-cost — the regime streaming is for (on TPU the imbalance is larger still:
-~2.4 ms dispatch vs arbitrarily large windows, PERF.md §8).
+cost — the regime streaming is for (on a chip the imbalance is larger
+still: a dispatch is milliseconds, a window as large as one likes).
 """
 
 import time
@@ -74,30 +74,38 @@ def test_streaming_overlaps_source_latency_with_compute():
     state, _ = engine.run_epoch_streaming(state, iter(blocks))
     jax.block_until_ready(state.center_params)
 
-    # calibrate: compute-only wall (zero source latency)
-    t0 = time.perf_counter()
-    state, _ = engine.run_epoch_streaming(state, iter(blocks))
-    jax.block_until_ready(state.center_params)
-    wall_compute = time.perf_counter() - t0
-    per_window = wall_compute / N_WINDOWS
+    # Other test workers on the same cores slow the timed stream and hide the
+    # overlap (stream/serial is ~0.70 on an idle box, 0.89 was seen under
+    # -n 6), so the claim is held to the first of a few attempts that shows
+    # it, each with its own calibration.
+    for attempt in range(3):
+        # calibrate: compute-only wall (zero source latency)
+        t0 = time.perf_counter()
+        state, _ = engine.run_epoch_streaming(state, iter(blocks))
+        jax.block_until_ready(state.center_params)
+        wall_compute = time.perf_counter() - t0
+        per_window = wall_compute / N_WINDOWS
 
-    # stream with source latency == per-window compute
-    src = _ThrottledIter(blocks, per_window)
-    t0 = time.perf_counter()
-    state, _ = engine.run_epoch_streaming(state, src)
-    jax.block_until_ready(state.center_params)
-    wall_stream = time.perf_counter() - t0
+        # stream with source latency == per-window compute
+        src = _ThrottledIter(blocks, per_window)
+        t0 = time.perf_counter()
+        state, _ = engine.run_epoch_streaming(state, src)
+        jax.block_until_ready(state.center_params)
+        wall_stream = time.perf_counter() - t0
 
-    serial_estimate = src.total_sleep + wall_compute
-    overlap_efficiency = (serial_estimate - wall_stream) / src.total_sleep
-    print(
-        f"compute {wall_compute:.3f}s, sleep {src.total_sleep:.3f}s, "
-        f"stream {wall_stream:.3f}s, overlap efficiency {overlap_efficiency:.2f}"
-    )
-    # a serial pipeline would land at ~serial_estimate; double buffering at
-    # ~max(sleep, compute) = ~serial/2.  0.78 splits the two decisively.
-    assert wall_stream < 0.78 * serial_estimate, (
-        f"no overlap: stream {wall_stream:.3f}s vs serial "
+        serial_estimate = src.total_sleep + wall_compute
+        overlap_efficiency = (serial_estimate - wall_stream) / src.total_sleep
+        print(
+            f"attempt {attempt}: compute {wall_compute:.3f}s, sleep "
+            f"{src.total_sleep:.3f}s, stream {wall_stream:.3f}s, overlap "
+            f"efficiency {overlap_efficiency:.2f}"
+        )
+        # a serial pipeline would land at ~serial_estimate; double buffering
+        # at ~max(sleep, compute) = ~serial/2.  0.78 splits the two.
+        if wall_stream < 0.78 * serial_estimate:
+            return
+    raise AssertionError(
+        f"no overlap in 3 attempts; last: stream {wall_stream:.3f}s vs serial "
         f"{serial_estimate:.3f}s (compute {wall_compute:.3f}s + "
         f"sleep {src.total_sleep:.3f}s)"
     )

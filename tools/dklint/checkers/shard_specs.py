@@ -8,16 +8,15 @@ scope — the operand shapes.  Flags only what is *provable*:
 
   * a spec naming an axis the governing mesh does not declare;
   * the same mesh axis used twice within one spec (jax rejects this at
-    trace time — on device, which we haven't had since r03);
+    trace time);
   * a spec whose rank exceeds the operand's known rank, and an explicit
     ``in_specs`` tuple whose length disagrees with the operand count;
   * a mesh-axis size that provably fails to divide the concrete dim it
-    partitions;
-  * **partial-manual ``compat.shard_map``**: ``axis_names`` a strict
-    subset of the mesh axes — the jax<0.5 shim raises
-    ``NotImplementedError`` for exactly this composition at runtime
-    (the pipeline×tensor-parallel case from PR 1), so it is a static
-    finding now.
+    partitions.
+
+Calls through ``compat.shard_map`` get the same judgement as direct
+``jax.shard_map`` calls.  Partial-manual maps (``axis_names`` a strict
+subset of the mesh axes) are valid and not reported.
 
 Anything unresolvable is trusted, the DK104/DK108 stance.
 """
@@ -54,8 +53,7 @@ class ShardSpecChecker(Checker):
     description = (
         "shard_map in_specs/out_specs provably unsound: axis absent from "
         "the governing mesh, duplicate axis in one spec, rank exceeding "
-        "the operand's, non-dividing mesh axis, or a partial-manual "
-        "compat.shard_map the jax<0.5 shim refuses at runtime"
+        "the operand's, or a non-dividing mesh axis"
     )
 
     def collect(self, project: Project, fi: FileInfo) -> None:
@@ -86,31 +84,6 @@ class ShardSpecChecker(Checker):
         # operand-grounded checks need the invocation
         if site.invoke is not None and in_specs is not None:
             yield from self._check_operands(project, fi, site, in_specs)
-
-        # partial-manual compat.shard_map (the jax<0.5 NotImplementedError)
-        if site.via == "compat" and mesh is not None and \
-                site.axis_names not in (None, UNKNOWN):
-            names = site.axis_names
-            if isinstance(names, str):
-                names = (names,)
-            if isinstance(names, tuple) and all(
-                isinstance(n, str) for n in names
-            ):
-                manual = set(names)
-                mesh_axes = set(mesh.names)
-                auto = mesh_axes - manual
-                if manual and manual < mesh_axes and auto:
-                    yield Finding(
-                        path=fi.relpath, line=call.lineno,
-                        col=call.col_offset, rule=self.rule,
-                        message=(
-                            "partial-manual compat.shard_map: axis_names "
-                            f"{sorted(manual)} is a strict subset of mesh "
-                            f"axes {sorted(mesh_axes)} — the jax<0.5 shim "
-                            "raises NotImplementedError for auto axes "
-                            f"{sorted(auto)} at runtime"
-                        ),
-                    )
 
     def _check_spec(self, fi: FileInfo, call: ast.Call,
                     mesh: Optional[MeshVal], which: str, index: int,

@@ -34,10 +34,10 @@ only when all resolvable sites agree.
 **Mesh model** — ``make_mesh``/``make_mesh_grid`` from
 ``distkeras_tpu/parallel/mesh.py``, raw ``jax.sharding.Mesh``
 constructions (axis sizes recovered from literal dims or a
-``.reshape(...)``), and ``compat.shard_map`` wrappers: the jax<0.5 shim is
-first-class — a call that resolves (directly or through the import map) to
-``distkeras_tpu.utils.compat.shard_map`` is tagged ``via='compat'`` so
-DK123 can flag the partial-manual composition the shim refuses at runtime.
+``.reshape(...)``), and ``compat.shard_map`` wrappers: a call that resolves
+(directly or through the import map) to
+``distkeras_tpu.utils.compat.shard_map`` is a shard_map site like a direct
+``jax.shard_map`` call (tagged ``via='compat'`` in the layout report).
 
 Adding an op evaluator: extend ``Evaluator._eval_call`` (dispatch on the
 import-resolved dotted name, then the short name) — take resolved operand

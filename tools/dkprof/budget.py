@@ -22,7 +22,7 @@ from typing import Dict, List, Optional
 
 __all__ = ["classify_op", "op_budget"]
 
-#: default chip ceilings (TPU v5e, PERF.md §1)
+#: default chip ceilings (TPU v5e: Google Cloud "TPU v5e" documentation)
 DEFAULT_PEAK_FLOPS = 197e12
 DEFAULT_PEAK_BW = 819e9
 
