@@ -55,7 +55,8 @@ def epoch_arrays(
     # C++ library is available, bit-identical numpy fallback otherwise.
     from distkeras_tpu import native, telemetry
 
-    with telemetry.trace.span("epoch_arrays", phase="data", rows=int(total)):
+    with telemetry.trace.epoch_span("epoch_arrays", phase="data",
+                                    rows=int(total)) as span:
         xs = native.gather_rows(features, idx)
         ys = native.gather_rows(labels, idx)
         if stepwise:
@@ -64,6 +65,8 @@ def epoch_arrays(
             shape = (num_workers, n_windows, window, batch_size)
         xs = xs.reshape(shape + features.shape[1:])
         ys = ys.reshape(shape + labels.shape[1:])
+        # the count at the boundary: what the gather wrote this epoch
+        span.attrs["bytes"] = int(xs.nbytes) + int(ys.nbytes)
     return xs, ys
 
 

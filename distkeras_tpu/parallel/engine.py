@@ -27,6 +27,7 @@ Everything is static-shaped and trace-once; there is no per-step Python.
 
 from __future__ import annotations
 
+import time
 from typing import Any, Optional, Sequence
 
 import jax
@@ -973,8 +974,8 @@ class WindowedEngine:
         return jax.jit(epoch_fn, donate_argnums=(0,))
 
     # ----------------------------------------------------------------- public
-    def _dispatch(self, fn, state, xs, ys):
-        """Dispatch one donating epoch program.
+    def _enqueue(self, fn, state, xs, ys):
+        """Enqueue one donating epoch program.
 
         With ``DISTKERAS_SANITIZE`` on, the dispatch (including any cache-miss
         trace) runs under the sanitizer's transfer guard — a host sync hidden
@@ -991,37 +992,23 @@ class WindowedEngine:
         donation.poison(state, label="epoch state (donate_argnums=0)")
         return out
 
-    def _dispatch_with_spans(self, fn, state, xs, ys, n_windows):
-        """Telemetry-enabled dispatch: wrap the (normally fully async) epoch
-        program in window/step/commit spans.
-
-        Phase attribution needs host-visible completion points, so this path
-        blocks on the dispatch outputs — trading async-dispatch overlap for
-        observability.  The trajectory is unchanged (same program, same
-        inputs; asserted in tests/test_telemetry.py).  "step" covers dispatch
-        through loss readiness; "commit" is the residual wait for the
-        committed center params after the losses are already on host — with
-        one fused XLA program that residual is usually small, which is itself
-        the measurement.  Only ever called with telemetry enabled; the
-        disabled path dispatches directly with zero added syncs."""
-        with telemetry.trace.span("window", windows=n_windows):
-            with telemetry.trace.span("step", phase="step"):
-                new_state, stats = self._dispatch(fn, state, xs, ys)
-                jax.block_until_ready(stats["loss"])
-            with telemetry.trace.span("commit", phase="commit"):
-                jax.block_until_ready(new_state.center_params)
+    def _dispatch(self, fn, state, xs, ys):
+        """Dispatch a whole epoch's (or chunk's) program, under the two
+        epoch-grain spans that are always recorded and never block:
+        ``dispatch`` is this thread's call that enqueues the program (a
+        cache-miss trace and compile land here), ``device_epoch`` runs from
+        its return until the epoch's losses are ready on the device, and is
+        closed by the telemetry package's readiness thread, not here.  Only
+        ``stats["loss"]`` goes to that thread: the state is donated by the
+        next dispatch."""
+        with telemetry.trace.epoch_span("dispatch", windows=int(xs.shape[1])):
+            new_state, stats = self._enqueue(fn, state, xs, ys)
+        telemetry.trace.probe(stats["loss"], "device_epoch",
+                              time.perf_counter(), phase="step")
         return new_state, stats
 
-    def run_epoch(self, state: TrainState, xs: jnp.ndarray, ys: jnp.ndarray,
-                  *, sync_telemetry: bool = True):
-        """Run one epoch.  ``xs``/``ys`` leading dims: [num_workers, n_windows,
-        window, batch] (uniform mode) or [num_workers, n_steps, batch]
-        (staleness mode).
-
-        ``sync_telemetry=False`` keeps the dispatch fully asynchronous even
-        when telemetry is enabled (no spans recorded here); the streaming
-        path uses it so double buffering survives and records its own spans
-        at its real sync points instead."""
+    def _epoch_program(self, xs):
+        """The cached jitted program for one epoch of ``xs``'s shape."""
         if self.commit_schedule is not None:
             key = ("step", xs.shape[1], xs.ndim)
             if key not in self._epoch_fns:
@@ -1032,10 +1019,18 @@ class WindowedEngine:
             key = ("win", n_windows, window, do_commit, xs.ndim)
             if key not in self._epoch_fns:
                 self._epoch_fns[key] = self._make_epoch_fn(n_windows, window, do_commit, xs.ndim)
-        fn = self._epoch_fns[key]
+        return self._epoch_fns[key]
+
+    def run_epoch(self, state: TrainState, xs: jnp.ndarray, ys: jnp.ndarray):
+        """Run one epoch.  ``xs``/``ys`` leading dims: [num_workers, n_windows,
+        window, batch] (uniform mode) or [num_workers, n_steps, batch]
+        (staleness mode).
+
+        The dispatch is asynchronous with telemetry on as with it off: the
+        ``dispatch`` and ``device_epoch`` spans (:meth:`_dispatch`) are
+        always recorded and wait for nothing on this thread."""
+        fn = self._epoch_program(xs)
         with self.mesh:
-            if sync_telemetry and telemetry.enabled():
-                return self._dispatch_with_spans(fn, state, xs, ys, int(xs.shape[1]))
             return self._dispatch(fn, state, xs, ys)
 
     def run_epochs(
@@ -1075,8 +1070,6 @@ class WindowedEngine:
             )
         fn = self._epoch_fns[key]
         with self.mesh:
-            if telemetry.enabled():
-                return self._dispatch_with_spans(fn, state, xs, ys, n_windows)
             return self._dispatch(fn, state, xs, ys)
 
     def clear_program_cache(self, keep_multi: Optional[tuple] = None) -> None:
@@ -1118,7 +1111,13 @@ class WindowedEngine:
             # (data.epoch_window_iter(feature_dtype=...)) arrive already
             # in the compute dtype — don't pay a second host copy
             xs = xs.astype(cast, copy=False)
-        return self.shard_batches(xs[:, None], ys[:, None])
+        xs, ys = xs[:, None], ys[:, None]
+        # a window's transfer: a per-window span the switch governs.  The
+        # enqueue only, so it feeds no phase: a streaming fit's ``h2d`` phase
+        # reads 0.0 (nothing on this path waits for a transfer)
+        with telemetry.trace.span("window_h2d",
+                                  bytes=int(xs.nbytes) + int(ys.nbytes)):
+            return self._put_batches(xs, ys)
 
     def run_epoch_streaming(self, state: TrainState, window_iter,
                             prefetch: int = 2, strict_link=None,
@@ -1210,12 +1209,14 @@ class WindowedEngine:
                         break
                     buf.append(block)
                 xs, ys = buf.popleft()
-                # async dispatch; sync_telemetry=False because blocking here
-                # would serialise the pipeline — spans are recorded at the real
-                # sync point (the backpressure wait) instead
+                # async dispatch of the n_windows=1 epoch program, under the
+                # per-window span the switch governs (not the epoch-grain
+                # ``dispatch``: a window is not an epoch); the wait is recorded
+                # at the real sync point, the backpressure wait below
                 with telemetry.trace.span("window_dispatch", window=n_windows):
-                    state, stats = self.run_epoch(
-                        state, xs, ys, sync_telemetry=False)
+                    fn = self._epoch_program(xs)
+                    with self.mesh:
+                        state, stats = self._enqueue(fn, state, xs, ys)
                 n_windows += 1
                 stats_list.append(stats)
                 if on_window is not None:
@@ -1360,30 +1361,32 @@ class WindowedEngine:
         """Device-put epoch data: worker axis leading; sequence (last) axis of
         xs also sharded when sequence parallelism is on.
 
-        Uses ``make_array_from_callback`` so the same code works multi-host
-        (each process materialises only its addressable shards — the DCN
-        analogue of Spark shipping partitions to executors)."""
+        Returns as soon as the transfer is enqueued, with telemetry on as
+        with it off.  Two epoch-grain spans, always recorded: ``h2d`` is this
+        thread's enqueue; ``h2d_transfer`` runs from entry until the rows are
+        ready on the device and is closed by the telemetry package's
+        readiness thread.  ``bytes`` is the count at the boundary."""
+        t0 = time.perf_counter()
+        nbytes = int(xs.nbytes) + int(ys.nbytes)
+        with telemetry.trace.epoch_span("h2d", bytes=nbytes):
+            out = self._put_batches(xs, ys)
+        telemetry.trace.probe(out, "h2d_transfer", t0, phase="h2d",
+                              bytes=nbytes)
+        return out
+
+    def _put_batches(self, xs: np.ndarray, ys: np.ndarray):
+        """Uses ``make_array_from_callback`` so the same code works
+        multi-host (each process materialises only its addressable shards —
+        the DCN analogue of Spark shipping partitions to executors)."""
         from jax.sharding import NamedSharding
 
         xs_spec, ys_spec = self._data_specs(xs.ndim)
-
-        def _put():
-            with self.mesh:
-                return (
-                    jax.make_array_from_callback(
-                        xs.shape, NamedSharding(self.mesh, xs_spec), lambda idx: xs[idx]
-                    ),
-                    jax.make_array_from_callback(
-                        ys.shape, NamedSharding(self.mesh, ys_spec), lambda idx: ys[idx]
-                    ),
-                )
-
-        if not telemetry.enabled():
-            return _put()
-        # blocking makes the span honest (the transfer itself, not just the
-        # enqueue); only taken when telemetry is on
-        with telemetry.trace.span("h2d", phase="h2d",
-                                  bytes=int(xs.nbytes) + int(ys.nbytes)):
-            out = _put()
-            jax.block_until_ready(out)
-        return out
+        with self.mesh:
+            return (
+                jax.make_array_from_callback(
+                    xs.shape, NamedSharding(self.mesh, xs_spec), lambda idx: xs[idx]
+                ),
+                jax.make_array_from_callback(
+                    ys.shape, NamedSharding(self.mesh, ys_spec), lambda idx: ys[idx]
+                ),
+            )

@@ -48,7 +48,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from distkeras_tpu import telemetry
 from distkeras_tpu.algorithms.base import UpdateRule
 from distkeras_tpu.models.adapter import ModelAdapter
 from distkeras_tpu.parallel.engine import (
@@ -426,23 +425,11 @@ class GSPMDEngine(WindowedEngine):
         return jax.tree.map(np.asarray, sliced)
 
     # --------------------------------------------------------------- sharding
-    def shard_batches(self, xs: np.ndarray, ys: np.ndarray):
+    def _put_batches(self, xs: np.ndarray, ys: np.ndarray):
+        # the base class's shard_batches records the spans around this
         sharding = NamedSharding(self.mesh, P(WORKER_AXIS))
-
-        def _put():
-            with self.mesh:
-                return (
-                    jax.make_array_from_callback(xs.shape, sharding, lambda idx: xs[idx]),
-                    jax.make_array_from_callback(ys.shape, sharding, lambda idx: ys[idx]),
-                )
-
-        if not telemetry.enabled():
-            return _put()
-        # same honest-transfer span as the base class (blocks so the span
-        # covers the copy, not just the enqueue) — parity for bench.py's
-        # phase breakdown under the GSPMD engine
-        with telemetry.trace.span("h2d", phase="h2d",
-                                  bytes=int(xs.nbytes) + int(ys.nbytes)):
-            out = _put()
-            jax.block_until_ready(out)
-        return out
+        with self.mesh:
+            return (
+                jax.make_array_from_callback(xs.shape, sharding, lambda idx: xs[idx]),
+                jax.make_array_from_callback(ys.shape, sharding, lambda idx: ys[idx]),
+            )

@@ -13,10 +13,18 @@ One subsystem, three surfaces:
   flight-recorder ring with crash blackbox dumps, and the fleet ``run_id``
   stamped into every trace event and scrape.
 
-Everything is gated on ``DISTKERAS_TELEMETRY`` (see :mod:`.runtime`): with
-the flag unset, ``trace.span()`` returns a shared no-op and instrumented
-code paths take their original branch — no extra host syncs, no extra
-allocations.  Import cost is stdlib-only; jax is touched lazily.
+**Always recorded**: the epoch-grain spans of the training loop
+(``epoch``, ``epoch_arrays``, ``h2d``, ``h2d_transfer``, ``dispatch``,
+``device_epoch``, ``stats_wait``; :mod:`.trace`), a handful an epoch, into
+the flight-recorder ring with their absolute ``perf_counter`` times (what
+that costs, the readiness thread's wake-ups included: :mod:`.trace`).
+**Governed by** ``DISTKERAS_TELEMETRY`` (see :mod:`.runtime`): everything
+dear — every other ``trace.span()`` (a shared no-op when unset), the trace
+and metrics files, histograms, the HTTP scrape, the crash blackbox.
+**Nothing blocks** either way: no span waits for the device on the thread
+that opens it, so instrumented code dispatches the same programs in the
+same order with the flag set or unset.  Import cost is stdlib-only; jax is
+touched lazily.
 """
 
 from __future__ import annotations

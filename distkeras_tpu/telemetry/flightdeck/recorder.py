@@ -4,10 +4,14 @@ The flush-at-exit telemetry files answer "what happened over the whole run";
 the flight recorder answers "what happened in the last few seconds before it
 died".  It is a fixed-size ring — a preallocated list plus a monotonically
 increasing index, both touched under one cheap lock — fed by the correlated
-tracer (every finished span), the metrics registry (every counter/gauge
-delta while telemetry is on), the :class:`DivergenceWatchdog` (every
-observation), and the sanitizer (every violation).  Recording is a tuple
-store; the per-event overhead is pinned by test next to the span fast path.
+tracer (every finished span while telemetry is on, and the epoch-grain spans
+of the training loop always: see :mod:`..trace`), the metrics registry
+(every counter/gauge delta while telemetry is on), the
+:class:`DivergenceWatchdog` (every observation), and the sanitizer (every
+violation).  Recording is a tuple store; the per-event overhead is pinned by
+test next to the span fast path.  :meth:`FlightRecorder.spans` gives the
+ring's spans with their absolute ``perf_counter`` times, for a reader in the
+same process (the benchmark's ``readers/spans.py``).
 
 On an unhandled trainer exception, a watchdog halt, a strict sanitizer
 violation, or a daemon job crash, :func:`blackbox_dump` serialises the ring
@@ -44,7 +48,10 @@ class FlightRecorder:
     one of ``span``/``metric``/``watchdog``/``sanitizer``, ``unix`` the wall
     timestamp (for humans), ``perf`` the ``perf_counter`` reading (for trace
     export), ``data`` a small JSON-safe payload, ``event`` the full Chrome
-    trace event dict for spans.
+    trace event dict for spans recorded with telemetry on.  A span's ``data``
+    is ``(t0, t1, thread, parent, attrs)``: absolute ``perf_counter``
+    seconds, the recording thread's name, the enclosing span's name, and the
+    span's attributes (``epoch`` among them).
     """
 
     def __init__(self, capacity: int = DEFAULT_CAPACITY):
@@ -60,7 +67,10 @@ class FlightRecorder:
 
     def record(self, kind: str, name: str, data=None, event=None) -> None:
         """Append one entry: a tuple build and a list store under the lock."""
-        entry = (kind, name, time.time(), time.perf_counter(), data, event)
+        self._store((kind, name, time.time(), time.perf_counter(), data, event))
+
+    def _store(self, entry) -> None:
+        kind, name = entry[0], entry[1]
         with self._lock:
             self._buf[self._idx % self.capacity] = entry
             self._idx += 1
@@ -70,8 +80,16 @@ class FlightRecorder:
                 self._last_spans[name] = entry[2]
 
     def record_span(self, event: Dict[str, Any]) -> None:
-        """Fed by the correlated tracer with the already-built trace event."""
+        """A span known only by its already-built trace event."""
         self.record("span", event["name"], event=event)
+
+    def record_timed_span(self, name, t0, t1, thread, parent, attrs,
+                          event=None) -> None:
+        """Fed by the correlated tracer with the span's absolute
+        ``perf_counter`` times; ``event`` is the trace event it built, or
+        ``None`` for an epoch-grain span recorded with telemetry off."""
+        self._store(("span", name, time.time(), t1,
+                     (t0, t1, thread, parent, attrs), event))
 
     def record_metric(self, name: str, value: float) -> None:
         self.record("metric", name, data={"value": value})
@@ -88,17 +106,34 @@ class FlightRecorder:
 
     # ----------------------------------------------------------- inspection
 
-    def events(self) -> List[Dict[str, Any]]:
-        """Ring contents, oldest first, as JSON-safe dicts."""
+    def _raw(self):
         with self._lock:
             if self._idx <= self.capacity:
-                raw = self._buf[: self._idx]
-            else:
-                head = self._idx % self.capacity
-                raw = self._buf[head:] + self._buf[:head]
+                return self._buf[: self._idx]
+            head = self._idx % self.capacity
+            return self._buf[head:] + self._buf[:head]
+
+    @staticmethod
+    def _timed(data) -> Dict[str, Any]:
+        t0, t1, thread, parent, attrs = data
+        return {"t0": t0, "t1": t1, "thread": thread, "parent": parent,
+                "attrs": dict(attrs)}
+
+    def spans(self) -> List[Dict[str, Any]]:
+        """The ring's spans that carry their times, oldest first:
+        ``{"name", "t0", "t1", "thread", "parent", "attrs"}``, ``t0``/``t1``
+        absolute ``time.perf_counter()`` seconds."""
+        return [dict(self._timed(data), name=name)
+                for kind, name, _unix, _perf, data, _event in self._raw()
+                if kind == "span" and data is not None]
+
+    def events(self) -> List[Dict[str, Any]]:
+        """Ring contents, oldest first, as JSON-safe dicts."""
         out = []
-        for kind, name, unix, perf, data, event in raw:
+        for kind, name, unix, perf, data, event in self._raw():
             d = {"kind": kind, "name": name, "unix": unix, "perf": perf}
+            if kind == "span" and data is not None:
+                data = self._timed(data)
             if data is not None:
                 d["data"] = data
             if event is not None:
@@ -139,7 +174,10 @@ class FlightRecorder:
         pid = os.getpid()
         for e in evs:
             if e["kind"] == "span":
-                out.append(e["event"])
+                # a span recorded while the switch was off has no trace
+                # event: the scrape that serves this is on only with it
+                if "event" in e:
+                    out.append(e["event"])
                 continue
             out.append({
                 "name": f'{e["kind"]}:{e["name"]}',

@@ -17,7 +17,9 @@ Endpoints:
     with the fleet ``run_id``.
 ``/healthz``
     Liveness: uptime, last event / last span-completion timestamps,
-    watchdog state, sanitizer mode and violation tallies.
+    watchdog state, sanitizer mode and violation tallies, ``probes_lost``
+    (epochs whose ``h2d_transfer`` or ``device_epoch`` span was never
+    recorded: the ``h2d``/``step`` phases were taken over the rest).
 ``/vars``
     JSON: full metrics snapshot, phase breakdown, last dynamics summary.
 ``/trace``
@@ -274,6 +276,7 @@ def _render(path: str, request: Optional[dict] = None):
             "last_spans": rec.last_spans(),
             "watchdog": rec.watchdog_state(),
             "sanitizer": {"mode": _sanitizer.mode(), "violations": counts},
+            "probes_lost": _tracer.probes_lost,
         }
         return ("application/json", json.dumps(body), 200)
     if path == "/vars":

@@ -2,8 +2,8 @@
 
 Usage at a host boundary (never inside jitted code):
 
-    with telemetry.trace.span("epoch", epoch=3):
-        with telemetry.trace.span("window", phase="step"):
+    with telemetry.trace.epoch_span("epoch", epoch=3):
+        with telemetry.trace.span("window_dispatch", window=0):
             ...
 
 Spans clock with ``time.perf_counter`` (monotonic — DK106's whole point),
@@ -11,13 +11,50 @@ nest per-thread, and are recorded as complete ("ph": "X") events whose
 ts/dur containment gives Perfetto the nesting; each event also carries an
 explicit ``args.parent`` so tests and scripts need no interval math.
 
-When telemetry is disabled, ``span()`` returns a shared no-op context
-manager — the cost is one cached-bool check and one dict-free branch, which
-the test suite pins against plain dict-lookup cost.
+**What is always recorded.**  The spans of the per-epoch loop — ``epoch``,
+``epoch_arrays``, ``h2d``, ``h2d_transfer``, ``dispatch``, ``device_epoch``,
+``stats_wait``: a handful an epoch — are opened with :meth:`Tracer.epoch_span`
+or closed by :meth:`Tracer.probe` and go into the flight-recorder ring
+whether or not ``DISTKERAS_TELEMETRY`` is set: two ``perf_counter`` reads and
+a tuple store each on the thread that opens them, and the readiness thread's
+wake-ups (below).  The ring keeps, for every span, its name, its absolute
+``perf_counter`` start and end in seconds, the thread, ``parent``, and the
+``epoch`` index that the spans of one epoch share
+(``flightdeck.recorder.spans()``).  **What the switch governs** is what is
+dear: every other ``span()`` (per-window, per-request), the tracer's own
+unbounded event list and the files written from it, the ``phase_*``
+histograms, the HTTP scrape.  With the switch off ``span()`` returns a shared
+no-op context manager — one cached-bool check and one dict-free branch,
+which the test suite pins against plain dict-lookup cost.
 
-A span opened with ``phase="step"`` (or data/h2d/commit/...) additionally
-feeds the ``phase_<name>_seconds`` histogram in the global metrics registry
-on exit — that is where bench.py's phase breakdown comes from.
+**Nothing blocks.**  No span waits for the device on the thread that opens
+it.  A span that ends when device work completes (``h2d_transfer``: the rows
+are on the device; ``device_epoch``: the epoch's losses are ready) is handed
+to :meth:`Tracer.probe`: one daemon thread of this module asks the arrays
+whether they are ready, records the span when they are, and drops its
+reference.  It waits for nothing and dispatches nothing, so telemetry on and
+off dispatch the same programs in the same order.  **What that thread costs**,
+switch or no switch, in every training process: while it holds a probe (a
+training loop's ``device_epoch`` is held all epoch long) it wakes every 20 ms
+(``PROBE_IDLE_POLL_S``), and every millisecond (``PROBE_POLL_S``) once a
+span has run nine tenths of the shorter of the last two of its name, or from
+its start where that is under 0.2 s (``PROBE_LONG_S``) or unknown; a wake-up
+takes the GIL for one ``is_ready()`` per array held.  In a steady loop of
+long epochs that is some 50 wake-ups a second, 1000 over the last tenth of a
+span, and a span dated at most 1 ms late; a span much shorter than the two
+before it is dated at most 20 ms late, once.  A
+probe whose span could not be recorded (the queue was full, or its arrays
+raised) is counted in :attr:`Tracer.probes_lost`, which ``/healthz`` shows and
+the benchmark's ``feed_gap`` note line carries: an epoch with a lost probe
+has no ``h2d_transfer`` or ``device_epoch``, and the readers' medians skip it.
+
+A span with ``phase="step"`` (or data/h2d/commit/...) additionally feeds the
+``phase_<name>_seconds`` histogram in the global metrics registry on exit,
+with telemetry on — that is where bench.py's phase breakdown comes from.
+``h2d_transfer`` feeds ``h2d`` and ``device_epoch`` feeds ``step`` (the
+device's epoch, not a host wait); the in-memory path feeds no ``commit``:
+one fused program has no such boundary.  The streaming path's per-window
+``window_h2d`` is the enqueue alone and feeds no phase.
 
 Exceptions raised while recording are NOT swallowed: the CI tier-1 variant
 with ``DISTKERAS_TELEMETRY=1`` exists precisely so instrumentation bugs fail
@@ -37,6 +74,7 @@ from __future__ import annotations
 
 import json
 import os
+import queue
 import threading
 import time
 import uuid
@@ -112,13 +150,167 @@ class Span:
     def __exit__(self, exc_type, exc, tb):
         t1 = self._tracer._clock()
         parent = self._tracer._pop()
-        self._tracer._record(self.name, self._t0, t1, parent, self.attrs)
-        if self.phase is not None:
-            _registry.histogram(
-                f"phase_{self.phase}_seconds",
-                help=f"host-visible seconds in the {self.phase} phase",
-            ).observe(t1 - self._t0)
+        self._tracer._record(self.name, self._t0, t1, parent, self.attrs,
+                             self.phase)
         return False
+
+
+class _EpochSpan(Span):
+    """An epoch-grain span (:meth:`Tracer.epoch_span`).  One that is given
+    ``epoch=`` makes it the thread's current epoch while it is open; one that
+    is not takes the current epoch as its own, so that a span opened layers
+    below the loop (``data.epoch_arrays``, ``engine.shard_batches``) carries
+    the identifier its epoch's other spans share."""
+
+    __slots__ = ("_outer",)
+
+    def __enter__(self):
+        tls = self._tracer._tls
+        self._outer = getattr(tls, "epoch", None)
+        if "epoch" in self.attrs:
+            tls.epoch = self.attrs["epoch"]
+        elif self._outer is not None:
+            self.attrs["epoch"] = self._outer
+        return super().__enter__()
+
+    def __exit__(self, exc_type, exc, tb):
+        self._tracer._tls.epoch = self._outer
+        return super().__exit__(exc_type, exc, tb)
+
+
+#: probes the readiness thread may hold unfinished; one more is dropped
+PROBE_DEPTH = 64
+#: how often the readiness thread looks at a span that is about to end: a
+#: span it closes ends at most this much late
+PROBE_POLL_S = 0.001
+#: how often it looks while every span it holds has, by the last two of its
+#: name, most of its length before it: the most a span that ends early is late
+PROBE_IDLE_POLL_S = 0.02
+#: the share of that length after which the looks come every PROBE_POLL_S
+PROBE_NEAR = 0.9
+#: a span expected to be shorter than this is looked at every PROBE_POLL_S
+#: from its start: an idle poll would be a large part of it
+PROBE_LONG_S = 0.2
+
+
+def _ready(arrays) -> bool:
+    import jax
+
+    return all(leaf.is_ready() for leaf in jax.tree.leaves(arrays)
+               if hasattr(leaf, "is_ready"))
+
+
+def _poll_wait(dues, now):
+    """How long the readiness thread may sleep: for ever with nothing held;
+    else until the first span is due to be looked at closely, but at least
+    one fine poll and at most one idle poll."""
+    if not dues:
+        return None
+    return min(PROBE_IDLE_POLL_S, max(PROBE_POLL_S, min(dues) - now))
+
+
+class _ReadinessProbe:
+    """The thread that watches device arrays become ready so that no other
+    has to wait for them.
+
+    ``submit`` never waits: with ``PROBE_DEPTH`` probes unfinished it drops
+    the new one and counts it in ``lost``.  The thread dispatches nothing to
+    the device and waits for nothing either: it asks each array it holds
+    whether it ``is_ready()``, records the span ``t0 -> ready`` of those
+    that are, and drops its reference to them at once.  (Blocking on them in
+    the order they came would date a transfer that finished during an epoch
+    by that epoch's end: the epoch's losses came first.)  It asks every
+    ``PROBE_IDLE_POLL_S`` until a span has run ``PROBE_NEAR`` of the shorter
+    of the last two of its name, then every ``PROBE_POLL_S`` (a first span,
+    and one expected to last under ``PROBE_LONG_S``, from its start): a
+    training loop's spans repeat, so most of a long epoch costs fifty
+    wake-ups a second and not a thousand.  A span that ends early is dated at
+    most one idle poll late, and the next of its name expects no more."""
+
+    def __init__(self, tracer):
+        self._tracer = tracer
+        self._queue = queue.Queue()
+        self._lock = threading.Lock()
+        self._thread = None
+        self._took = {}  # name -> seconds its last two spans took
+        self.lost = 0
+
+    def submit(self, arrays, name, t0, phase, parent, attrs) -> bool:
+        with self._lock:
+            if self._queue.unfinished_tasks >= PROBE_DEPTH:
+                self.lost += 1
+                return False
+            if self._thread is None:
+                self._thread = threading.Thread(
+                    target=self._run, name="dk-telemetry-probe", daemon=True)
+                self._thread.start()
+        self._queue.put((arrays, name, t0, phase, parent, attrs))
+        return True
+
+    def _run(self):
+        held = []
+        while True:
+            try:
+                self._sweep(held)
+            except Exception:  # noqa: BLE001 — this thread must not die of
+                # a span it could not record (already let go of): count it
+                with self._lock:
+                    self.lost += 1
+
+    def _due(self, item):
+        """When to begin to look closely at ``item``: at once for the first
+        of its name and for a short one."""
+        _, name, t0 = item[:3]
+        expect = min(self._took.get(name, (0.0,)))
+        return t0 + PROBE_NEAR * expect if expect >= PROBE_LONG_S else t0
+
+    def _sweep(self, held):
+        """Wait for a new probe or for the next look (:func:`_poll_wait`),
+        then settle every held probe whose arrays are ready."""
+        wait = _poll_wait([self._due(item) for item in held],
+                          self._tracer._clock())
+        try:
+            held.append(self._queue.get(timeout=wait))
+            while True:
+                held.append(self._queue.get_nowait())
+        except queue.Empty:
+            pass
+        now = self._tracer._clock()
+        for item in list(held):
+            arrays, name, t0, phase, parent, attrs = item
+            try:
+                ready = _ready(arrays)
+            except Exception:  # noqa: BLE001 — a deleted or failed array:
+                # its error surfaces on the thread that reads the result
+                ready = None
+            if ready is False:
+                continue
+            # settled: let go of it before recording, so that a span that
+            # cannot be recorded is not looked at again (by identity:
+            # ``list.remove`` would compare the arrays of the probes before
+            # it, and a jax array raises on ``==`` with a tuple)
+            held[:] = [other for other in held if other is not item]
+            self._queue.task_done()
+            if ready:
+                self._took[name] = (self._took.get(name, (now - t0,))[-1],
+                                    now - t0)
+                self._tracer._record(name, t0, now, parent, attrs, phase)
+            else:
+                with self._lock:
+                    self.lost += 1
+
+    def drain(self, timeout) -> bool:
+        """Wait, at most ``timeout`` seconds, until every submitted probe
+        has been recorded.  True if none is left."""
+        deadline = time.monotonic() + timeout
+        done = self._queue.all_tasks_done
+        with done:
+            while self._queue.unfinished_tasks:
+                left = deadline - time.monotonic()
+                if left <= 0:
+                    return False
+                done.wait(left)
+        return True
 
 
 class Tracer:
@@ -131,6 +323,11 @@ class Tracer:
     and feeds finished spans to the flight-recorder ring — the module-global
     :data:`trace` is; ad-hoc tracers (golden tests, scripts) default to
     uncorrelated so their output is a pure function of their inputs.
+
+    ``anchor`` is one reading of two clocks taken together at the tracer's
+    origin, ``(clock(), time.time_ns())``: the events' ``ts`` count from the
+    first, so the second puts a written trace on the wall clock, beside logs
+    and other processes' files.  :meth:`write` stores it in the file.
     """
 
     def __init__(self, clock=time.perf_counter, pid=None, correlated=False):
@@ -142,6 +339,8 @@ class Tracer:
         self._tls = threading.local()
         self._tids = {}
         self._origin = clock()
+        self.anchor = (self._origin, time.time_ns())
+        self._probe = _ReadinessProbe(self)
 
     # ------------------------------------------------------------- recording
 
@@ -149,6 +348,38 @@ class Tracer:
         if not runtime.enabled():
             return NOOP_SPAN
         return Span(self, name, phase, attrs)
+
+    def epoch_span(self, name, phase=None, **attrs):
+        """A span of the per-epoch loop: recorded into the flight-recorder
+        ring whether or not telemetry is on (module docstring), and stamped
+        with the ``epoch`` of the epoch-grain span that encloses it.  A
+        handful an epoch, never one per window or per request."""
+        return _EpochSpan(self, name, phase, attrs)
+
+    def probe(self, arrays, name, t0, phase=None, **attrs):
+        """Record the span ``t0 -> arrays ready on the device`` without
+        waiting here: the readiness thread watches them.  Epoch-grain like
+        :meth:`epoch_span` (always recorded; ``epoch`` and ``parent`` are
+        this thread's current ones).  Never pass a buffer that a later
+        dispatch donates.  False if the probe was dropped (queue full) and
+        counted in :attr:`probes_lost`."""
+        epoch = getattr(self._tls, "epoch", None)
+        if epoch is not None:
+            attrs.setdefault("epoch", epoch)
+        return self._probe.submit(arrays, name, t0, phase, self.current(),
+                                  attrs)
+
+    def drain(self, timeout=1.0) -> bool:
+        """Wait, at most ``timeout`` seconds, for the readiness thread to
+        record the probes it holds (their arrays are ready by the time a fit
+        has read its last losses, so this returns at once there)."""
+        return self._probe.drain(timeout)
+
+    @property
+    def probes_lost(self) -> int:
+        """Probes whose span was never recorded: dropped at a full queue,
+        or their arrays raised.  ``/healthz`` shows it."""
+        return self._probe.lost
 
     def _stack(self):
         stack = getattr(self._tls, "stack", None)
@@ -205,7 +436,19 @@ class Tracer:
             return
         self._record(name, t0, t1, None, attrs)
 
-    def _record(self, name, t0, t1, parent, attrs):
+    def _record(self, name, t0, t1, parent, attrs, phase=None):
+        thread = threading.current_thread().name
+        if not runtime.enabled():
+            # an epoch-grain span with telemetry off: the ring alone
+            if self._correlated:
+                _flight_recorder.record_timed_span(
+                    name, t0, t1, thread, parent, attrs)
+            return
+        if phase is not None:
+            _registry.histogram(
+                f"phase_{phase}_seconds",
+                help=f"seconds in the {phase} phase",
+            ).observe(t1 - t0)
         ident = threading.get_ident()
         args = dict(attrs)
         ctx = getattr(self._tls, "ctx", None)
@@ -232,13 +475,16 @@ class Tracer:
             }
             self._events.append(event)
         if self._correlated:
-            _flight_recorder.record_span(event)
+            _flight_recorder.record_timed_span(
+                name, t0, t1, thread, parent, attrs, event)
 
     def reset(self):
+        self._probe.drain(1.0)
         with self._lock:
             self._events.clear()
             self._tids.clear()
             self._origin = self._clock()
+            self.anchor = (self._origin, time.time_ns())
 
     # --------------------------------------------------------------- export
 
@@ -254,6 +500,11 @@ class Tracer:
 
     def write(self, path) -> str:
         payload = self.export()
+        perf, wall_ns = self.anchor
+        # ``ts`` counts microseconds from ``perf_counter_s``; the wall clock
+        # read ``time_ns`` at that moment
+        payload["otherData"] = {"clock_anchor": {
+            "perf_counter_s": perf, "time_ns": wall_ns}}
         # tmp + replace: dktrace merge / flightdeck may read this file from
         # another process while a dump is still streaming out
         tmp = os.fspath(path) + ".tmp"

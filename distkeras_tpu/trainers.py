@@ -665,17 +665,28 @@ class Trainer:
             from distkeras_tpu.utils.tb import ScalarLogger
 
             scalar_log = ScalarLogger(self.tensorboard_dir)
-        # env-driven step-windowed jax.profiler capture; profile_dir (the
-        # explicit per-trainer knob below) takes precedence — both would
-        # race on one global profiler session
-        prof = None if self.profile_dir else telemetry.ProfilerHook.from_env()
+        # one profiler path: profile_dir (the explicit per-trainer knob) and
+        # the env-driven DISTKERAS_PROFILE both build a step-windowed
+        # ProfilerHook; profile_dir takes precedence — both would race on one
+        # global profiler session.  It captures the second iteration of the
+        # loop below (the first includes compilation), or the only one; the
+        # capture blocks nothing, so it holds what the device ran meanwhile.
+        if self.profile_dir:
+            steps = -(-(self.num_epoch - start_epoch) // self.dispatch_epochs)
+            prof = telemetry.ProfilerHook(
+                self.profile_dir, start_epoch + min(1, steps - 1))
+        else:
+            prof = telemetry.ProfilerHook.from_env()
         if telemetry.enabled():
             telemetry.install_jax_hooks()
 
         last_summary: dict = {}
 
         def _materialise(stats, epoch_idx):
-            stats = jax.tree.map(np.asarray, stats)
+            # the one place the training thread waits for the device: how
+            # long it had nothing to do but wait (always recorded)
+            with telemetry.trace.epoch_span("stats_wait"):
+                stats = jax.tree.map(np.asarray, stats)
             dyn = stats.get("dynamics")
             summary = None
             if dyn is not None:
@@ -726,7 +737,7 @@ class Trainer:
                     )
                 state, epoch_stats = self._train_chunked(
                     engine, state, feats, labels, num_workers, window, shuffle,
-                    ckpt, start_epoch, _materialise,
+                    ckpt, start_epoch, _materialise, prof,
                 )
                 # all epochs consumed; the per-epoch loop below runs zero times
                 start_epoch = self.num_epoch
@@ -747,7 +758,8 @@ class Trainer:
                     _chaos.fault("epoch")  # seeded kill entering this epoch
                 if prof is not None:
                     prof.on_step(epoch)
-                with telemetry.trace.span("epoch", epoch=epoch):
+                with telemetry.trace.epoch_span(
+                        "epoch", epoch=epoch, epochs=1):
                     if self.streaming:
                         from distkeras_tpu.data import epoch_window_iter, plan_epoch
 
@@ -832,14 +844,7 @@ class Trainer:
                             )
                         xs, ys = engine.shard_batches(xs, ys)
                         run_one = lambda xs=xs, ys=ys: engine.run_epoch(state, xs, ys)
-                    # Trace the second epoch (the first includes compilation),
-                    # or the only epoch when there is just one.
-                    if self.profile_dir and epoch == min(start_epoch + 1, self.num_epoch - 1):
-                        with jax.profiler.trace(self.profile_dir):
-                            state, stats = run_one()
-                            jax.block_until_ready(state.center_params)
-                    else:
-                        state, stats = run_one()
+                    state, stats = run_one()
                     ps = getattr(self, "parameter_server", None)
                     if ps is not None:
                         # live PS observability: copy the commit counter off
@@ -926,6 +931,9 @@ class Trainer:
                 scalar_log.close()
         if average_at_end:
             state, _ = engine.average_workers(state)
+        # every epoch's losses have been read, so the readiness thread's
+        # last spans are a moment away: have the ring whole on return
+        telemetry.trace.drain()
 
         losses_per_epoch = [float(_epoch_mean(s, "loss")) for s in epoch_stats]
         metrics_per_epoch = [
@@ -972,7 +980,7 @@ class Trainer:
 
     def _train_chunked(
         self, engine, state, feats, labels, num_workers, window,
-        shuffle, ckpt, start_epoch, _materialise,
+        shuffle, ckpt, start_epoch, _materialise, prof,
     ):
         """The ``dispatch_epochs>1`` epoch loop: up to ``dispatch_epochs``
         epochs per device dispatch via :meth:`WindowedEngine.run_epochs`,
@@ -983,6 +991,7 @@ class Trainer:
         ``(state, epoch_stats)`` with every epoch's stats but the last
         already materialised — the caller's trailing ``_materialise`` call
         finishes the last one, same invariant as the per-epoch loop.
+        ``prof`` (a ``ProfilerHook`` or None) counts chunks as its steps.
         """
         from distkeras_tpu.data import plan_epoch
 
@@ -1007,40 +1016,29 @@ class Trainer:
         epoch_stats: List[dict] = []
         epoch = start_epoch
         chunk_idx = 0
-        first_chunk_size = None
+        ps = getattr(self, "parameter_server", None)
         while epoch < self.num_epoch:
             chunk = min(self.dispatch_epochs, self.num_epoch - epoch)
             if ckpt is not None:
                 chunk = min(chunk, self.checkpoint_every - epoch % self.checkpoint_every)
-            if first_chunk_size is None:
-                first_chunk_size = chunk
-            # Trace the second chunk — but only if it reuses the first
-            # chunk's compiled program (same chunk size); a differently-sized
-            # tail chunk would trace a fresh XLA compile, not steady state.
-            # With a single chunk, trace it (compile included — better than
-            # nothing, and the per-epoch loop has the same property at
-            # num_epoch == 1).
-            last_chunk = epoch + chunk >= self.num_epoch
+            if prof is not None:
+                prof.on_step(start_epoch + chunk_idx)
             # "epoch" span per chunk dispatch (attrs carry how many epochs it
-            # covers) so chunked runs keep the epoch→window→commit nesting
-            with telemetry.trace.span("epoch", epoch=epoch, epochs=chunk):
-                if self.profile_dir and (
-                    (chunk_idx == 1 and chunk == first_chunk_size)
-                    or (chunk_idx == 0 and last_chunk)
-                ):
-                    with jax.profiler.trace(self.profile_dir):
-                        state, stats = engine.run_epochs(
-                            state, xs, ys, chunk, shuffle_seed=shuffle_seed)
-                        jax.block_until_ready(state.center_params)
-                else:
-                    state, stats = engine.run_epochs(
-                        state, xs, ys, chunk, shuffle_seed=shuffle_seed)
-            # Same O(1)-retention scheme as the per-epoch loop: materialise
-            # the previous chunk's stats (long computed) while this chunk's
-            # stay device-resident.
-            for i, s in enumerate(epoch_stats):
-                if not isinstance(jax.tree.leaves(s)[0], np.ndarray):
-                    epoch_stats[i] = _materialise(s, i + start_epoch)
+            # covers), around the same spans as the per-epoch loop's
+            with telemetry.trace.epoch_span("epoch", epoch=epoch, epochs=chunk):
+                state, stats = engine.run_epochs(
+                    state, xs, ys, chunk, shuffle_seed=shuffle_seed)
+                if ps is not None:
+                    # live progress, as in the per-epoch loop: copy the
+                    # commit counter off this chunk's state before the next
+                    # dispatch donates it
+                    ps.track(getattr(state, "center_rule", None))
+                # Same O(1)-retention scheme as the per-epoch loop:
+                # materialise the previous chunk's stats (long computed)
+                # while this chunk's stay device-resident.
+                for i, s in enumerate(epoch_stats):
+                    if not isinstance(jax.tree.leaves(s)[0], np.ndarray):
+                        epoch_stats[i] = _materialise(s, i + start_epoch)
             epoch_stats.extend(split(stats, chunk))
             epoch += chunk
             chunk_idx += 1

@@ -249,6 +249,7 @@ def test_exporter_endpoints_and_discovery_file(tmp_path):
     assert health["uptime_seconds"] >= 0
     assert health["sanitizer"]["mode"] in ("off", "warn", "strict")
     assert isinstance(health["sanitizer"]["violations"], dict)
+    assert health["probes_lost"] == telemetry.trace.probes_lost
 
     code, text = _get(addr, "/vars")
     v = json.loads(text)

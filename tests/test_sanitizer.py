@@ -347,7 +347,7 @@ def test_strict_trainer_raises_on_seeded_item_and_names_span():
                     worker_optimizer=("sgd", {"learning_rate": 0.1}),
                     num_workers=2, batch_size=16, num_epoch=1,
                     communication_window=2, seed=7)
-    with pytest.raises(TransferViolation, match="span 'step'") as exc:
+    with pytest.raises(TransferViolation, match="span 'dispatch'") as exc:
         t.train(from_numpy(x, onehot))
     assert "hot loop" in str(exc.value)
 

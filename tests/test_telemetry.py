@@ -2,10 +2,11 @@
 bucketing, Chrome-trace / Prometheus golden files, the daemon ``metrics``
 verb round-trip, the disabled-path overhead pin, ScalarLogger lifecycle,
 and an end-to-end smoke train that must write a Perfetto-loadable trace
-with nested epoch→window→commit spans."""
+whose epoch-grain spans nest under their epoch."""
 
 import json
 import os
+import sys
 import threading
 import time
 
@@ -481,14 +482,68 @@ def _train(toy, num_epoch=2, **kwargs):
     return t
 
 
-def test_trajectory_unchanged_by_telemetry(toy_classification):
+EPOCH_SPANS = ("epoch", "epoch_arrays", "h2d", "h2d_transfer", "dispatch",
+               "device_epoch", "stats_wait")
+
+
+def _ring_spans():
+    telemetry.trace.drain(5.0)
+    return telemetry.flightdeck.recorder.spans()
+
+
+class _SyncLog:
+    """Patches ``jax.block_until_ready`` and ``np.asarray`` to log
+    ``(thread name, open span)`` of every call that waits for a device
+    array."""
+
+    def __init__(self, monkeypatch):
+        import jax
+
+        self.calls = []
+        real_block, real_asarray = jax.block_until_ready, np.asarray
+
+        def block(x):
+            self.calls.append((threading.current_thread().name,
+                               telemetry.trace.current()))
+            return real_block(x)
+
+        def asarray(a, *args, **kwargs):
+            if isinstance(a, jax.Array):
+                self.calls.append((threading.current_thread().name,
+                                   telemetry.trace.current()))
+            return real_asarray(a, *args, **kwargs)
+
+        monkeypatch.setattr(jax, "block_until_ready", block)
+        monkeypatch.setattr(np, "asarray", asarray)
+
+
+@pytest.mark.parametrize("on", [False, True], ids=["off", "on"])
+def test_trajectory_unchanged_by_telemetry(toy_classification, monkeypatch, on):
+    """Telemetry on and off run the same program with the same inputs, and
+    neither makes the training thread wait for the device between the gather
+    and the dispatch: the only waits there belong to the readiness thread."""
     telemetry.configure(False)
     base = _train(toy_classification).get_history()["loss"]
-    telemetry.configure(True)
+    telemetry.configure(on)
     telemetry.trace.reset()
     telemetry.metrics.reset()
+    telemetry.flightdeck.recorder.reset()
+    log = _SyncLog(monkeypatch)
+    me = threading.current_thread().name
     instrumented = _train(toy_classification).get_history()["loss"]
     assert instrumented == base  # bit-identical: same program, same inputs
+    mine = [span for thread, span in log.calls if thread == me]
+    # the training thread waits for the device where it reads an epoch's
+    # losses (stats_wait) and when it builds the returned model (no span
+    # open: after the loop), nowhere inside an epoch's gather, put or dispatch
+    assert "stats_wait" in mine
+    assert set(mine) <= {"stats_wait", None}, mine
+    # the spans that end on the device were closed by the readiness thread,
+    # which waits for nothing either (it asks ``is_ready``)
+    assert {thread for thread, _ in log.calls} == {me}
+    probed = [s["name"] for s in _ring_spans()
+              if s["thread"] == "dk-telemetry-probe"]
+    assert sorted(probed) == ["device_epoch"] * 2 + ["h2d_transfer"] * 2
 
 
 def test_smoke_train_writes_nested_chrome_trace(toy_classification, tmp_path,
@@ -500,31 +555,282 @@ def test_smoke_train_writes_nested_chrome_trace(toy_classification, tmp_path,
     assert len(traces) == 1
     payload = json.load(open(tmp_path / traces[0]))  # must json.load cleanly
     events = payload["traceEvents"]
-    parents = {e["name"]: e["args"].get("parent") for e in events}
-    # the acceptance nesting: epoch -> window -> commit
-    assert parents["window"] == "epoch"
-    assert parents["commit"] == "window"
+    parents = {}
+    for e in events:
+        parents.setdefault(e["name"], set()).add(e["args"].get("parent"))
+    # the nesting: every epoch-grain span sits under its epoch (but the
+    # wait for the last epoch's losses, after the loop); the in-memory path
+    # has no window/step/commit any more (one fused program)
+    for name in EPOCH_SPANS[1:]:
+        assert parents[name] == ({"epoch", None} if name == "stats_wait"
+                                 else {"epoch"}), name
+    assert not {"window", "step", "commit"} & set(parents)
     epochs = [e for e in events if e["name"] == "epoch"]
     assert [e["args"]["epoch"] for e in epochs] == [0, 1]
-    # containment in time, not just labels: the first window sits inside
-    # the first epoch
-    w = min((e for e in events if e["name"] == "window"), key=lambda e: e["ts"])
+    # containment in time, not just labels: the training thread's spans of
+    # the first epoch sit inside the first epoch
     ep = epochs[0]
-    assert ep["ts"] <= w["ts"] and w["ts"] + w["dur"] <= ep["ts"] + ep["dur"]
+    for name in ("epoch_arrays", "h2d", "dispatch"):
+        w = min((e for e in events if e["name"] == name), key=lambda e: e["ts"])
+        assert w["args"]["epoch"] == 0
+        assert ep["ts"] <= w["ts"] and w["ts"] + w["dur"] <= ep["ts"] + ep["dur"]
+    # the clock anchor: the wall clock at the origin that ``ts`` counts from
+    anchor = payload["otherData"]["clock_anchor"]
+    assert anchor == dict(zip(("perf_counter_s", "time_ns"),
+                              telemetry.trace.anchor))
+    assert abs(anchor["time_ns"] - time.time_ns()) < 3600e9
 
     metrics_files = [f for f in os.listdir(tmp_path) if f.startswith("metrics_")]
     assert len(metrics_files) == 1
     snap = json.loads(open(tmp_path / metrics_files[0]).read().splitlines()[-1])
     bd = {k: v for k, v in snap["metrics"].items() if k.startswith("phase_")}
-    # the four bench phases all saw time during an in-memory train
-    assert {"phase_data_seconds", "phase_h2d_seconds", "phase_step_seconds",
-            "phase_commit_seconds"} <= set(bd)
+    # data, h2d (the transfer) and step (the device's epoch) saw time during
+    # an in-memory train; commit has no boundary in one fused program
+    assert {"phase_data_seconds", "phase_h2d_seconds",
+            "phase_step_seconds"} <= set(bd)
+    assert "phase_commit_seconds" not in bd
+    assert telemetry.metrics.phase_breakdown()["commit"] == 0.0
+    assert bd["phase_step_seconds"]["count"] == 2  # one device_epoch an epoch
     assert snap["metrics"]["training_seconds"]["value"] > 0
     assert snap["metrics"]["samples_per_sec_per_chip"]["value"] > 0
+
+
+def test_fit_with_telemetry_off_leaves_epoch_spans_in_ring(toy_classification):
+    """(a) The seven epoch-grain spans are in the ring for every epoch of a
+    default run, with the epoch's id and absolute times that nest."""
+    telemetry.configure(False)
+    telemetry.flightdeck.recorder.reset()
+    before = time.perf_counter()
+    _train(toy_classification, num_epoch=3)
+    after = time.perf_counter()
+    spans = _ring_spans()
+    assert telemetry.trace.export()["traceEvents"] == []  # the ring alone
+    by = {}
+    for s in spans:
+        assert before <= s["t0"] <= s["t1"] <= after  # absolute perf_counter
+        by.setdefault(s["attrs"].get("epoch"), {}).setdefault(
+            s["name"], []).append(s)
+    for epoch in range(3):
+        names = set(by[epoch])
+        # nothing to wait for in the first iteration: stats_wait from 1 on
+        want = set(EPOCH_SPANS) - ({"stats_wait"} if epoch == 0 else set())
+        assert names == want, (epoch, names)
+        ep = by[epoch]["epoch"][0]
+        assert ep["parent"] is None and ep["attrs"]["epochs"] == 1
+        for name in ("epoch_arrays", "h2d", "dispatch", "stats_wait"):
+            for s in by[epoch].get(name, ()):
+                assert s["parent"] == "epoch" and s["thread"] == ep["thread"]
+                assert ep["t0"] <= s["t0"] and s["t1"] <= ep["t1"]
+        order = [by[epoch][n][0] for n in ("epoch_arrays", "h2d", "dispatch")]
+        assert all(a["t1"] <= b["t0"] for a, b in zip(order, order[1:]))
+        put, transfer = by[epoch]["h2d"][0], by[epoch]["h2d_transfer"][0]
+        assert transfer["thread"] == "dk-telemetry-probe"
+        assert transfer["t0"] <= put["t0"] and transfer["t1"] >= put["t0"]
+        assert transfer["attrs"]["bytes"] == put["attrs"]["bytes"] > 0
+        gather = by[epoch]["epoch_arrays"][0]["attrs"]
+        assert gather["bytes"] == put["attrs"]["bytes"] and gather["rows"] == 512
+        device = by[epoch]["device_epoch"][0]
+        assert device["thread"] == "dk-telemetry-probe"
+        assert device["t0"] >= by[epoch]["dispatch"][0]["t1"]
+        assert by[epoch]["dispatch"][0]["attrs"]["windows"] == 2
+    # the last epoch's losses are read after the loop, under no epoch
+    assert list(by[None]) == ["stats_wait"]
+    assert "epoch" in telemetry.flightdeck.recorder.last_spans()  # /healthz
+
+
+def test_probe_drops_reference_and_never_waits(monkeypatch):
+    """(c) The readiness thread lets go of what it watched at once; a full
+    queue drops the probe, counts it, and does not wait."""
+    import gc
+    import weakref
+
+    # ``telemetry.trace`` is the tracer; its module is under the same name
+    trace_mod = sys.modules["distkeras_tpu.telemetry.trace"]
+
+    class Rows:
+        def __init__(self, gate):
+            self.gate = gate
+
+        def is_ready(self):
+            return self.gate.is_set()
+
+        def __eq__(self, other):  # as a jax array against a tuple of them
+            raise TypeError("unsupported operand type(s) for ==")
+
+        __hash__ = None
+
+    monkeypatch.setattr(trace_mod, "PROBE_DEPTH", 2)
+    tr = Tracer()
+    gate = threading.Event()
+    rows = Rows(gate)
+    ref = weakref.ref(rows)
+    t0 = time.perf_counter()
+    assert tr.probe(rows, "h2d_transfer", t0, bytes=8)
+    del rows
+    assert tr.probe(Rows(gate), "h2d_transfer", t0)  # two are unfinished
+    t1 = time.perf_counter()
+    assert tr.probe(Rows(gate), "h2d_transfer", t0) is False  # dropped
+    assert time.perf_counter() - t1 < 0.5  # and did not wait for room
+    assert tr.probes_lost == 1
+    assert not tr.drain(0.05)  # two are still held
+    gate.set()
+    assert tr.drain(10.0)
+    gc.collect()
+    assert ref() is None  # nothing keeps the rows alive
+    events = tr.export()["traceEvents"]
+    assert [e["name"] for e in events] == ["h2d_transfer"] * 2
+    assert sorted(map(str, (e["args"] for e in events))) == [
+        "{'bytes': 8}", "{}"]
+    # every span ends when its own arrays are ready, whatever came before it
+    late, soon = threading.Event(), threading.Event()
+    assert tr.probe(Rows(late), "device_epoch", t0)
+    assert tr.probe(Rows(soon), "h2d_transfer", t0, bytes=9)
+    soon.set()
+    deadline = time.perf_counter() + 5.0
+    while len(tr.export()["traceEvents"]) < 3 and time.perf_counter() < deadline:
+        time.sleep(0.001)
+    assert [e["args"] for e in tr.export()["traceEvents"]
+            if e["args"].get("bytes") == 9]  # not behind the epoch before it
+    assert tr.probes_lost == 1  # and settling it out of turn lost nothing
+    late.set()
+    assert tr.drain(10.0)
+
+    # arrays that raise (the device's own error) do not kill the thread
+    class Broken:
+        def is_ready(self):
+            raise RuntimeError("device error")
+
+    assert tr.probe(Broken(), "device_epoch", t0)
+    assert tr.probe(Rows(gate), "device_epoch", t0)
+    assert tr.drain(10.0)
+    assert [e["name"] for e in tr.export()["traceEvents"]].count("device_epoch") == 2
+    assert tr.probes_lost == 2  # the full queue's and this one
+
+
+@pytest.mark.parametrize("dues, now, want", [
+    ((), 5.0, None),                # nothing held: sleep until a probe comes
+    ((5.5,), 5.0, 0.02),            # its end is far: one idle poll
+    ((5.5, 5.008), 5.0, 0.008),     # no further than the first span's due
+    ((5.0004,), 5.0, 0.001),        # about to be due: one fine poll
+    ((4.0, 9.0), 5.0, 0.001),       # one is past its due: fine polls
+])
+def test_readiness_thread_sleeps_until_a_span_is_due(dues, now, want):
+    """The wake-ups are the always-on cost: idle polls while every held
+    span has most of its expected length before it, fine polls after."""
+    trace_mod = sys.modules["distkeras_tpu.telemetry.trace"]
+    got = trace_mod._poll_wait(list(dues), now)
+    assert got == (want if want is None else pytest.approx(want))
+
+
+def test_readiness_thread_expects_a_span_to_last_like_the_last_two():
+    """A first span of a name is looked at every fine poll from its start;
+    later ones once nine tenths of the shorter of the last two have passed,
+    and still end where their arrays were ready."""
+
+    class Rows:
+        looks = 0
+
+        def __init__(self, gate):
+            self.gate = gate
+
+        def is_ready(self):
+            self.looks += 1
+            return self.gate.is_set()
+
+    tr = Tracer()
+    probe = tr._probe
+    assert probe._due((None, "device_epoch", 7.0)) == 7.0  # no history
+    took = []
+    probe._took["h2d_transfer"] = (0.19, 5.0)  # a short one: no idle poll
+    assert probe._due((None, "h2d_transfer", 7.0)) == 7.0
+    for length in (0.3, 0.3, 0.3):
+        gate = threading.Event()
+        rows = Rows(gate)
+        t0 = time.perf_counter()
+        assert tr.probe(rows, "device_epoch", t0)
+        threading.Timer(length, gate.set).start()
+        assert tr.drain(10.0)
+        took.append((rows.looks, tr.export()["traceEvents"][-1]["dur"] / 1e6))
+    for looks, dur in took:
+        assert 0.3 <= dur < 2.0  # never before the arrays were ready
+    a, b = probe._took["device_epoch"]
+    assert probe._due((None, "device_epoch", 7.0)) == pytest.approx(
+        7.0 + 0.9 * min(a, b))
+    # 0.27 s of idle polls and 0.03 s of fine ones, against 0.3 s of fine
+    assert took[1][0] < took[0][0] and took[2][0] < took[0][0]
+    assert tr.probes_lost == 0
+
+
+def test_num_updates_advances_during_chunked_fit(toy_classification):
+    """(d) ``_train_chunked`` tracks the commit counter after each chunk's
+    dispatch, so ``num_updates`` moves while a ``dispatch_epochs=2`` fit
+    runs (the benchmark's clock)."""
+    from distkeras_tpu.parameter_servers import ParameterServer
+
+    seen = []
+    real = ParameterServer.track
+
+    def track(self, center_rule_state):
+        real(self, center_rule_state)
+        seen.append(self.num_updates)
+
+    x, y, onehot = toy_classification
+    t = dk.DOWNPOUR(FlaxModel(MLP(features=(16,), num_classes=2)),
+                    loss="categorical_crossentropy",
+                    worker_optimizer=("sgd", {"learning_rate": 0.1}),
+                    num_workers=4, batch_size=16, num_epoch=4,
+                    communication_window=4, seed=7, dispatch_epochs=2)
+    ParameterServer.track = track
+    try:
+        t.train(from_numpy(x, onehot))
+    finally:
+        ParameterServer.track = real
+    # 2 windows x 4 workers an epoch, 2 epochs a chunk: 16 commits a chunk
+    assert seen == [16, 32]
+    assert t.num_updates == 32
+    spans = _ring_spans()
+    chunks = [s["attrs"] for s in spans if s["name"] == "epoch"][-2:]
+    assert chunks == [{"epoch": 0, "epochs": 2}, {"epoch": 2, "epochs": 2}]
+
+
+@pytest.mark.parametrize("kwargs,steps", [
+    ({}, [0, 1, 2]), ({"dispatch_epochs": 2}, [0, 1])],
+    ids=["per_epoch", "chunked"])
+def test_profile_dir_goes_through_profiler_hook(toy_classification, tmp_path,
+                                                monkeypatch, kwargs, steps):
+    """(e) ``profile_dir=`` is one profiler path: a ``ProfilerHook`` over
+    the loop's second iteration, started and stopped exactly once."""
+    import jax
+
+    calls = []
+    monkeypatch.setattr(ProfilerHook, "_start",
+                        lambda self: calls.append(("start", self.logdir)))
+    monkeypatch.setattr(ProfilerHook, "_stop",
+                        lambda self: calls.append(("stop", self.logdir)))
+    real_on_step = ProfilerHook.on_step
+    seen = []
+
+    def on_step(self, step):
+        seen.append(step)
+        real_on_step(self, step)
+
+    monkeypatch.setattr(ProfilerHook, "on_step", on_step)
+    monkeypatch.setattr(jax.profiler, "trace", None)  # the old inline path
+    monkeypatch.setattr(jax.profiler, "start_trace", None)
+    _train(toy_classification, num_epoch=3 if not kwargs else 4,
+           profile_dir=str(tmp_path / "prof"), **kwargs)
+    assert seen == steps
+    assert calls == [("start", str(tmp_path / "prof")),
+                     ("stop", str(tmp_path / "prof"))]
 
 
 def test_streaming_train_records_spans(toy_classification):
     _train(toy_classification, num_epoch=1, streaming=True)
     names = {e["name"] for e in telemetry.trace.export()["traceEvents"]}
-    # streaming records its real sync points instead of window/step/commit
-    assert {"epoch", "window_dispatch", "h2d", "window_gather"} <= names
+    # streaming records its real sync points, per window (the switch governs
+    # those), and none of the in-memory path's epoch-grain spans but "epoch"
+    assert {"epoch", "window_dispatch", "window_h2d", "window_gather"} <= names
+    # "h2d" is the in-memory path's name; a window's put is an enqueue
+    assert not {"dispatch", "device_epoch", "h2d", "h2d_transfer",
+                "epoch_arrays"} & names
+    assert telemetry.metrics.phase_breakdown()["h2d"] == 0.0
