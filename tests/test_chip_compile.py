@@ -46,8 +46,8 @@ def one_chip():
 
 def _kernel(q, k, v):
     # interpret=False: the compiled kernel, whatever backend the test
-    # process itself sits on
-    return flash_attention(q, k, v, True, 256, 512, False)
+    # process itself sits on; the blocks are the defaults, what callers run
+    return flash_attention(q, k, v, True, interpret=False)
 
 
 def _loss(q, k, v):
@@ -59,9 +59,11 @@ def _compiled_text(fn, shape, dtype, sharding):
     return jax.jit(fn).lower(arg, arg, arg).compile().as_text()
 
 
-# [batch, seq, heads, head_dim]: GPT-2 small's attention at chip_smoke's LM
-# batch in both dtypes, a 128-wide head, and two lengths the kernel must pad
+# [batch, seq, heads, head_dim]: the benchmark's GPT-2 cell, GPT-2 small's
+# attention at chip_smoke's LM batch in both dtypes, a 128-wide head, and two
+# lengths the kernel must pad
 @pytest.mark.parametrize("shape,dtype", [
+    ((16, 1024, 12, 64), jnp.bfloat16),
     ((8, 1024, 12, 64), jnp.bfloat16),
     ((8, 1024, 12, 64), jnp.float32),
     ((2, 1024, 8, 128), jnp.bfloat16),
@@ -77,10 +79,12 @@ def test_flash_attention_compiles_for_v5e(one_chip, shape, dtype):
     assert bwd.count("tpu_custom_call") >= 3
 
 
-def test_flash_attention_compiles_under_vmap_for_v5e(one_chip):
+# two virtual workers at chip_smoke's batch; the benchmark's cell, one worker
+@pytest.mark.parametrize("shape", [(2, 8, 1024, 12, 64),
+                                   (1, 16, 1024, 12, 64)])
+def test_flash_attention_compiles_under_vmap_for_v5e(one_chip, shape):
     """Virtual workers put the kernel under ``vmap`` (a leading worker axis)
     inside the engine's shard_map and scan."""
-    shape = (2, 8, 1024, 12, 64)
     fwd = _compiled_text(jax.vmap(_kernel), shape, jnp.bfloat16, one_chip)
     assert "tpu_custom_call" in fwd
     bwd = _compiled_text(jax.vmap(jax.grad(_loss, argnums=(0, 1, 2))), shape,
