@@ -138,4 +138,7 @@ def result_line(manifest, cell, run, traced):
             "device": run["device"]}
     if traced and run.get("breakdown"):
         line["breakdown"] = run["breakdown"]
+    if run.get("compared"):
+        # each number that ``correct`` compared beside its limit, last
+        line["compared"] = run["compared"]
     return line

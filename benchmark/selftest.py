@@ -66,20 +66,32 @@ def check_files():
         for key in ("config", "traffic", "chips", "why"):
             assert cell[key] == entry[key], (entry["name"], key)
         assert entry["config"] in configs and 1 <= len(entry["why"]) <= 200
-        assert entry["chips"] in (1, 4) and cell["rate_hint"] > 0
+        assert entry["chips"] in (1, 4)
         four += entry["chips"] == 4
         driver = harness.load_driver(cell["traffic_spec"]["kind"])
-        assert driver.plan(cell, manifest["run_seconds"])["epochs"] >= 5
+        # a kind of driver brings its own check of a cell's files; a driver
+        # without one is a ``train_job``
+        getattr(driver, "check_cell", check_train_cell)(
+            cell, manifest["run_seconds"])
     assert four <= max(1, len(manifest["workloads"]) // 4)
     cells = {w["name"] for w in manifest["workloads"]}
     for path in glob.glob(os.path.join(HERE, "workloads", "*.json")):
         assert os.path.basename(path)[:-5] in cells, f"{path}: not in BENCHMARK.json"
     layers = set()
+    reports = lambda name, cell: harness.applies(end_to_end[name], cell)
+    for metric in manifest["end_to_end"]:
+        assert set(metric.get("workloads", ())) <= cells
+    for cell in cells:  # set-up time and at least one other, in every cell
+        assert reports("setup_s", cell), cell
+        assert sum(reports(name, cell) for name in end_to_end) >= 2, cell
     for metric in manifest["per_layer"]:
         spec = harness.metric_spec(metric["name"])
         assert set(spec) == {"reader", "what"}, metric["name"]
         assert set(metric.get("workloads", ())) <= cells
         assert metric["moves"] in end_to_end
+        # every cell that a metric lists reports the metric that it moves
+        for cell in metric.get("workloads", cells):
+            assert reports(metric["moves"], cell), (metric["name"], cell)
         assert callable(harness.resolve("readers", spec["reader"]))
         layers.add(metric["layer"])
     for metric in manifest["end_to_end"] + manifest["per_layer"]:
@@ -101,7 +113,23 @@ def check_files():
         pass
     else:
         raise AssertionError("an unknown device got peaks")
-    return f"{len(cells)} cells, {len(manifest['per_layer'])} per-layer metrics"
+    # the serving driver's arithmetic, as far as it needs no model: the
+    # repo's suite runs ``selftest.py files``, and so these with it
+    import test_serve
+
+    for test in test_serve.WITHOUT_A_MODEL:
+        test()
+    return (f"{len(cells)} cells, {len(manifest['per_layer'])} per-layer "
+            f"metrics, {len(test_serve.WITHOUT_A_MODEL)} tests of the serving "
+            "driver's arithmetic")
+
+
+def check_train_cell(cell, run_seconds):
+    """What ``train_job`` asks of a cell's files: a rate that sizes the job,
+    and epochs enough for a period and a capture."""
+    assert cell["rate_hint"] > 0
+    driver = harness.load_driver(cell["traffic_spec"]["kind"])
+    assert driver.plan(cell, run_seconds)["epochs"] >= 5
 
 
 # ------------------------------------------------------------------- flops
@@ -401,9 +429,22 @@ def check_refusal():
     return f"exit {done.returncode}, no result line"
 
 
+def check_serve():
+    """The serving quarter: every test of ``test_serve.py`` (the schedule, the
+    window's arithmetic, ``correct`` and its control, the driver end to end
+    on a tiny model and with a token altered underneath)."""
+    import test_serve
+
+    names = sorted(name for name in vars(test_serve) if name.startswith("test_"))
+    for name in names:
+        getattr(test_serve, name)()
+    return f"{len(names)} tests of test_serve.py"
+
+
 CHECKS = {"files": check_files, "flops": check_flops, "watcher": check_watcher,
           "correct": check_correct, "trace": check_trace,
-          "rehearsal": check_rehearsal, "refusal": check_refusal}
+          "rehearsal": check_rehearsal, "serve": check_serve,
+          "refusal": check_refusal}
 
 
 def main(argv):
