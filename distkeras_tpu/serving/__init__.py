@@ -34,7 +34,12 @@ decoding with exact accept/resample semantics; ``mesh`` — tensor-parallel
 decode over the local devices.
 """
 
-from distkeras_tpu.serving.cache import PagedKVCache, append_rows, rollback_rows
+from distkeras_tpu.serving.cache import (
+    PagedKVCache,
+    append_rows,
+    paged_decode_attention,
+    rollback_rows,
+)
 from distkeras_tpu.serving.engine import EngineCrashed, ServingEngine, serving_metrics
 from distkeras_tpu.serving.frontend import (
     GenerateRequest,
@@ -84,6 +89,7 @@ __all__ = [
     "install_http_endpoint",
     "install_tier_endpoint",
     "modified_probs",
+    "paged_decode_attention",
     "rollback_rows",
     "sample_one",
     "sample_tokens",

@@ -14,8 +14,13 @@ continuous-batching formulation (Orca/vLLM):
   temperature/top-k/top-p, active flag, speculative opt-in) is *data*, so
   admitting or retiring a request never retraces (dklint DK102);
 * a **paged KV cache** (:mod:`distkeras_tpu.serving.cache`): K/V pools
-  shared by all slots, per-slot page tables, pages allocated at admission
-  and freed at retirement;
+  shared by all slots (one ``[pages, page, heads*head_dim]`` array a
+  layer, donated to every program and written in place), per-slot page
+  tables, pages allocated at admission and freed at retirement.  A
+  single-token step writes one row a slot and layer and reads the pages
+  block by block as far as the longest live slot reaches
+  (:func:`~distkeras_tpu.serving.cache.paged_decode_attention`): its cost
+  follows what the slots hold, not what they could;
 * between decode steps the host loop **admits** queued requests into free
   slots (prefill) and **retires** finished ones (EOS / max-new-tokens), so
   a long request never convoys short ones;
@@ -81,7 +86,13 @@ from distkeras_tpu.sanitizer import lockwatch
 from distkeras_tpu.telemetry import accounting as _accounting
 from distkeras_tpu.telemetry import runtime as _truntime
 from distkeras_tpu.telemetry.trace import NOOP_SPAN, trace as _trace
-from distkeras_tpu.serving.cache import PagedKVCache, append_rows, rollback_rows
+from distkeras_tpu.serving.cache import (
+    PagedKVCache,
+    append_rows,
+    decode_block_pages,
+    paged_decode_attention,
+    rollback_rows,
+)
 from distkeras_tpu.serving.frontend import (
     GenerateRequest,
     GenerateResult,
@@ -161,6 +172,15 @@ def serving_metrics(registry=None) -> dict:
         "hot_swaps": registry.counter(
             "serving_hot_swaps_total",
             help="in-place param hot-swaps applied by this engine",
+        ),
+        "kv_read": registry.counter(
+            "serving_decode_kv_positions_read_total",
+            help="cache positions the single-token steps' attention was told "
+                 "to cover: per active slot, pos + 1 rounded up to the block",
+        ),
+        "kv_capacity": registry.counter(
+            "serving_decode_kv_positions_capacity_total",
+            help="cache positions those steps could cover: slots x max context",
         ),
     }
 
@@ -357,6 +377,16 @@ class ServingEngine:
     is a ``TrainedModel``, or a ``TransformerLM``/``StagedLM`` (raw or
     behind ``FlaxModel``) plus ``params``.
 
+    What a step reads: the decode step (and the draft's) never gathers a
+    slot's whole window.  In each layer it writes the step's K and V row
+    through the page table in place and attends over blocks of some 128
+    positions with an online softmax, stopping after the block that holds
+    the longest live slot's position; shorter slots are masked inside the
+    block.  ``serving_decode_kv_positions_read_total`` over
+    ``..._capacity_total`` says how much of the slots' capacity the steps
+    were told to cover.  The verify step (``spec_tokens`` rows a slot) and
+    the prefill keep a dense attention of their own width.
+
     Fast-path knobs: ``prefill_buckets`` (width ladder; default
     power-of-two), ``draft_model``/``draft_params``/``spec_tokens``
     (speculative decoding), ``mesh`` (a 1-D tensor-parallel
@@ -382,6 +412,10 @@ class ServingEngine:
             num_pages=num_pages, dtype=dtype,
         )
         self._width = self._cache.max_context()
+        # positions that a single-token step reads at a time (host-side twin
+        # of paged_decode_attention's block, for the kv_read counter)
+        self._kv_block = self._cache.page_size * decode_block_pages(
+            self._cache.page_size, self._cache.pages_per_slot)
         self._buckets = _resolve_buckets(
             prefill_buckets, self._cache.page_size, self._width)
         self._queue = RequestQueue(queue_size)
@@ -411,7 +445,8 @@ class ServingEngine:
             self._psum = lambda x: jax.lax.psum(x, axis)
             from jax.sharding import NamedSharding, PartitionSpec as P
 
-            pool_sharding = NamedSharding(mesh, P(None, None, None, axis, None))
+            # a row holds the heads side by side: sharding it shards the heads
+            pool_sharding = NamedSharding(mesh, P(None, None, axis))
             self._cache.k_pages = jax.device_put(self._cache.k_pages, pool_sharding)
             self._cache.v_pages = jax.device_put(self._cache.v_pages, pool_sharding)
 
@@ -514,7 +549,7 @@ class ServingEngine:
 
         from distkeras_tpu.utils import compat
 
-        pool = P(None, None, None, self._tp_axis, None)
+        pool = (P(None, None, self._tp_axis),) * self._cache.num_layers
         in_specs = (self._target_param_specs(), pool, pool) + (P(),) * n_rest
         out_specs = (pool, pool) + (P(),) * n_out
         # check_vma=True: JAX proves what the P() out_specs claim — the
@@ -563,10 +598,10 @@ class ServingEngine:
                     # stash the whole padded chunk into this slot's pages;
                     # rows past the prompt land on scratch/overwritten pages
                     # and are causally masked below — never attended.
-                    kc = k[0].reshape(npages, ps, *k.shape[-2:])
-                    vc = v[0].reshape(npages, ps, *v.shape[-2:])
-                    pools["k"] = pools["k"].at[li, table].set(kc)
-                    pools["v"] = pools["v"].at[li, table].set(vc)
+                    kc = k[0].reshape(npages, ps, -1)
+                    vc = v[0].reshape(npages, ps, -1)
+                    pools["k"][li] = pools["k"][li].at[table].set(kc)
+                    pools["v"][li] = pools["v"][li].at[table].set(vc)
                     # causal attention over the chunk itself (same masking
                     # math as _SelfAttention._decode_attention)
                     qt = jnp.moveaxis(q, 1, 2)
@@ -593,15 +628,15 @@ class ServingEngine:
                 # draft prefill: only the K/V writes matter — XLA dead-code
                 # eliminates the attention outputs, leaving the cheap qkv
                 # projections per layer
-                pools = {"k": kpool, "v": vpool}
+                pools = {"k": list(kpool), "v": list(vpool)}
                 trunk(params, pools, tokens, table)
-                return pools["k"], pools["v"]
+                return tuple(pools["k"]), tuple(pools["v"])
 
             return prefill_cache_only
 
         def prefill(params, kpool, vpool, tokens, table, length, key,
                     temp, top_k, top_p):
-            pools = {"k": kpool, "v": vpool}
+            pools = {"k": list(kpool), "v": list(vpool)}
             x = trunk(params, pools, tokens, table)
             logits = _head_apply(params["final_ln"], params["head"], x, eps)
             row = jax.lax.dynamic_index_in_dim(
@@ -609,18 +644,21 @@ class ServingEngine:
             )
             key, sub = jax.random.split(key)
             tok = sample_one(row, sub, temp, top_k, top_p)
-            return pools["k"], pools["v"], tok, key
+            return tuple(pools["k"]), tuple(pools["v"]), tok, key
 
         return prefill
 
-    def _build_decode(self):
-        spec, cache = self._spec, self._cache
-        s, ctx = self.num_slots, self._width
+    def _build_step(self, spec: _Spec, psum, *, name: str, qprobs: bool):
+        """One single-token step over all slots, for the target
+        (``decode``) or the draft (``draft_step``, which also returns the
+        *modified* distribution it sampled from, the q of the acceptance
+        test).  Every layer goes through :func:`paged_decode_attention`: the
+        step's row is written in place and the read stops at the longest
+        live slot."""
         eps = spec.ln_eps
-        psum = self._psum
 
-        def decode(params, kpool, vpool, tables, pos, last, keys,
-                   temp, top_k, top_p, active):
+        def step(params, kpool, vpool, tables, pos, last, keys,
+                 temp, top_k, top_p, active):
             # One token for every slot.  Inactive slots compute garbage into
             # the scratch page (their tables point at physical page 0) and
             # sample token 0 — all masked out host-side.
@@ -628,24 +666,13 @@ class ServingEngine:
                 jnp.clip(pos, 0, spec.max_len - 1)
             ]
             x = x[:, None, :]  # [slots, 1, dim]
-            pools = {"k": kpool, "v": vpool}
+            pools = {"k": list(kpool), "v": list(vpool)}
 
             def paged_attend(li):
                 def attend(q, k, v):
-                    pools["k"] = append_rows(pools["k"], li, tables, pos, k)
-                    pools["v"] = append_rows(pools["v"], li, tables, pos, v)
-                    kg = pools["k"][li][tables]
-                    kg = kg.reshape(s, ctx, *kg.shape[-2:])
-                    vg = pools["v"][li][tables]
-                    vg = vg.reshape(s, ctx, *vg.shape[-2:])
-                    scale = 1.0 / jnp.sqrt(q.shape[-1]).astype(q.dtype)
-                    sc = jnp.einsum("shd,skhd->shk", q[:, 0], kg) * scale
-                    mask = jnp.arange(ctx)[None, :] <= pos[:, None]
-                    sc = jnp.where(mask[:, None, :], sc, -jnp.inf)
-                    out = jnp.einsum(
-                        "shk,skhd->shd", jax.nn.softmax(sc, axis=-1), vg
-                    )
-                    return out[:, None]
+                    pools["k"][li], pools["v"][li], out = paged_decode_attention(
+                        pools["k"][li], pools["v"][li], tables, pos, q, k, v)
+                    return out
 
                 return attend
 
@@ -656,56 +683,22 @@ class ServingEngine:
             new_keys, subs = split[:, 0], split[:, 1]
             tok = sample_tokens(logits, subs, temp, top_k, top_p)
             tok = jnp.where(active, tok, 0)
-            return pools["k"], pools["v"], tok, new_keys
+            outs = (tuple(pools["k"]), tuple(pools["v"]), tok)
+            if qprobs:
+                outs += (jax.vmap(modified_probs)(logits, temp, top_k, top_p),)
+            return outs + (new_keys,)
 
-        return decode
+        step.__name__ = name  # the program's name in a device trace
+        return step
+
+    def _build_decode(self):
+        return self._build_step(self._spec, self._psum, name="decode",
+                                qprobs=False)
 
     def _build_draft_step(self):
-        """One single-token draft step over all slots: writes draft K/V at
-        ``pos``, samples the proposal, and returns the draft's *modified*
-        distribution (the q of the acceptance test).  Always replicated."""
-        dspec, cache = self._draft_spec, self._cache
-        s, ctx = self.num_slots, self._width
-        eps = dspec.ln_eps
-
-        def draft_step(params, kpool, vpool, tables, pos, last, keys,
-                       temp, top_k, top_p, active):
-            x = params["tok"][last] + params["pos"][
-                jnp.clip(pos, 0, dspec.max_len - 1)
-            ]
-            x = x[:, None, :]
-            pools = {"k": kpool, "v": vpool}
-
-            def paged_attend(li):
-                def attend(q, k, v):
-                    pools["k"] = append_rows(pools["k"], li, tables, pos, k)
-                    pools["v"] = append_rows(pools["v"], li, tables, pos, v)
-                    kg = pools["k"][li][tables]
-                    kg = kg.reshape(s, ctx, *kg.shape[-2:])
-                    vg = pools["v"][li][tables]
-                    vg = vg.reshape(s, ctx, *vg.shape[-2:])
-                    scale = 1.0 / jnp.sqrt(q.shape[-1]).astype(q.dtype)
-                    sc = jnp.einsum("shd,skhd->shk", q[:, 0], kg) * scale
-                    mask = jnp.arange(ctx)[None, :] <= pos[:, None]
-                    sc = jnp.where(mask[:, None, :], sc, -jnp.inf)
-                    out = jnp.einsum(
-                        "shk,skhd->shd", jax.nn.softmax(sc, axis=-1), vg
-                    )
-                    return out[:, None]
-
-                return attend
-
-            for li, bp in enumerate(params["blocks"]):
-                x = _block_apply(bp, x, paged_attend(li), eps)
-            logits = _head_apply(params["final_ln"], params["head"], x, eps)[:, 0]
-            split = jax.vmap(jax.random.split)(keys)
-            new_keys, subs = split[:, 0], split[:, 1]
-            tok = sample_tokens(logits, subs, temp, top_k, top_p)
-            tok = jnp.where(active, tok, 0)
-            qprobs = jax.vmap(modified_probs)(logits, temp, top_k, top_p)
-            return pools["k"], pools["v"], tok, qprobs, new_keys
-
-        return draft_step
+        """The draft's single-token step.  Always replicated."""
+        return self._build_step(self._draft_spec, None, name="draft_step",
+                                qprobs=True)
 
     def _build_verify(self):
         """The multi-token target step: feed the window ``[last, d_1 ..
@@ -731,16 +724,14 @@ class ServingEngine:
             x = params["tok"][fed] + params["pos"][
                 jnp.clip(positions, 0, spec.max_len - 1)
             ]
-            pools = {"k": kpool, "v": vpool}
+            pools = {"k": list(kpool), "v": list(vpool)}
 
             def paged_attend(li):
                 def attend(q, k, v):
-                    pools["k"] = append_rows(pools["k"], li, tables, pos, k)
-                    pools["v"] = append_rows(pools["v"], li, tables, pos, v)
-                    kg = pools["k"][li][tables]
-                    kg = kg.reshape(s, ctx, *kg.shape[-2:])
-                    vg = pools["v"][li][tables]
-                    vg = vg.reshape(s, ctx, *vg.shape[-2:])
+                    pools["k"][li] = append_rows(pools["k"][li], tables, pos, k)
+                    pools["v"][li] = append_rows(pools["v"][li], tables, pos, v)
+                    kg = pools["k"][li][tables].reshape(s, ctx, *k.shape[-2:])
+                    vg = pools["v"][li][tables].reshape(s, ctx, *v.shape[-2:])
                     scale = 1.0 / jnp.sqrt(q.shape[-1]).astype(q.dtype)
                     sc = jnp.einsum("smhd,skhd->smhk", q, kg) * scale
                     mask = jnp.arange(ctx)[None, None, :] <= positions[:, :, None]
@@ -760,10 +751,11 @@ class ServingEngine:
             out = jnp.where(active[:, None], out, 0)
             # erase the rejected suffix so the pools only ever hold
             # accepted-token K/V between iterations
-            for li in range(len(params["blocks"])):
-                pools["k"] = rollback_rows(pools["k"], li, tables, pos, count, m)
-                pools["v"] = rollback_rows(pools["v"], li, tables, pos, count, m)
-            return pools["k"], pools["v"], out, count, accepted, new_keys
+            for name in ("k", "v"):
+                pools[name] = [rollback_rows(pool, tables, pos, count, m)
+                               for pool in pools[name]]
+            return (tuple(pools["k"]), tuple(pools["v"]), out, count, accepted,
+                    new_keys)
 
         return verify
 
@@ -1240,8 +1232,20 @@ class ServingEngine:
             attrs["tenants"] = tenants
         return _trace.span("serving.decode_step", **attrs)
 
+    def _count_kv_read(self, pos) -> None:
+        """How far the bound by live length engages, from what the host
+        already knows (the traced programs do not change with it): the
+        positions one single-token step is told to cover, each active slot's
+        ``pos + 1`` rounded up to the block, against every slot's whole
+        window."""
+        blocks = -(-(pos[self._active] + 1) // self._kv_block)
+        self._metrics["kv_read"].inc(
+            int(np.minimum(blocks * self._kv_block, self._width).sum()))
+        self._metrics["kv_capacity"].inc(self.num_slots * self._width)
+
     def _plain_once(self) -> None:
         t0 = time.perf_counter()
+        self._count_kv_read(self._pos)
         with self._step_span():
             kp, vp, tok, keys = self._decode(
                 self._spec.params(), self._cache.k_pages, self._cache.v_pages,
@@ -1298,6 +1302,7 @@ class ServingEngine:
             dparams = self._draft_spec.params()
             drafts, qprobs = [], []
             for i in range(m):
+                self._count_kv_read(base_pos + i)
                 dc.k_pages, dc.v_pages, tok, qp, dkeys = self._draft_step(
                     dparams, dc.k_pages, dc.v_pages, tables,
                     jnp.asarray(base_pos + i), last, dkeys, temp, topk, topp,

@@ -348,6 +348,8 @@ def test_serving_metrics_schema_golden():
     m["spec_proposed"].inc(24)
     m["spec_accepted"].inc(19)
     m["hot_swaps"].inc(2)
+    m["kv_read"].inc(768)
+    m["kv_capacity"].inc(1024)
     golden = open(os.path.join(GOLDEN, "serving_metrics.txt")).read()
     assert registry.to_prometheus(labels={"run_id": "fleet1234"}) == golden
     # get-or-create: a second call must hand back the same instruments

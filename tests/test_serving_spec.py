@@ -552,26 +552,26 @@ def test_append_and_rollback_rows_respect_tables():
     cache.alloc(0, 2)
     cache.alloc(1, 2)
     tables = jnp.asarray(cache.tables)
-    pool = cache.k_pages  # zeros [1, pages, 4, 1, 1]
+    pool = cache.k_pages[0]  # one layer: zeros [pages, 4, 1]
 
     rows = jnp.arange(1, 7, dtype=pool.dtype).reshape(2, 3, 1, 1)
     pos = jnp.asarray([3, 6], jnp.int32)  # slot1: rows 6,7 valid, 8 overhangs
-    pool = append_rows(pool, 0, tables, pos, rows)
-    got = np.asarray(pool)[0]
+    pool = append_rows(pool, tables, pos, rows)
+    got = np.asarray(pool)
     t = cache.tables
-    assert got[t[0, 0], 3, 0, 0] == 1          # slot0 logical 3
-    assert got[t[0, 1], 0, 0, 0] == 2          # slot0 logical 4 -> page 2
-    assert got[t[0, 1], 1, 0, 0] == 3
-    assert got[t[1, 1], 2, 0, 0] == 4          # slot1 logical 6 (table row 1)
-    assert got[t[1, 1], 3, 0, 0] == 5
+    assert got[t[0, 0], 3, 0] == 1          # slot0 logical 3
+    assert got[t[0, 1], 0, 0] == 2          # slot0 logical 4 -> page 2
+    assert got[t[0, 1], 1, 0] == 3
+    assert got[t[1, 1], 2, 0] == 4          # slot1 logical 6 (table row 1)
+    assert got[t[1, 1], 3, 0] == 5
     # logical 8 == capacity: redirected to scratch, owned pages untouched
     assert 6 not in got[t[0]] and 6 not in got[t[1, 1]]
 
     # rollback: slot0 keeps 1 of 3 rows, slot1 keeps all (count >= m)
-    pool = rollback_rows(pool, 0, tables, pos, jnp.asarray([1, 3]), 3)
-    got = np.asarray(pool)[0]
-    assert got[t[0, 0], 3, 0, 0] == 1          # kept
-    assert got[t[0, 1], 0, 0, 0] == 0          # rejected -> zeroed
-    assert got[t[0, 1], 1, 0, 0] == 0
-    assert got[t[1, 1], 2, 0, 0] == 4          # other slot untouched
-    assert got[t[1, 1], 3, 0, 0] == 5
+    pool = rollback_rows(pool, tables, pos, jnp.asarray([1, 3]), 3)
+    got = np.asarray(pool)
+    assert got[t[0, 0], 3, 0] == 1          # kept
+    assert got[t[0, 1], 0, 0] == 0          # rejected -> zeroed
+    assert got[t[0, 1], 1, 0] == 0
+    assert got[t[1, 1], 2, 0] == 4          # other slot untouched
+    assert got[t[1, 1], 3, 0] == 5
