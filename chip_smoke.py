@@ -46,9 +46,9 @@ import numpy as np
 
 SEED = 0
 
-#: the headline job — bench.py's ``cifar_cnn_downpour`` (CONFIG_BATCH /
-#: ``_engine_for``): shapes are the configuration's own, only the number of
-#: windows is smoke-sized.  The learning rate (bench.py's is 0.05) and the
+#: the headline job — the paper's ``cifar_cnn_downpour`` (ROADMAP.md R12's
+#: table): shapes are the configuration's own, only the number of
+#: windows is smoke-sized.  The learning rate (the table's is 0.05) and the
 #: data's scale of 0.5 were chosen so that the loss falls on this synthetic
 #: data and the "loss falls" check means something; at 0.05 the CNN diverges
 #: within an epoch, f32 as well as bf16 (measured on the chip).

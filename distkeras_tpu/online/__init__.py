@@ -39,9 +39,6 @@ Wire it up in-process::
 
 or as a daemon deployment: ``Job.online_loop(replicas=3, ...)`` spawns the
 serving tier and the scheduler loop as co-scheduled jobs on one fleet.
-``bench.py --loop`` runs the whole circle — served traffic → captured
-windows → retrain → verified publish → rolling hot-swap — with the chaos
-harness armed.
 """
 
 from distkeras_tpu.online.capture import (

@@ -263,7 +263,7 @@ class WindowedEngine:
         self._sanitize = sanitizer_mod.enabled()
         self._epoch_fns = {}
         #: filled by :meth:`run_epoch_streaming`: source/transfer timing and
-        #: the link-bound verdict for the last streamed epoch (bench reads it)
+        #: the link-bound verdict for the last streamed epoch
         self.last_stream_report = None
         self._link_warned = False
 
@@ -1077,8 +1077,8 @@ class WindowedEngine:
 
         A live executable that is not the one being measured degrades
         steady-state TPU throughput ~15-20% until collected (measured on
-        v5e — bench.py's round-2 lesson); benchmark harnesses call this
-        between calibration and the timed region, then ``gc.collect()``.
+        v5e); a harness that calibrates before it times calls this between
+        the two, then ``gc.collect()``.
         ``keep_multi=(num_epochs, shuffle_seed)`` retains a matching
         :meth:`run_epochs` program — the one about to be timed — so a
         calibration that landed on the same rep count is not recompiled.
@@ -1144,7 +1144,7 @@ class WindowedEngine:
         steady-state unhideable source fraction exceeds 25% it warns once —
         or raises when ``strict_link=True`` (default: the
         ``DISTKERAS_STREAMING_STRICT`` env var).  The measured report is
-        kept on ``self.last_stream_report`` for bench/debug.
+        kept on ``self.last_stream_report``.
 
         ``on_window(state, n)`` (optional) fires after window ``n`` (1-based)
         has been dispatched — the trainers' mid-epoch checkpoint hook (model

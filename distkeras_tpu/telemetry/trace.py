@@ -50,7 +50,7 @@ has no ``h2d_transfer`` or ``device_epoch``, and the readers' medians skip it.
 
 A span with ``phase="step"`` (or data/h2d/commit/...) additionally feeds the
 ``phase_<name>_seconds`` histogram in the global metrics registry on exit,
-with telemetry on — that is where bench.py's phase breakdown comes from.
+with telemetry on — the ``metrics_<pid>.jsonl`` snapshot's phase breakdown.
 ``h2d_transfer`` feeds ``h2d`` and ``device_epoch`` feeds ``step`` (the
 device's epoch, not a host wait); the in-memory path feeds no ``commit``:
 one fused program has no such boundary.  The streaming path's per-window

@@ -2,10 +2,10 @@
 
 A machine that starts cold pays every compile again (tens of seconds for the
 larger epoch programs), so the scripts that run on a chip — ``chip_smoke.py``,
-``bench.py``, ``examples/*.py`` — call :func:`enable_compile_cache` before
-their first jit.  It is deliberately NOT called at package import or from the
-test suite: a library import must not start writing to disk, and the tests
-must compile what they test.
+``benchmark/run.py``, ``examples/*.py`` — call :func:`enable_compile_cache`
+before their first jit.  It is deliberately NOT called at package import or
+from the test suite: a library import must not start writing to disk, and the
+tests must compile what they test.
 
 Where the cache lives is the entry script's to say, not this library's:
 where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and this

@@ -19,8 +19,27 @@ import jax
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_num_cpu_devices", 8)
 
+import sys
+
 import numpy as np
 import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmark")
+
+
+@pytest.fixture
+def harness():
+    """``benchmark/harness.py`` as a run imports it: the checkout and
+    ``benchmark/`` on ``sys.path`` for the test, so that the readers it loads
+    by file find ``flops``, ``harness`` and ``tracelib`` beside them."""
+    added = [p for p in (ROOT, BENCH) if p not in sys.path]
+    sys.path[:0] = added
+    import harness
+
+    yield harness
+    for p in added:
+        sys.path.remove(p)
 
 
 @pytest.fixture(autouse=True)

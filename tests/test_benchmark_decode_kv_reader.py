@@ -2,27 +2,16 @@
 (``benchmark/readers/decode_kv.py``) on hand-made ``facts["marks"]``, found
 the way a run finds it: by the metric's file."""
 
-import os
-import sys
-
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH = os.path.join(ROOT, "benchmark")
 READ = "serving_decode_kv_positions_read_total"
 CAPACITY = "serving_decode_kv_positions_capacity_total"
 
 
 @pytest.fixture
-def reader():
-    added = [p for p in (ROOT, BENCH) if p not in sys.path]
-    sys.path[:0] = added
-    import harness
-
-    yield harness.resolve(
+def reader(harness):
+    return harness.resolve(
         "readers", harness.metric_spec("decode_kv_read_share")["reader"])
-    for p in added:
-        sys.path.remove(p)
 
 
 def _edge(read, capacity, **others):

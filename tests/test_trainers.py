@@ -13,7 +13,14 @@ import pytest
 
 import distkeras_tpu as dk
 from distkeras_tpu.frame import from_numpy
-from distkeras_tpu.models import MLP, FlaxModel
+from distkeras_tpu.models import (
+    CIFARCNN,
+    MLP,
+    MNISTCNN,
+    FlaxModel,
+    ResNet20,
+    TextCNN,
+)
 from distkeras_tpu.predictors import ModelPredictor
 
 
@@ -197,3 +204,58 @@ def test_predictor_integration(toy_classification):
     out = out.with_column("y", y)
     acc = dk.AccuracyEvaluator(prediction_col="p_idx", label_col="y").evaluate(out)
     assert acc > 0.85
+
+
+# The six jobs of the paper (ROADMAP.md R12's table, the source of the
+# benchmark's future cells): model, trainer with its rule's knobs, worker
+# optimizer, classes; rows are the table's shapes and the compute dtype is
+# bfloat16 in all six.  The table's per-worker batch (128-512) and window (16;
+# 32 for the single trainer) are cut to what a CPU runs in seconds.
+PAPER_JOBS = {
+    "cifar_cnn_downpour": (
+        CIFARCNN(), dk.DOWNPOUR, {},
+        ("sgd", {"learning_rate": 0.05, "momentum": 0.9}), (32, 32, 3), 10),
+    "mnist_mlp_single": (
+        MLP(), dk.SingleTrainer, None,
+        ("sgd", {"learning_rate": 0.1}), (784,), 10),
+    "mnist_cnn_downpour": (
+        MNISTCNN(), dk.DOWNPOUR, {},
+        ("sgd", {"learning_rate": 0.05}), (28, 28, 1), 10),
+    "cifar_cnn_aeasgd": (
+        CIFARCNN(), dk.AEASGD, {"rho": 5.0, "learning_rate": 0.05},
+        ("sgd", {"learning_rate": 0.05}), (32, 32, 3), 10),
+    "cifar_resnet20_adag": (
+        ResNet20(), dk.ADAG, {},
+        ("sgd", {"learning_rate": 0.1, "momentum": 0.9}), (32, 32, 3), 10),
+    "imdb_textcnn_dynsgd": (
+        TextCNN(vocab_size=20000, num_classes=2), dk.DynSGD, {},
+        ("adam", {"learning_rate": 1e-3}), (256,), 2),
+}
+
+
+@pytest.mark.parametrize("job", sorted(PAPER_JOBS))
+def test_paper_job_trains_through_its_trainer(job):
+    """Each paper job through its public trainer and ``train(df)``: every
+    epoch's loss is finite and the parameter server counted one commit per
+    worker and window."""
+    module, trainer_cls, rule_knobs, optimizer, shape, classes = PAPER_JOBS[job]
+    workers, batch, window, windows, epochs = 2, 4, 2, 2, 2
+    single = rule_knobs is None
+    rows = (1 if single else workers) * windows * window * batch
+    rng = np.random.default_rng(0)
+    if job == "imdb_textcnn_dynsgd":  # token ids
+        x = rng.integers(0, 1000, size=(rows,) + shape).astype(np.int32)
+    else:
+        x = (0.5 * rng.normal(size=(rows,) + shape)).astype(np.float32)
+    onehot = np.eye(classes, dtype=np.float32)[rng.integers(0, classes, size=rows)]
+    kwargs = {} if single else dict(
+        rule_knobs, num_workers=workers, communication_window=window)
+    t = trainer_cls(FlaxModel(module), loss="categorical_crossentropy",
+                    worker_optimizer=optimizer, metrics=(), batch_size=batch,
+                    num_epoch=epochs, compute_dtype="bfloat16", **kwargs)
+    trained = t.train(from_numpy(x, onehot))
+    losses = t.get_history()["loss"]
+    assert len(losses) == epochs and np.all(np.isfinite(losses)), losses
+    assert np.asarray(trained.predict(x[:batch])).shape == (batch, classes)
+    if not single:
+        assert t.num_updates == workers * windows * epochs

@@ -5,8 +5,7 @@ per-tenant tokens/sec, page-seconds, queue p99, and share-of-fleet from a
 process's ``/ledger`` or the daemon's fleet-merged ``ledger_status``.
 
 ``check`` is the automation gate: exit 0 when nothing is firing, 2 when
-any alert fires, 3 on a source error — the same contract as
-``dkprof compare --budget``, so CI legs compose uniformly.
+any alert fires, 3 on a source error.
 """
 
 from __future__ import annotations
