@@ -308,6 +308,9 @@ def save_data_state(directory: str, data_state, step: int) -> str:
     model step, and power loss cannot un-write one that was reported
     saved."""
     path = data_state_path(directory, step)
+    # the model's asynchronous save beside it may not have made the
+    # directory yet
+    os.makedirs(os.path.dirname(path), exist_ok=True)
     _atomic_write_json(path, data_state.to_json())
     return path
 

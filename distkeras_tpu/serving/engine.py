@@ -24,6 +24,18 @@ continuous-batching formulation (Orca/vLLM):
 * between decode steps the host loop **admits** queued requests into free
   slots (prefill) and **retires** finished ones (EOS / max-new-tokens), so
   a long request never convoys short ones;
+* the loop **dispatches ahead**: what only the device knows (the token just
+  sampled, the RNG keys) is chained from one program into the next as
+  device arrays, and the host reads every program's tokens *one program
+  behind*, while the next one runs — its uploads, dispatches, read-backs
+  and bookkeeping pass under device time instead of between programs.
+  The host knows a request's end by length as a count, so a slot is given
+  back with its last step's dispatch; an EOS is seen one step late (the
+  slot rides that step, its token is dropped).  Wherever the host must see
+  the truth (before it sleeps, hot-swap, cancel, drain, crash, stop) it
+  reads everything first.  One program behind, always: no depth to choose.
+  (The speculative loop is accepted by count, which the host needs before
+  the next window: it stays serial.);
 * SLO metrics through the telemetry registry — TTFT and per-token-latency
   histograms, queue depth, token/request counters — visible on the
   flightdeck ``/metrics`` scrape.
@@ -71,6 +83,7 @@ key chain, so a request's tokens don't change when its neighbours opt in.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import threading
 import time
@@ -160,6 +173,12 @@ def serving_metrics(registry=None) -> dict:
             "serving_decode_steps_total",
             help="target decode/verify iterations (speculative emits >1 "
                  "token per step, so steps/tokens < 1)",
+        ),
+        "decode_chained": registry.counter(
+            "serving_decode_steps_chained_total",
+            help="decode steps dispatched while the step before them was "
+                 "still unread by the host: their token and key inputs were "
+                 "that step's device outputs",
         ),
         "spec_proposed": registry.counter(
             "serving_spec_proposed_total",
@@ -346,9 +365,13 @@ class _Pending:
 
 
 class _SlotState:
-    """Host-side record for one occupied batch slot."""
+    """Host-side record for one request from its prefill's dispatch to its
+    answer.  It holds a batch slot until its last decode step has been
+    dispatched (``steps_left`` reaches 0) or it is retired; its tokens
+    arrive one program behind, so it can outlive the slot by one read."""
 
-    __slots__ = ("pending", "tokens", "plen", "ttft_s", "pages", "admit_t")
+    __slots__ = ("pending", "tokens", "plen", "ttft_s", "pages", "admit_t",
+                 "steps_left", "done")
 
     def __init__(self, pending: _Pending, plen: int):
         self.pending = pending
@@ -356,7 +379,24 @@ class _SlotState:
         self.plen = plen
         self.ttft_s = 0.0
         self.pages = 0        # pages held — the page-seconds numerator
-        self.admit_t = 0.0    # prefill-done wall time — its clock start
+        self.admit_t = 0.0    # prefill-dispatched wall time — its clock start
+        # decode steps still to dispatch: the prefill makes the first token
+        self.steps_left = pending.max_new - 1
+        self.done = False     # resolved: a token that arrives later is dropped
+
+
+class _InFlight:
+    """One dispatched program whose sampled tokens the host has not read:
+    ``tok`` is the device array (``[slots]`` of a decode step, a scalar of a
+    prefill), ``rows`` the ``(slot, state)`` pairs it sampled for, and
+    ``prefill`` a prefill's ``(queue wait, loop time)`` for the ledger."""
+
+    __slots__ = ("tok", "rows", "prefill")
+
+    def __init__(self, tok, rows, prefill=None):
+        self.tok = tok
+        self.rows = rows
+        self.prefill = prefill
 
 
 # -------------------------------------------------------------------- engine
@@ -376,6 +416,20 @@ class ServingEngine:
     ``submit``/``generate`` (or explicitly via :meth:`start`).  ``model``
     is a ``TrainedModel``, or a ``TransformerLM``/``StagedLM`` (raw or
     behind ``FlaxModel``) plus ``params``.
+
+    The loop never blocks on the program it has just dispatched.  The
+    decode step's token, position and key inputs are the device outputs
+    of the program before it (a prefill seats its first token and key
+    into the slot's place on the device); tables, temperature, top-k,
+    top-p and the active flags are uploaded when an admit or a retire
+    changed them.  Tokens reach the host, and ``ttft_s`` is stamped, one
+    program behind.  ``serving_decode_steps_chained_total`` over
+    ``serving_decode_steps_total`` says how often a step was dispatched
+    with the step before it still unread.  ``cancel`` / ``drain`` /
+    ``hot_swap`` / ``stop`` and a crash read everything in flight first:
+    the tokens a caller gets back are those the device made.  With a
+    ``draft_model`` the loop is serial (the accepted count decides the
+    next window).
 
     What a step reads: the decode step (and the draft's) never gathers a
     slot's whole window.  In each layer it writes the step's K and V row
@@ -482,15 +536,30 @@ class ServingEngine:
 
         s = self.num_slots
         self._slots: List[Optional[_SlotState]] = [None] * s
+        # host mirrors, advanced by count (never read back from the device)
         self._pos = np.zeros(s, np.int32)        # position of the fed token
-        self._last = np.zeros(s, np.int32)       # token being fed this step
-        self._keys = np.zeros((s, 2), np.uint32)
-        self._draft_keys = np.zeros((s, 2), np.uint32)
         self._temp = np.zeros(s, np.float32)
         self._topk = np.zeros(s, np.int32)
         self._topp = np.ones(s, np.float32)
         self._active = np.zeros(s, bool)
         self._spec_on = np.zeros(s, bool)
+        # what only the device knows.  The speculative loop is serial and
+        # keeps it in these host arrays; the plain loop chains it on the
+        # device (_dev) and never reads the keys back at all.
+        self._last = np.zeros(s, np.int32)       # token being fed this step
+        self._keys = np.zeros((s, 2), np.uint32)
+        self._draft_keys = np.zeros((s, 2), np.uint32)
+        # the decode step's per-slot inputs as device arrays: last, keys and
+        # pos are the previous program's outputs; tables, pos, temp, top_k,
+        # top_p and active are uploaded again when an admit or a retire
+        # changed them (_dirty), not every step
+        self._dev: Dict[str, Any] = {
+            "last": jnp.zeros(s, jnp.int32),
+            "keys": jnp.zeros((s, 2), jnp.uint32),
+        }
+        self._dirty = True
+        # programs whose tokens are still on the device, oldest first
+        self._inflight: collections.deque = collections.deque()
 
         self._cv = lockwatch.maybe_wrap(threading.Condition(), "serving.engine")
         self._running = False
@@ -510,7 +579,7 @@ class ServingEngine:
         self._prefill_fns: Dict[Tuple[str, int], Any] = {}
         if self._draft_spec is None:
             self._decode = jax.jit(
-                self._maybe_shard(self._build_decode(), n_rest=8, n_out=2),
+                self._maybe_shard(self._build_decode(), n_rest=8, n_out=3),
                 donate_argnums=(1, 2))
         else:
             self._draft_step = jax.jit(
@@ -573,7 +642,7 @@ class ServingEngine:
                     self._maybe_shard(
                         self._build_prefill(width, spec, sample=True,
                                             psum=self._psum),
-                        n_rest=7, n_out=2),
+                        n_rest=10, n_out=3),
                     donate_argnums=(1, 2))
             else:
                 fn = jax.jit(
@@ -635,7 +704,7 @@ class ServingEngine:
             return prefill_cache_only
 
         def prefill(params, kpool, vpool, tokens, table, length, key,
-                    temp, top_k, top_p):
+                    temp, top_k, top_p, last, keys, slot):
             pools = {"k": list(kpool), "v": list(vpool)}
             x = trunk(params, pools, tokens, table)
             logits = _head_apply(params["final_ln"], params["head"], x, eps)
@@ -644,7 +713,11 @@ class ServingEngine:
             )
             key, sub = jax.random.split(key)
             tok = sample_one(row, sub, temp, top_k, top_p)
-            return tuple(pools["k"]), tuple(pools["v"]), tok, key
+            # seat the first token and the request's key into the slot's
+            # place in the decode step's inputs, here on the device: the
+            # next step can be dispatched before the host has seen either
+            return (tuple(pools["k"]), tuple(pools["v"]), tok,
+                    last.at[slot].set(tok), keys.at[slot].set(key))
 
         return prefill
 
@@ -654,7 +727,9 @@ class ServingEngine:
         *modified* distribution it sampled from, the q of the acceptance
         test).  Every layer goes through :func:`paged_decode_attention`: the
         step's row is written in place and the read stops at the longest
-        live slot."""
+        live slot.  Its last three outputs (tokens, ``pos`` advanced for the
+        active slots, keys) are the next step's ``last``, ``pos`` and
+        ``keys``."""
         eps = spec.ln_eps
 
         def step(params, kpool, vpool, tables, pos, last, keys,
@@ -686,7 +761,9 @@ class ServingEngine:
             outs = (tuple(pools["k"]), tuple(pools["v"]), tok)
             if qprobs:
                 outs += (jax.vmap(modified_probs)(logits, temp, top_k, top_p),)
-            return outs + (new_keys,)
+            # tok, the next positions and the keys are the next step's
+            # last, pos and keys: the host chains them without reading them
+            return outs + (pos + active.astype(pos.dtype), new_keys)
 
         step.__name__ = name  # the program's name in a device trace
         return step
@@ -785,6 +862,8 @@ class ServingEngine:
             self._cv.notify_all()
         if thread is not None:
             thread.join(timeout=timeout)
+        if thread is None or not thread.is_alive():
+            self._flush()  # the loop's own on its way out; here if it died
         for slot in range(self.num_slots):
             if self._slots[slot] is not None:
                 self._retire(slot, "aborted")
@@ -931,8 +1010,9 @@ class ServingEngine:
                     running, acked = self._running, self._drain_ack
                 if not running:
                     return True  # stopped/crashed under us — slots are clear
-                if acked and not self._active.any():
-                    return True
+                if (acked and not self._active.any()
+                        and not self._inflight):
+                    return True  # nothing on the device, nothing unread
                 time.sleep(0.002)
             return False
 
@@ -1004,16 +1084,29 @@ class ServingEngine:
     # ------------------------------------------------------------ host loop
 
     def _loop(self) -> None:
+        """The host loop.  It never blocks on the program it has just
+        dispatched: an iteration dispatches its prefills (``_admit``) and its
+        decode step, each fed by the device outputs of the program before,
+        and only then reads the tokens of everything older than the newest
+        program (``_read_behind``) while that one runs.  The host's own work
+        (uploads, dispatch, read-back latency, bookkeeping, admission) then
+        passes under device time.  Wherever the host must see the truth it
+        reads everything first (``_flush``): before it sleeps, before a
+        hot-swap is applied, before a cancel, on a crash and on the way
+        out."""
         while True:
             try:
                 with self._cv:
-                    if not self._running:
-                        return
+                    running = self._running
                     self._drain_ack = self._draining
                     swap_pending = self._swap is not None
                     paused = self._draining or swap_pending
+                if not running:
+                    self._flush()  # stop() gets back what the device made
+                    return
                 self._cancel_requested()
                 if swap_pending and not self._active.any():
+                    self._flush()  # what is in flight ran on the old params
                     self._apply_swap()
                     with self._cv:
                         paused = self._draining
@@ -1025,6 +1118,7 @@ class ServingEngine:
                     _chaos.fault("replica")
                 progressed = self._decode_once() or progressed
                 if not progressed:
+                    self._flush()  # nothing new to run behind: read it all
                     with self._cv:
                         if (self._running and self._swap is None
                                 and not self._cancelled
@@ -1040,6 +1134,7 @@ class ServingEngine:
             if not self._cancelled:
                 return
             cancelled, self._cancelled = self._cancelled, []
+        self._flush()  # a cancelled request hands back what the device made
         for pending in cancelled:
             if pending.done():
                 continue
@@ -1074,6 +1169,7 @@ class ServingEngine:
             self._running = False
             self._thread = None
             self._cv.notify_all()
+        self._flush()  # the partial tokens are those the device made
         for slot in range(self.num_slots):
             if self._slots[slot] is not None:
                 self._retire(slot, "aborted")
@@ -1109,12 +1205,18 @@ class ServingEngine:
         return admitted
 
     def _prefill_into(self, slot: int, pending: _Pending, need: int) -> None:
+        """Dispatch one prefill into ``slot`` and do not wait for it: the
+        program seats its first token and its key into the decode step's
+        inputs on the device, and the token is read one program behind
+        (``_first_token``), where ``ttft_s`` is stamped.  The speculative
+        engine's loop is serial and reads it at once."""
         req = pending.request
         plen = len(req.prompt)
         self._cache.alloc(slot, need)
         # smallest bucket that fits the prompt (the ladder always ends at
         # max_context and submit bounded plen, so next() can't exhaust)
         width = next(w for w in self._buckets if w >= plen)
+        serial = self._draft_spec is not None
         t0 = time.perf_counter()
         span = NOOP_SPAN
         if _truntime.enabled():
@@ -1131,21 +1233,26 @@ class ServingEngine:
             if req.tenant:
                 attrs["tenant"] = req.tenant
             span = _trace.span("serving.prefill", **attrs)
+        state = _SlotState(pending, plen)
+        state.pages = need
         with span:
             tokens = np.zeros((1, width), np.int32)
             tokens[0, :plen] = req.prompt
             tokens_dev = jnp.asarray(tokens)
-            table = jnp.asarray(
-                self._cache.tables[slot, : width // self._cache.page_size])
-            kp, vp, tok, key = self._prefill_for(width)(
+            # a copy: the host goes on writing the table while this runs
+            table = jnp.asarray(self._cache.tables[
+                slot, : width // self._cache.page_size].copy())
+            last, keys = ((self._last, self._keys) if serial
+                          else (self._dev["last"], self._dev["keys"]))
+            kp, vp, tok, last, keys = self._prefill_for(width)(
                 self._spec.params(), self._cache.k_pages,
-                self._cache.v_pages, tokens_dev, table, jnp.int32(plen),
-                jax.random.PRNGKey(req.seed), jnp.float32(req.temperature),
-                jnp.int32(req.top_k), jnp.float32(req.top_p),
+                self._cache.v_pages, tokens_dev, table, np.int32(plen),
+                jax.random.PRNGKey(req.seed), np.float32(req.temperature),
+                np.int32(req.top_k), np.float32(req.top_p),
+                last, keys, np.int32(slot),
             )
             self._cache.k_pages, self._cache.v_pages = kp, vp
-            spec_on = (self._draft_spec is not None
-                       and req.speculative is not False)
+            spec_on = serial and req.speculative is not False
             if spec_on:
                 dc = self._draft_cache
                 dkp, dvp = self._prefill_for(width, role="draft")(
@@ -1155,44 +1262,37 @@ class ServingEngine:
                 # a draft chain decorrelated from the request's target chain
                 self._draft_keys[slot] = np.asarray(
                     jax.random.fold_in(jax.random.PRNGKey(req.seed), 7))
-            tok0 = int(np.asarray(tok))
+            if serial:
+                self._keys = np.array(keys)  # np.array: a writable host copy
+            else:
+                self._dev["last"], self._dev["keys"] = last, keys
         now = time.perf_counter()
         self._metrics["prefill_seconds"].observe(now - t0)
         self._metrics["prefill_padded"].inc(width - plen)
 
-        state = _SlotState(pending, plen)
-        state.tokens.append(tok0)
-        state.ttft_s = now - pending.enqueue_t
-        state.pages = need
         state.admit_t = now
-        self._metrics["ttft"].observe(state.ttft_s)
-        self._metrics["tokens"].inc()
-        if self._ledger is not None:
-            # prompt tokens, queue wait, prefill device-seconds, and the
-            # first sampled token bill at admission — all host-visible
-            self._ledger.admit(
-                req.tenant, prompt_tokens=plen,
-                queue_wait_s=t0 - pending.enqueue_t,
-                device_s=now - t0, generated=1)
         self._slots[slot] = state
         self._pos[slot] = plen
-        self._last[slot] = tok0
-        self._keys[slot] = np.asarray(key)
         self._temp[slot] = req.temperature
         self._topk[slot] = req.top_k
         self._topp[slot] = req.top_p
-        self._active[slot] = True
+        # an answer of one token takes no decode step: its slot only waits
+        # for the read
+        self._active[slot] = state.steps_left > 0
         self._spec_on[slot] = spec_on
+        self._dirty = True
+        self._inflight.append(_InFlight(
+            tok, [(slot, state)], prefill=(t0 - pending.enqueue_t, now - t0)))
         self._refresh_gauges()
-
-        if req.eos_id is not None and tok0 == req.eos_id:
-            self._retire(slot, "eos")
-        elif len(state.tokens) >= pending.max_new:
-            self._retire(slot, "length")
+        if serial:
+            self._flush()
+            if self._slots[slot] is state:
+                self._last[slot] = state.tokens[0]
 
     def _decode_once(self) -> bool:
         """One engine iteration over every active slot: a plain decode
-        step, or (with a draft model) m draft steps + one verify step."""
+        step dispatched ahead of the last one's read, or (with a draft
+        model, serially) m draft steps + one verify step."""
         if not self._active.any():
             return False
         if self._draft_spec is not None:
@@ -1243,45 +1343,124 @@ class ServingEngine:
             int(np.minimum(blocks * self._kv_block, self._width).sum()))
         self._metrics["kv_capacity"].inc(self.num_slots * self._width)
 
+    def _step_inputs(self) -> Tuple[Any, ...]:
+        """The decode step's eight per-slot inputs, as device arrays.  What
+        an admit or a retire changed is uploaded again, in one transfer and
+        as copies (the host goes on writing its mirrors while the step is in
+        flight); ``last``, ``keys`` and, on a steady step, ``pos`` are the
+        outputs of the program before."""
+        dev = self._dev
+        if self._dirty:
+            active = self._active.copy()
+            # a slot that takes no part writes its row to the scratch page
+            tables = self._cache.tables * active[:, None]
+            (dev["tables"], dev["pos"], dev["temp"], dev["top_k"],
+             dev["top_p"], dev["active"]) = jax.device_put(
+                (tables, self._pos * active, self._temp.copy(),
+                 self._topk.copy(), self._topp.copy(), active))
+            self._dirty = False
+        return (dev["tables"], dev["pos"], dev["last"], dev["keys"],
+                dev["temp"], dev["top_k"], dev["top_p"], dev["active"])
+
     def _plain_once(self) -> None:
+        """Dispatch the next decode step, then read what is behind it.  The
+        step's token, position and key inputs are the device outputs of the
+        program before (no host round trip); the host advances its mirror
+        of ``pos`` by count and releases a slot whose last step this was
+        (its end by length is a count the host has), so no step is spent on
+        a finished slot.  Only then are the tokens of the step before and of
+        this iteration's prefills read, while this step runs.  ``eos_id`` is
+        therefore seen one step late: that slot rides this step too and its
+        overrun token is dropped.  The call's wall time (dispatch of this
+        step, wait for and read-back of the previous) is one observation of
+        ``serving_token_latency_seconds``."""
         t0 = time.perf_counter()
         self._count_kv_read(self._pos)
         with self._step_span():
-            kp, vp, tok, keys = self._decode(
+            kp, vp, tok, pos, keys = self._decode(
                 self._spec.params(), self._cache.k_pages, self._cache.v_pages,
-                jnp.asarray(self._cache.tables), jnp.asarray(self._pos),
-                jnp.asarray(self._last), jnp.asarray(self._keys),
-                jnp.asarray(self._temp), jnp.asarray(self._topk),
-                jnp.asarray(self._topp), jnp.asarray(self._active),
-            )
+                *self._step_inputs())
             self._cache.k_pages, self._cache.v_pages = kp, vp
-            toks = np.asarray(tok)      # device sync: the step is done here
-        self._keys = np.array(keys)     # np.array: keep the host copy writable
-        dt = time.perf_counter() - t0
-        self._metrics["token_latency"].observe(dt)
-        self._metrics["decode_steps"].inc()
-        ledger = self._ledger
-        # device-seconds estimate: the step's wall time split evenly over
-        # the slots it decoded for (captured before retirements mutate it)
-        share = dt / max(1, int(self._active.sum()))
+            self._dev.update(last=tok, pos=pos, keys=keys)
+            self._metrics["decode_steps"].inc()
+            if any(rec.prefill is None for rec in self._inflight):
+                # the step before is unread: this one took its outputs
+                self._metrics["decode_chained"].inc()
+            rows = [(int(slot), self._slots[slot])
+                    for slot in np.flatnonzero(self._active)]
+            self._inflight.append(_InFlight(tok, rows))
+            self._pos[self._active] += 1
+            for slot, state in rows:
+                state.steps_left -= 1
+                if state.steps_left <= 0:
+                    self._release(slot)
+            self._read_behind(1, t0)
+        self._metrics["token_latency"].observe(time.perf_counter() - t0)
 
-        for slot in range(self.num_slots):
-            state = self._slots[slot]
-            if state is None or not self._active[slot]:
-                continue
-            t = int(toks[slot])
-            state.tokens.append(t)
-            self._metrics["tokens"].inc()
+    # ---------------------------------------------- reading one program behind
+
+    def _read_behind(self, keep: int, t0: float) -> None:
+        """Read the tokens of every program in flight but the newest
+        ``keep``, oldest first, blocking on each until the device has made
+        them.  ``t0`` is when the caller's own work began: the ledger's
+        share of the loop's time for a step."""
+        while len(self._inflight) > keep:
+            rec = self._inflight.popleft()
+            toks = np.asarray(rec.tok)  # device sync: that program is done
+            now = time.perf_counter()
+            if rec.prefill is not None:
+                self._first_token(rec, int(toks), now)
+            else:
+                self._step_tokens(rec, toks, now - t0)
+
+    def _flush(self) -> None:
+        """Read everything in flight: the host sees what the device made."""
+        self._read_behind(0, time.perf_counter())
+
+    def _first_token(self, rec: _InFlight, tok0: int, now: float) -> None:
+        (slot, state), = rec.rows
+        pending = state.pending
+        state.ttft_s = now - pending.enqueue_t  # the token is on the host
+        self._metrics["ttft"].observe(state.ttft_s)
+        if self._ledger is not None:
+            # prompt tokens, queue wait, prefill device-seconds, and the
+            # first sampled token bill with the first token — all
+            # host-visible
+            queue_wait_s, device_s = rec.prefill
+            self._ledger.admit(
+                pending.request.tenant, prompt_tokens=state.plen,
+                queue_wait_s=queue_wait_s, device_s=device_s, generated=1)
+        self._emit(slot, state, tok0)
+
+    def _step_tokens(self, rec: _InFlight, toks, dt: float) -> None:
+        ledger = self._ledger
+        # device-seconds estimate: the loop's time for the step split evenly
+        # over the slots it decoded for
+        share = dt / max(1, len(rec.rows))
+        for slot, state in rec.rows:
+            if state.done:
+                continue  # retired since (EOS a step late, a cancel): dropped
             if ledger is not None:
                 ledger.decode(state.pending.request.tenant,
                               tokens=1, device_s=share)
-            self._pos[slot] += 1
-            self._last[slot] = t
-            eos = state.pending.request.eos_id
-            if eos is not None and t == eos:
-                self._retire(slot, "eos")
-            elif len(state.tokens) >= state.pending.max_new:
-                self._retire(slot, "length")
+            self._emit(slot, state, int(toks[slot]))
+
+    def _emit(self, slot: int, state: _SlotState, t: int) -> None:
+        """Hand one read token to its request; finish it at EOS or at its
+        length.  The slot is the request's still unless its last step was
+        dispatched before (``_plain_once``)."""
+        state.tokens.append(t)
+        self._metrics["tokens"].inc()
+        eos = state.pending.request.eos_id
+        if eos is not None and t == eos:
+            reason = "eos"
+        elif len(state.tokens) >= state.pending.max_new:
+            reason = "length"
+        else:
+            return
+        if self._slots[slot] is state:
+            self._release(slot)
+        self._resolve(state, reason)
 
     def _spec_once(self) -> None:
         """One speculative iteration: chain m draft steps (device arrays
@@ -1303,7 +1482,7 @@ class ServingEngine:
             drafts, qprobs = [], []
             for i in range(m):
                 self._count_kv_read(base_pos + i)
-                dc.k_pages, dc.v_pages, tok, qp, dkeys = self._draft_step(
+                dc.k_pages, dc.v_pages, tok, qp, _, dkeys = self._draft_step(
                     dparams, dc.k_pages, dc.v_pages, tables,
                     jnp.asarray(base_pos + i), last, dkeys, temp, topk, topp,
                     active)
@@ -1364,7 +1543,11 @@ class ServingEngine:
                 self._pos[slot] += emitted
                 self._last[slot] = int(out[slot, emitted - 1])
 
-    def _retire(self, slot: int, reason: str) -> None:
+    def _release(self, slot: int) -> None:
+        """Give the slot and its pages back (loop thread): after the
+        request's last step was dispatched, or with its retirement.  The
+        device's program order keeps a later prefill into these pages behind
+        every step that still writes them."""
         state = self._slots[slot]
         if self._ledger is not None:
             # page-seconds sample at slot free: pages held x wall time
@@ -1380,8 +1563,17 @@ class ServingEngine:
         self._temp[slot] = 0.0
         self._topk[slot] = 0
         self._topp[slot] = 1.0
-        self._finish(state.pending, state.tokens, reason, state.ttft_s)
+        self._dirty = True
         self._refresh_gauges()
+
+    def _retire(self, slot: int, reason: str) -> None:
+        state = self._slots[slot]
+        self._release(slot)
+        self._resolve(state, reason)
+
+    def _resolve(self, state: _SlotState, reason: str) -> None:
+        state.done = True
+        self._finish(state.pending, state.tokens, reason, state.ttft_s)
 
     def _finish(self, pending: _Pending, tokens: List[int], reason: str,
                 ttft_s: float) -> None:

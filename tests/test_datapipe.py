@@ -263,6 +263,20 @@ def test_data_state_sidecar_save_restore(tmp_path):
     assert ckpt_mod.restore_data_state(d, step=99) is None
 
 
+def test_data_state_sidecar_lands_in_a_directory_not_yet_made(tmp_path):
+    """The sidecar is written beside the model's *asynchronous* save, which
+    may not have made the checkpoint directory yet (seen under six test
+    workers: the online scheduler then retrained its window)."""
+    from distkeras_tpu import checkpoint as ckpt_mod
+
+    d = str(tmp_path / "not" / "yet")
+    ds = DataState.capture(4, np.random.default_rng(1), block_cursor=7)
+    path = ckpt_mod.save_data_state(d, ds, step=5)
+    assert os.path.dirname(path) == d and os.path.isfile(path)
+    got = ckpt_mod.restore_data_state(d, step=5)
+    assert (got.epoch, got.block_cursor) == (4, 7)
+
+
 def test_manager_partial_then_boundary_save_and_gc(tmp_path):
     """save_partial writes model + sidecar; the SAME step's later boundary
     save must overwrite the partial (Orbax refuses overwrites unless the

@@ -345,6 +345,7 @@ def test_serving_metrics_schema_golden():
     m["prefill_seconds"].observe(0.006)
     m["prefill_padded"].inc(13)
     m["decode_steps"].inc(17)
+    m["decode_chained"].inc(15)
     m["spec_proposed"].inc(24)
     m["spec_accepted"].inc(19)
     m["hot_swaps"].inc(2)
