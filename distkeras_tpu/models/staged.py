@@ -291,12 +291,14 @@ class StagedLM(StagedTransformer):
         return self.head(params["head"], h), new_cache
 
     def decode_spec(self, params):
-        """Slice staged params into the serving engine's layout
-        (:mod:`distkeras_tpu.serving.engine`): the ``[S, per_stage, ...]``
-        block stack unfolds into a flat per-block list (same order as
-        :meth:`decode_step`'s scan); embed/head are already replicated.
-        Like prediction, serving runs the sequential executor — the
-        pipeline is a training-time schedule."""
+        """What the serving engine serves this model by
+        (:class:`distkeras_tpu.models.decode.DecodeSpec`): the
+        ``[S, per_stage, ...]`` block stack unfolds into a flat per-block
+        list (same order as :meth:`decode_step`'s scan); embed/head are
+        already replicated.  Like prediction, serving runs the sequential
+        executor — the pipeline is a training-time schedule."""
+        from distkeras_tpu.models.decode import transformer_decode_spec
+
         if self.seq_axis is not None:
             raise ValueError(
                 "serving decodes on the single-device twin — build the "
@@ -307,22 +309,13 @@ class StagedLM(StagedTransformer):
             lambda x: x.reshape((-1,) + x.shape[2:]), params["blocks"]
         )
         n_blocks = self.num_stages * self.blocks_per_stage
-        return {
-            "config": {
-                "dim": self.dim, "heads": self.heads,
-                # explicit head geometry for the engine's tensor-parallel
-                # build (global count, independent of kernel sharding)
-                "head_dim": self.dim // self.heads,
-                "num_layers": n_blocks, "max_len": self.max_len,
-                "vocab_size": self.vocab_size, "ln_eps": self.ln_eps,
-            },
-            "embed": {
-                "tok": params["embed"]["tok_embed"]["embedding"],
-                "pos": params["embed"]["pos_embed"]["embedding"],
-            },
-            "blocks": [
-                jax.tree.map(lambda x, i=i: x[i], flat) for i in range(n_blocks)
-            ],
-            "final_ln": params["head"]["LayerNorm_0"],
-            "head": params["head"]["out"],
-        }
+        return transformer_decode_spec(
+            tok=params["embed"]["tok_embed"]["embedding"],
+            pos=params["embed"]["pos_embed"]["embedding"],
+            blocks=[jax.tree.map(lambda x, i=i: x[i], flat)
+                    for i in range(n_blocks)],
+            final_ln=params["head"]["LayerNorm_0"], head=params["head"]["out"],
+            dim=self.dim, heads=self.heads, head_dim=self.dim // self.heads,
+            max_len=self.max_len, vocab_size=self.vocab_size,
+            ln_eps=self.ln_eps,
+        )

@@ -252,36 +252,33 @@ class TransformerLM(nn.Module):
         return nn.Dense(self.vocab_size, name="lm_head")(x)
 
     def decode_spec(self, params):
-        """Slice ``params`` into the layout the serving engine consumes
-        (:mod:`distkeras_tpu.serving.engine`): embedding tables, per-block
-        subtrees, final LayerNorm, LM head, plus static config.  Kept next
-        to the model so the serving layer cannot drift from the param tree
-        this module actually builds."""
+        """What the serving engine serves this model by
+        (:class:`distkeras_tpu.models.decode.DecodeSpec`): the block's two
+        pools a layer and its embedding, prefill, step and head over this
+        module's own param tree.  Kept next to the model so the serving
+        layer cannot drift from the param tree this module actually
+        builds."""
+        from distkeras_tpu.models.decode import transformer_decode_spec
+
         if self.seq_axis is not None:
             raise ValueError(
                 "serving decodes on the single-device twin — build the "
                 "engine from a seq_axis=None model with the same params"
             )
-        return {
-            "config": {
-                "dim": self.dim, "heads": self.heads,
-                # explicit head geometry: the engine's tensor-parallel build
-                # shards the qkv kernels over heads, so the global count must
-                # come from config, not from (shard-local) kernel shapes
-                "head_dim": self.dim // self.heads,
-                "num_layers": self.num_layers, "max_len": self.max_len,
-                "vocab_size": self.vocab_size,
-                # blocks and the final LayerNorm both use the flax default
-                "ln_eps": 1e-6,
-            },
-            "embed": {
-                "tok": params["tok_embed"]["embedding"],
-                "pos": params["pos_embed"]["embedding"],
-            },
-            "blocks": [params[f"block_{i}"] for i in range(self.num_layers)],
-            "final_ln": params["LayerNorm_0"],
-            "head": params["lm_head"],
-        }
+        return transformer_decode_spec(
+            tok=params["tok_embed"]["embedding"],
+            pos=params["pos_embed"]["embedding"],
+            blocks=[params[f"block_{i}"] for i in range(self.num_layers)],
+            final_ln=params["LayerNorm_0"], head=params["lm_head"],
+            dim=self.dim, heads=self.heads,
+            # explicit head geometry: the tensor-parallel build shards the
+            # qkv kernels over heads, so the global count must come from
+            # here, not from (shard-local) kernel shapes
+            head_dim=self.dim // self.heads, max_len=self.max_len,
+            vocab_size=self.vocab_size,
+            # blocks and the final LayerNorm both use the flax default
+            ln_eps=1e-6,
+        )
 
 
 class TransformerClassifier(nn.Module):

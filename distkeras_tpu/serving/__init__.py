@@ -1,13 +1,20 @@
-"""Online inference: continuous batching, paged KV cache, SLO metrics.
+"""Online inference: continuous batching, paged per-layer state, SLO metrics.
 
 The request-level serving layer ROADMAP item 1 calls for — everything the
 training side can only do call-at-a-time (``greedy_generate``) reshaped for
 a service that admits requests whenever they arrive:
 
 * :class:`~distkeras_tpu.serving.engine.ServingEngine` — the decode loop
-  (fixed slot ring, ONE jitted step, prefill-on-admission / retire-on-EOS);
-* :mod:`~distkeras_tpu.serving.cache` — paged KV cache (slot page tables
-  over shared K/V pools);
+  (fixed slot ring, ONE jitted step, prefill-on-admission / retire-on-EOS).
+  It serves any model whose ``decode_spec(params)`` returns a
+  :class:`~distkeras_tpu.models.decode.DecodeSpec` (the block contract: the
+  kinds of state a layer keeps a position, and the model's own embedding,
+  prefill layer, step layer and head): ``TransformerLM``, ``StagedLM``,
+  ``LatentMoELM``;
+* :mod:`~distkeras_tpu.serving.cache` — the paged cache: slot page tables
+  over shared pools, one tuple of per-layer pools for each kind of state the
+  block declares (keys and values; or latent attention's one row for all
+  heads, written by an expanded prefill and read by an absorbed step);
 * :mod:`~distkeras_tpu.serving.sampling` — temperature / top-k / top-p
   with per-request seeds, all traced (no recompiles);
 * :mod:`~distkeras_tpu.serving.frontend` — request/response dataclasses,
@@ -31,7 +38,10 @@ or as a daemon job: ``PunchcardServer``'s ``serve`` verb
 Fast paths (all optional engine kwargs): ``prefill_buckets`` — power-of-two
 prefill width ladder; ``draft_model``/``spec_tokens`` — speculative
 decoding with exact accept/resample semantics; ``mesh`` — tensor-parallel
-decode over the local devices.
+decode over the local devices.  The last two are builds a block opts into
+(``DecodeSpec.window`` / ``.shard``): GPT-2's block brings both,
+``LatentMoELM``'s neither yet, and the engine refuses those combinations at
+construction.
 """
 
 from distkeras_tpu.serving.cache import (

@@ -9,18 +9,28 @@ sampling, and must not pay a compile.  This engine is the standard
 continuous-batching formulation (Orca/vLLM):
 
 * a fixed ring of ``num_slots`` **batch slots**;
+* a **block contract** (:class:`distkeras_tpu.models.decode.DecodeSpec`,
+  what a model's ``decode_spec(params)`` hook returns): the model says what
+  state a layer keeps a position (``(name, row_width)`` pools a layer) and
+  supplies its own embedding, prefill layer, single-token step layer and
+  head; the engine keeps slots, pages, programs, sampling and the loop, and
+  names no submodule and holds no attention arithmetic of any block.
+  ``TransformerLM`` and ``StagedLM`` (two pools a layer, keys and values)
+  and ``LatentMoELM`` (one latent pool a layer) are served by the same loop
+  and the same programs' builders;
 * ONE jitted single-token **decode step** over all slots — every
   per-request quantity (position, last token, RNG key,
   temperature/top-k/top-p, active flag, speculative opt-in) is *data*, so
   admitting or retiring a request never retraces (dklint DK102);
-* a **paged KV cache** (:mod:`distkeras_tpu.serving.cache`): K/V pools
-  shared by all slots (one ``[pages, page, heads*head_dim]`` array a
-  layer, donated to every program and written in place), per-slot page
-  tables, pages allocated at admission and freed at retirement.  A
-  single-token step writes one row a slot and layer and reads the pages
-  block by block as far as the longest live slot reaches
-  (:func:`~distkeras_tpu.serving.cache.paged_decode_attention`): its cost
-  follows what the slots hold, not what they could;
+* a **paged cache** (:mod:`distkeras_tpu.serving.cache`): for each kind of
+  state the block declares, pools shared by all slots (one ``[pages, page,
+  row_width]`` array a layer, donated to every program and written in
+  place), per-slot page tables, pages allocated at admission and freed at
+  retirement.  A single-token step writes one row a slot and layer and
+  reads the pages block by block as far as the longest live slot reaches
+  (:func:`~distkeras_tpu.serving.cache.paged_decode_attention` for keys and
+  values, :func:`~distkeras_tpu.serving.cache.paged_latent_attention` for a
+  latent row): its cost follows what the slots hold, not what they could;
 * between decode steps the host loop **admits** queued requests into free
   slots (prefill) and **retires** finished ones (EOS / max-new-tokens), so
   a long request never convoys short ones;
@@ -49,7 +59,9 @@ Fast paths (each optional, all compile-count pinned):
   lazily; ``serving_prefill_padded_tokens`` counts the padding burned so
   the win is visible on ``/metrics``.
 * **Speculative decoding** (``draft_model``) — a cheaper draft model
-  (anything with a ``decode_spec``, e.g. a shallower ``TransformerLM``)
+  (anything with a ``decode_spec``, e.g. a shallower ``TransformerLM``;
+  the target's block must bring the multi-token ``window`` step, which
+  ``LatentMoELM``'s does not yet: the engine refuses it at construction)
   proposes ``spec_tokens`` tokens per engine iteration via single-token
   draft steps; ONE multi-token target step verifies the window against the
   paged cache and emits the accepted prefix plus a correction token
@@ -61,13 +73,15 @@ Fast paths (each optional, all compile-count pinned):
   requests use exact acceptance-rejection resampling.  Requests opt out per
   call (``speculative=False``) and ride the same program as traced data.
 * **Sharded decode** (``mesh``) — the target's prefill/decode/verify
-  programs run under a tensor-parallel ``shard_map`` (heads sharded, MLP
-  and embeddings replicated), so one engine serves from every local device.
+  programs run under a tensor-parallel ``shard_map`` as the block's
+  ``shard`` twin lays it out (GPT-2's block: heads sharded, MLP and
+  embeddings replicated), so one engine serves from every local device.  A
+  block without a twin (``LatentMoELM``) is refused at construction.
 
-Numerics: the engine re-runs the model's own flax submodules
-(``nn.LayerNorm`` / ``nn.DenseGeneral`` / ``nn.Dense`` / the
-``_decode_attention`` masking math) over param subtrees sliced out by the
-model's ``decode_spec`` hook, so greedy requests emit tokens **bitwise
+Numerics: the GPT-2-shaped block (``models/decode.py``) re-runs the model's
+own flax submodules (``nn.LayerNorm`` / ``nn.DenseGeneral`` / ``nn.Dense`` /
+the ``_decode_attention`` masking math) over its param subtrees, so greedy
+requests emit tokens **bitwise
 identical** to ``greedy_generate`` (tests/test_serving.py pins this under
 staggered concurrent arrival).  Prefill pads the prompt to its bucket
 width — positions past the prompt are causally masked and their cache rows
@@ -84,12 +98,11 @@ key chain, so a request's tokens don't change when its neighbours opt in.
 from __future__ import annotations
 
 import collections
-import dataclasses
+import functools
 import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
-import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -101,9 +114,8 @@ from distkeras_tpu.telemetry import runtime as _truntime
 from distkeras_tpu.telemetry.trace import NOOP_SPAN, trace as _trace
 from distkeras_tpu.serving.cache import (
     PagedKVCache,
-    append_rows,
     decode_block_pages,
-    paged_decode_attention,
+    fit_rows,
     rollback_rows,
 )
 from distkeras_tpu.serving.frontend import (
@@ -201,41 +213,20 @@ def serving_metrics(registry=None) -> dict:
             "serving_decode_kv_positions_capacity_total",
             help="cache positions those steps could cover: slots x max context",
         ),
+        "state_bytes": registry.gauge(
+            "serving_state_per_position_bytes",
+            help="bytes of paged state one position keeps over all layers "
+                 "and kinds of state the served block declares",
+        ),
     }
 
 
-# ------------------------------------------------------------ model slicing
+# ----------------------------------------------------------- the served model
 
 
-@dataclasses.dataclass
-class _Spec:
-    """Normalized decode view of one causal LM: embedding tables, per-block
-    param subtrees, final LN + head, and the static config the step
-    functions close over.  Built from the model's ``decode_spec`` hook."""
-
-    tok: Any
-    pos: Any
-    blocks: List[Any]
-    final_ln: Any
-    head: Any
-    dim: int
-    heads: int
-    head_dim: int
-    max_len: int
-    vocab: int
-    ln_eps: float
-
-    def params(self) -> dict:
-        """The pytree passed (not closed over) to the jitted steps, so big
-        leaves ride as runtime buffers rather than baked constants."""
-        return {
-            "tok": self.tok, "pos": self.pos, "blocks": list(self.blocks),
-            "final_ln": self.final_ln, "head": self.head,
-        }
-
-
-def _resolve_spec(model, params) -> _Spec:
-    """Accept a ``TrainedModel``, a ``FlaxModel`` adapter + params, or a raw
+def _resolve_spec(model, params):
+    """The model's :class:`~distkeras_tpu.models.decode.DecodeSpec`.  Accepts
+    a ``TrainedModel``, a ``FlaxModel`` adapter + params, or a raw
     module/adapter with a ``decode_spec`` hook + params."""
     from distkeras_tpu.models.adapter import FlaxModel, TrainedModel
 
@@ -247,67 +238,32 @@ def _resolve_spec(model, params) -> _Spec:
     if hook is None:
         raise TypeError(
             f"{type(model).__name__} has no decode_spec hook; serving "
-            "supports TransformerLM and StagedLM"
+            "supports TransformerLM, StagedLM and LatentMoELM"
         )
     if params is None:
         raise ValueError(
             "params required when passing a bare module/adapter "
             "(a TrainedModel carries its own)"
         )
-    raw = hook(params)
-    cfg = raw["config"]
-    qkv = raw["blocks"][0]["_SelfAttention_0"]["qkv"]["kernel"]
-    # prefer the config's head geometry (authoritative even if the kernels
-    # are resharded later); fall back to kernel shapes for older hooks
-    return _Spec(
-        tok=jnp.asarray(raw["embed"]["tok"]),
-        pos=jnp.asarray(raw["embed"]["pos"]),
-        blocks=list(raw["blocks"]),
-        final_ln=raw["final_ln"],
-        head=raw["head"],
-        dim=int(cfg["dim"]),
-        heads=int(cfg.get("heads", qkv.shape[-2])),
-        head_dim=int(cfg.get("head_dim", qkv.shape[-1])),
-        max_len=int(cfg["max_len"]),
-        vocab=int(cfg["vocab_size"]),
-        ln_eps=float(cfg["ln_eps"]),
-    )
+    return hook(params)
 
 
-def _block_apply(bp, x, attend, eps, psum=None):
-    """One encoder block over param subtree ``bp``, reusing the model's own
-    flax submodules so the math is bit-identical to training/`generate`.
-    ``attend(q, k, v)`` supplies the paged-cache attention.  Head counts are
-    read off the (possibly shard-local) kernel shapes, so the same function
-    serves both the replicated and the tensor-parallel build; ``psum`` is
-    the cross-shard reduction under ``shard_map`` (None when unsharded)."""
-    ap = bp["_SelfAttention_0"]
-    dim = bp["Dense_1"]["kernel"].shape[-1]
-    mlp = bp["Dense_0"]["kernel"].shape[-1]
-    heads, head_dim = ap["qkv"]["kernel"].shape[-2:]
-    h = nn.LayerNorm(epsilon=eps).apply({"params": bp["LayerNorm_0"]}, x)
-    qkv = nn.DenseGeneral((3, heads, head_dim)).apply({"params": ap["qkv"]}, h)
-    q, k, v = qkv[..., 0, :, :], qkv[..., 1, :, :], qkv[..., 2, :, :]
-    out = attend(q, k, v)
-    if psum is None:
-        h = nn.DenseGeneral(dim, axis=(-2, -1)).apply({"params": ap["proj"]}, out)
-    else:
-        # tensor-parallel: each shard contracts its local heads bias-free,
-        # the psum sums the partials, and the replicated bias is added once
-        # (DenseGeneral per shard would add it axis-size times)
-        h = jnp.einsum("...hd,hdo->...o", out, ap["proj"]["kernel"])
-        h = psum(h) + ap["proj"]["bias"]
-    x = x + h
-    h = nn.LayerNorm(epsilon=eps).apply({"params": bp["LayerNorm_1"]}, x)
-    h = nn.Dense(mlp).apply({"params": bp["Dense_0"]}, h)
-    h = nn.gelu(h)
-    h = nn.Dense(dim).apply({"params": bp["Dense_1"]}, h)
-    return x + h
+def _donated(spec) -> Tuple[int, ...]:
+    """A program's donated arguments: one tuple of pools a kind of state,
+    right behind the weights."""
+    return tuple(range(1, 1 + len(spec.state)))
 
 
-def _head_apply(final_ln, head, x, eps):
-    h = nn.LayerNorm(epsilon=eps).apply({"params": final_ln}, x)
-    return nn.Dense(head["kernel"].shape[-1]).apply({"params": head}, h)
+def _take_pools(spec, args):
+    """A program's arguments behind the weights, split into its pools
+    (``name -> list of per-layer arrays``) and its own inputs."""
+    names = [name for name, _ in spec.state]
+    return ({n: list(p) for n, p in zip(names, args)}, args[len(names):])
+
+
+def _give_pools(spec, pools):
+    """The pools as a program hands them back, ahead of its other outputs."""
+    return tuple(tuple(pools[name]) for name, _ in spec.state)
 
 
 def _resolve_buckets(prefill_buckets, page_size: int, max_context: int):
@@ -389,14 +345,16 @@ class _InFlight:
     """One dispatched program whose sampled tokens the host has not read:
     ``tok`` is the device array (``[slots]`` of a decode step, a scalar of a
     prefill), ``rows`` the ``(slot, state)`` pairs it sampled for, and
-    ``prefill`` a prefill's ``(queue wait, loop time)`` for the ledger."""
+    ``prefill`` a prefill's ``(queue wait, loop time)`` for the ledger,
+    ``aux`` what a block with counters of its own handed back beside."""
 
-    __slots__ = ("tok", "rows", "prefill")
+    __slots__ = ("tok", "rows", "prefill", "aux")
 
-    def __init__(self, tok, rows, prefill=None):
+    def __init__(self, tok, rows, prefill=None, aux=None):
         self.tok = tok
         self.rows = rows
         self.prefill = prefill
+        self.aux = aux  # the block's own counts, for its ``observe``
 
 
 # -------------------------------------------------------------------- engine
@@ -414,8 +372,9 @@ class ServingEngine:
 
     The host loop runs on a daemon thread started lazily by the first
     ``submit``/``generate`` (or explicitly via :meth:`start`).  ``model``
-    is a ``TrainedModel``, or a ``TransformerLM``/``StagedLM`` (raw or
-    behind ``FlaxModel``) plus ``params``.
+    is a ``TrainedModel``, or anything with a ``decode_spec(params)`` hook
+    (``TransformerLM``/``StagedLM``, raw or behind ``FlaxModel``;
+    ``LatentMoELM``) plus ``params``.
 
     The loop never blocks on the program it has just dispatched.  The
     decode step's token, position and key inputs are the device outputs
@@ -432,8 +391,8 @@ class ServingEngine:
     next window).
 
     What a step reads: the decode step (and the draft's) never gathers a
-    slot's whole window.  In each layer it writes the step's K and V row
-    through the page table in place and attends over blocks of some 128
+    slot's whole window.  In each layer it writes the step's rows (K and V,
+    or the one latent row) through the page table in place and attends over blocks of some 128
     positions with an online softmax, stopping after the block that holds
     the longest live slot's position; shorter slots are masked inside the
     block.  ``serving_decode_kv_positions_read_total`` over
@@ -444,7 +403,15 @@ class ServingEngine:
     Fast-path knobs: ``prefill_buckets`` (width ladder; default
     power-of-two), ``draft_model``/``draft_params``/``spec_tokens``
     (speculative decoding), ``mesh`` (a 1-D tensor-parallel
-    ``jax.sharding.Mesh``; ``heads`` must divide by its size).
+    ``jax.sharding.Mesh``; ``heads`` must divide by its size).  The last two
+    need the block's ``window`` and ``shard``; ``LatentMoELM`` brings
+    neither yet, and the constructor says so.
+
+    A block may bring counters of its own (``DecodeSpec.instruments`` /
+    ``observe``: ``LatentMoELM``'s ``serving_moe_*``): its programs hand a
+    few small arrays back beside the tokens, read one program behind with
+    them.  ``serving_state_per_position_bytes`` is the declared state's size
+    for any block.
     """
 
     def __init__(self, model, params=None, *, num_slots: int = 4,
@@ -454,17 +421,39 @@ class ServingEngine:
                  prefill_buckets: Optional[Sequence[int]] = None,
                  draft_model=None, draft_params=None, spec_tokens: int = 4,
                  mesh=None):
-        self._spec = _resolve_spec(model, params)
-        spec = self._spec
+        spec = _resolve_spec(model, params)
+
+        # ------------------------------------------------ tensor parallelism
+        self._mesh = mesh
+        if mesh is not None:
+            if len(mesh.axis_names) != 1:
+                raise ValueError(
+                    "serving mesh must be 1-D (one tensor-parallel axis); "
+                    f"got axes {mesh.axis_names}"
+                )
+            self._tp_axis = mesh.axis_names[0]
+            spec = self._sharded(spec)
+        if draft_model is not None and spec.window is None:
+            raise ValueError(
+                f"{type(model).__name__}'s block has no multi-token window "
+                "step (DecodeSpec.window), so it cannot be the target of "
+                "speculative decoding: build the engine without draft_model="
+            )
+        self._spec = spec
         if pages_per_slot is None:
             pages_per_slot = -(-spec.max_len // page_size)
         self.num_slots = int(num_slots)
         self._cache = PagedKVCache(
-            num_layers=len(spec.blocks), num_slots=num_slots,
+            num_layers=spec.num_layers, num_slots=num_slots,
             page_size=page_size, pages_per_slot=pages_per_slot,
-            heads=spec.heads, head_dim=spec.head_dim,
-            num_pages=num_pages, dtype=dtype,
+            state=spec.state, num_pages=num_pages, dtype=dtype,
         )
+        if mesh is not None:
+            from jax.sharding import NamedSharding
+
+            for name, pools in self._cache.pools.items():
+                self._cache.pools[name] = jax.device_put(
+                    pools, NamedSharding(mesh, spec.pool_specs[name]))
         self._width = self._cache.max_context()
         # positions that a single-token step reads at a time (host-side twin
         # of paged_decode_attention's block, for the kv_read counter)
@@ -474,35 +463,20 @@ class ServingEngine:
             prefill_buckets, self._cache.page_size, self._width)
         self._queue = RequestQueue(queue_size)
         self._metrics = serving_metrics(registry)
+        self._metrics["state_bytes"].set(self._cache.bytes_per_position())
+        # the block's own counters (a DecodeSpec with ``instruments``): its
+        # programs hand their small arrays back with the tokens
+        self._observe = None
+        if spec.observe is not None:
+            if registry is None:
+                from distkeras_tpu.telemetry.metrics import metrics as registry
+            self._observe = functools.partial(
+                spec.observe, spec.instruments(registry))
         # per-tenant ledger (None when DISTKERAS_ACCOUNTING is off): every
         # billing site meters from already-host-visible bookkeeping, so the
         # flag-off path keeps a single `is None` check and the traced
         # programs are byte-identical either way
         self._ledger = _accounting.maybe_ledger(registry)
-
-        # ------------------------------------------------ tensor parallelism
-        self._mesh = mesh
-        self._psum = None
-        if mesh is not None:
-            if len(mesh.axis_names) != 1:
-                raise ValueError(
-                    "serving mesh must be 1-D (one tensor-parallel axis); "
-                    f"got axes {mesh.axis_names}"
-                )
-            self._tp_axis = mesh.axis_names[0]
-            tp = int(mesh.devices.size)
-            if spec.heads % tp:
-                raise ValueError(
-                    f"model heads {spec.heads} not divisible by mesh size {tp}"
-                )
-            axis = self._tp_axis
-            self._psum = lambda x: jax.lax.psum(x, axis)
-            from jax.sharding import NamedSharding, PartitionSpec as P
-
-            # a row holds the heads side by side: sharding it shards the heads
-            pool_sharding = NamedSharding(mesh, P(None, None, axis))
-            self._cache.k_pages = jax.device_put(self._cache.k_pages, pool_sharding)
-            self._cache.v_pages = jax.device_put(self._cache.v_pages, pool_sharding)
 
         # --------------------------------------------------- draft / verify
         self._draft_spec = None
@@ -512,9 +486,10 @@ class ServingEngine:
             if self._spec_tokens < 1:
                 raise ValueError("spec_tokens must be >= 1")
             dspec = _resolve_spec(draft_model, draft_params)
-            if dspec.vocab != spec.vocab:
+            if dspec.vocab_size != spec.vocab_size:
                 raise ValueError(
-                    f"draft vocab {dspec.vocab} != target vocab {spec.vocab}"
+                    f"draft vocab {dspec.vocab_size} != target vocab "
+                    f"{spec.vocab_size}"
                 )
             serviceable = min(self._width, spec.max_len)
             if dspec.max_len < serviceable:
@@ -528,10 +503,10 @@ class ServingEngine:
             # the draft is replicated even under a mesh (it's cheap by
             # construction, and sharding it would serialize two shard_maps)
             self._draft_cache = PagedKVCache(
-                num_layers=len(dspec.blocks), num_slots=num_slots,
+                num_layers=dspec.num_layers, num_slots=num_slots,
                 page_size=page_size, pages_per_slot=pages_per_slot,
-                heads=dspec.heads, head_dim=dspec.head_dim,
-                num_pages=self._cache.num_pages, dtype=dtype,
+                state=dspec.state, num_pages=self._cache.num_pages,
+                dtype=dtype,
             )
 
         s = self.num_slots
@@ -569,64 +544,75 @@ class ServingEngine:
         self._crashed = False
         self._draining = False
         self._drain_ack = False
-        self._swap: Optional[Tuple[_Spec, threading.Event]] = None
+        self._swap: Optional[Tuple[Any, threading.Event]] = None
         self._cancelled: List[_Pending] = []
 
         # Programs compile once per (engine, mesh) config — never per
         # request (the retrace pin in tests/test_serving.py counts on it):
         # one decode OR (one draft step + one verify), plus one prefill per
-        # *used* bucket width, built lazily in _prefill_for.
+        # *used* bucket width, built lazily in _prefill_for.  Every program
+        # takes the weights, then one tuple of per-layer pools a kind of
+        # state (donated, written in place), then its own inputs.
         self._prefill_fns: Dict[Tuple[str, int], Any] = {}
         if self._draft_spec is None:
             self._decode = jax.jit(
                 self._maybe_shard(self._build_decode(), n_rest=8, n_out=3),
-                donate_argnums=(1, 2))
+                donate_argnums=_donated(spec))
         else:
             self._draft_step = jax.jit(
-                self._build_draft_step(), donate_argnums=(1, 2))
+                self._build_draft_step(), donate_argnums=_donated(dspec))
             self._verify = jax.jit(
                 self._maybe_shard(self._build_verify(), n_rest=11, n_out=4),
-                donate_argnums=(1, 2))
+                donate_argnums=_donated(spec))
 
     # ------------------------------------------------------- traced programs
 
-    def _target_param_specs(self):
-        """PartitionSpecs for the target params under the tensor-parallel
-        mesh: qkv sharded over heads, attention proj contracting over the
-        sharded heads, everything else (embeddings, LN, MLP, head)
-        replicated."""
-        from jax.sharding import PartitionSpec as P
-
-        axis = self._tp_axis
-        with self._cv:
-            spec = self._spec
-        specs = jax.tree.map(lambda _: P(), spec.params())
-        for bs in specs["blocks"]:
-            ap = bs["_SelfAttention_0"]
-            ap["qkv"]["kernel"] = P(None, None, axis, None)
-            ap["qkv"]["bias"] = P(None, axis, None)
-            ap["proj"]["kernel"] = P(axis, None, None)
-        return specs
+    def _sharded(self, spec):
+        """The spec's tensor-parallel twin for this engine's mesh."""
+        if spec.shard is None:
+            raise ValueError(
+                "this model's block has no tensor-parallel build "
+                "(DecodeSpec.shard): build the engine without mesh="
+            )
+        return spec.shard(self._tp_axis, int(self._mesh.devices.size))
 
     def _maybe_shard(self, fn, n_rest: int, n_out: int):
-        """Wrap a ``(params, kpool, vpool, *rest) -> (kpool, vpool, *outs)``
-        step in a tensor-parallel shard_map when the engine has a mesh.
-        Pools are heads-sharded; every other input/output is replicated."""
+        """Wrap a ``(params, *pools, *rest) -> (*pools, *outs)`` step in a
+        tensor-parallel shard_map when the engine has a mesh.  Weights and
+        pools are sharded as the block's twin says; every other input/output
+        is replicated."""
         if self._mesh is None:
             return fn
         from jax.sharding import PartitionSpec as P
 
         from distkeras_tpu.utils import compat
 
-        pool = (P(None, None, self._tp_axis),) * self._cache.num_layers
-        in_specs = (self._target_param_specs(), pool, pool) + (P(),) * n_rest
-        out_specs = (pool, pool) + (P(),) * n_out
+        with self._cv:
+            spec = self._spec
+        pools = tuple((spec.pool_specs[name],) * spec.num_layers
+                      for name, _ in spec.state)
+        in_specs = (spec.param_specs,) + pools + (P(),) * n_rest
+        out_specs = pools + (P(),) * n_out
         # check_vma=True: JAX proves what the P() out_specs claim — the
         # sampled outputs are replicated because the inputs are and every
         # cross-head contraction is psummed
         return compat.shard_map(
             fn, self._mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=True)
+
+    @staticmethod
+    def _pools_of(cache):
+        """The cache's pools in the order of its state: a program's pool
+        arguments."""
+        return tuple(cache.pools[name] for name, _ in cache.state)
+
+    @staticmethod
+    def _keep(cache, out):
+        """Take a program's pools back into ``cache`` (it donated them);
+        returns the program's other outputs."""
+        for (name, _), pools in zip(cache.state, out):
+            cache.pools[name] = pools
+        return out[len(cache.state):]
 
     def _prefill_for(self, width: int, role: str = "target"):
         """The jitted prefill program for one bucket width, compiled on
@@ -640,156 +626,132 @@ class ServingEngine:
             if role == "target":
                 fn = jax.jit(
                     self._maybe_shard(
-                        self._build_prefill(width, spec, sample=True,
-                                            psum=self._psum),
+                        self._build_prefill(width, spec, sample=True),
                         n_rest=10, n_out=3),
-                    donate_argnums=(1, 2))
+                    donate_argnums=_donated(spec))
             else:
+                dspec = self._draft_spec
                 fn = jax.jit(
-                    self._build_prefill(width, self._draft_spec, sample=False,
-                                        psum=None),
-                    donate_argnums=(1, 2))
+                    self._build_prefill(width, dspec, sample=False),
+                    donate_argnums=_donated(dspec))
             self._prefill_fns[key] = fn
         return fn
 
-    def _build_prefill(self, width: int, spec: _Spec, *, sample: bool, psum):
+    def _build_prefill(self, width: int, spec, *, sample: bool):
         ps = self._cache.page_size
         npages = width // ps
-        eps = spec.ln_eps
+        counted = sample and spec.observe is not None
 
-        def trunk(params, pools, tokens, table):
+        def trunk(params, pools, tokens, table, live):
             # tokens [1, width] right-padded; table [npages].
-            positions = jnp.clip(jnp.arange(width), 0, spec.max_len - 1)
-            x = params["tok"][tokens] + params["pos"][positions][None]
+            positions = jnp.arange(width)[None]
+            x = spec.embed(params, tokens, positions)
+            aux = []
+            for li in range(spec.num_layers):
+                def write(name, rows, li=li):
+                    # the whole padded chunk into this slot's pages; rows
+                    # past the prompt land on scratch/overwritten pages
+                    pool = pools[name][li]
+                    pools[name][li] = pool.at[table].set(
+                        fit_rows(rows.reshape(npages, ps, -1), pool))
 
-            def paged_attend(li):
-                def attend(q, k, v):
-                    # stash the whole padded chunk into this slot's pages;
-                    # rows past the prompt land on scratch/overwritten pages
-                    # and are causally masked below — never attended.
-                    kc = k[0].reshape(npages, ps, -1)
-                    vc = v[0].reshape(npages, ps, -1)
-                    pools["k"][li] = pools["k"][li].at[table].set(kc)
-                    pools["v"][li] = pools["v"][li].at[table].set(vc)
-                    # causal attention over the chunk itself (same masking
-                    # math as _SelfAttention._decode_attention)
-                    qt = jnp.moveaxis(q, 1, 2)
-                    kt = jnp.moveaxis(k, 1, 2)
-                    vt = jnp.moveaxis(v, 1, 2)
-                    scale = 1.0 / jnp.sqrt(q.shape[-1]).astype(q.dtype)
-                    s = jnp.einsum("bhqd,bhkd->bhqk", qt, kt) * scale
-                    q_pos = jnp.arange(width)[:, None]
-                    k_pos = jnp.arange(width)[None, :]
-                    s = jnp.where(k_pos <= q_pos, s, -jnp.inf)
-                    out = jnp.einsum(
-                        "bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), vt
-                    )
-                    return jnp.moveaxis(out, 1, 2)
-
-                return attend
-
-            for li, bp in enumerate(params["blocks"]):
-                x = _block_apply(bp, x, paged_attend(li), eps, psum=psum)
-            return x
+                x, extra = spec.prefill(params, li, x, positions, write, live)
+                aux.append(extra)
+            return x, tuple(aux)
 
         if not sample:
-            def prefill_cache_only(params, kpool, vpool, tokens, table):
-                # draft prefill: only the K/V writes matter — XLA dead-code
-                # eliminates the attention outputs, leaving the cheap qkv
-                # projections per layer
-                pools = {"k": list(kpool), "v": list(vpool)}
-                trunk(params, pools, tokens, table)
-                return tuple(pools["k"]), tuple(pools["v"])
+            def prefill_cache_only(params, *args):
+                # draft prefill: only the state's writes matter — XLA
+                # dead-code eliminates the attention outputs, leaving the
+                # cheap projections per layer
+                pools, (tokens, table) = _take_pools(spec, args)
+                trunk(params, pools, tokens, table,
+                      jnp.ones((1, width), bool))
+                return _give_pools(spec, pools)
 
             return prefill_cache_only
 
-        def prefill(params, kpool, vpool, tokens, table, length, key,
-                    temp, top_k, top_p, last, keys, slot):
-            pools = {"k": list(kpool), "v": list(vpool)}
-            x = trunk(params, pools, tokens, table)
-            logits = _head_apply(params["final_ln"], params["head"], x, eps)
-            row = jax.lax.dynamic_index_in_dim(
-                logits[0], length - 1, axis=0, keepdims=False
-            )
+        def prefill(params, *args):
+            pools, (tokens, table, length, key, temp, top_k, top_p, last,
+                    keys, slot) = _take_pools(spec, args)
+            x, aux = trunk(params, pools, tokens, table,
+                           jnp.arange(width)[None] < length)
+            row = spec.head(params, x, at=length - 1)
             key, sub = jax.random.split(key)
             tok = sample_one(row, sub, temp, top_k, top_p)
             # seat the first token and the request's key into the slot's
             # place in the decode step's inputs, here on the device: the
             # next step can be dispatched before the host has seen either
-            return (tuple(pools["k"]), tuple(pools["v"]), tok,
-                    last.at[slot].set(tok), keys.at[slot].set(key))
+            outs = _give_pools(spec, pools) + (
+                tok, last.at[slot].set(tok), keys.at[slot].set(key))
+            return outs + ((aux,) if counted else ())
 
         return prefill
 
-    def _build_step(self, spec: _Spec, psum, *, name: str, qprobs: bool):
+    def _build_step(self, spec, *, name: str, qprobs: bool, counted: bool):
         """One single-token step over all slots, for the target
         (``decode``) or the draft (``draft_step``, which also returns the
         *modified* distribution it sampled from, the q of the acceptance
-        test).  Every layer goes through :func:`paged_decode_attention`: the
-        step's row is written in place and the read stops at the longest
-        live slot.  Its last three outputs (tokens, ``pos`` advanced for the
-        active slots, keys) are the next step's ``last``, ``pos`` and
+        test).  Every layer is the block's own ``step``: the step's rows are
+        written in place and the read stops at the longest live slot.  After
+        the pools its outputs are the tokens, ``pos`` advanced for the
+        active slots and the keys: the next step's ``last``, ``pos`` and
         ``keys``."""
-        eps = spec.ln_eps
+        names = [n for n, _ in spec.state]
 
-        def step(params, kpool, vpool, tables, pos, last, keys,
-                 temp, top_k, top_p, active):
+        def step(params, *args):
+            pools, (tables, pos, last, keys, temp, top_k, top_p,
+                    active) = _take_pools(spec, args)
             # One token for every slot.  Inactive slots compute garbage into
             # the scratch page (their tables point at physical page 0) and
             # sample token 0 — all masked out host-side.
-            x = params["tok"][last] + params["pos"][
-                jnp.clip(pos, 0, spec.max_len - 1)
-            ]
-            x = x[:, None, :]  # [slots, 1, dim]
-            pools = {"k": list(kpool), "v": list(vpool)}
-
-            def paged_attend(li):
-                def attend(q, k, v):
-                    pools["k"][li], pools["v"][li], out = paged_decode_attention(
-                        pools["k"][li], pools["v"][li], tables, pos, q, k, v)
-                    return out
-
-                return attend
-
-            for li, bp in enumerate(params["blocks"]):
-                x = _block_apply(bp, x, paged_attend(li), eps, psum=psum)
-            logits = _head_apply(params["final_ln"], params["head"], x, eps)[:, 0]
+            x = spec.embed(params, last[:, None], pos[:, None])
+            aux = []
+            for li in range(spec.num_layers):
+                layer, x, extra = spec.step(
+                    params, li, x, {n: pools[n][li] for n in names}, tables,
+                    pos, active[:, None])
+                aux.append(extra)
+                for n in names:
+                    pools[n][li] = layer[n]
+            logits = spec.head(params, x)[:, 0]
             split = jax.vmap(jax.random.split)(keys)
             new_keys, subs = split[:, 0], split[:, 1]
             tok = sample_tokens(logits, subs, temp, top_k, top_p)
             tok = jnp.where(active, tok, 0)
-            outs = (tuple(pools["k"]), tuple(pools["v"]), tok)
+            outs = _give_pools(spec, pools) + (tok,)
             if qprobs:
                 outs += (jax.vmap(modified_probs)(logits, temp, top_k, top_p),)
             # tok, the next positions and the keys are the next step's
             # last, pos and keys: the host chains them without reading them
-            return outs + (pos + active.astype(pos.dtype), new_keys)
+            outs += (pos + active.astype(pos.dtype), new_keys)
+            return outs + ((tuple(aux),) if counted else ())
 
         step.__name__ = name  # the program's name in a device trace
         return step
 
     def _build_decode(self):
-        return self._build_step(self._spec, self._psum, name="decode",
-                                qprobs=False)
+        return self._build_step(self._spec, name="decode", qprobs=False,
+                                counted=self._spec.observe is not None)
 
     def _build_draft_step(self):
         """The draft's single-token step.  Always replicated."""
-        return self._build_step(self._draft_spec, None, name="draft_step",
-                                qprobs=True)
+        return self._build_step(self._draft_spec, name="draft_step",
+                                qprobs=True, counted=False)
 
     def _build_verify(self):
         """The multi-token target step: feed the window ``[last, d_1 ..
-        d_{m-1}]``, write its K/V through the page tables, compute all m
-        next-token logits in one pass, judge the drafts per slot
-        (:func:`speculative_verify_tokens`), and roll the rejected suffix
-        rows back out of the pools."""
+        d_{m-1}]``, write its state through the page tables (the block's
+        ``window``), compute all m next-token logits in one pass, judge the
+        drafts per slot (:func:`speculative_verify_tokens`), and roll the
+        rejected suffix rows back out of the pools."""
         spec = self._spec
-        s, ctx, m = self.num_slots, self._width, self._spec_tokens
-        eps = spec.ln_eps
-        psum = self._psum
+        m = self._spec_tokens
+        names = [n for n, _ in spec.state]
 
-        def verify(params, kpool, vpool, tables, pos, last, drafts, qprobs,
-                   keys, temp, top_k, top_p, active, spec_on):
+        def verify(params, *args):
+            pools, (tables, pos, last, drafts, qprobs, keys, temp, top_k,
+                    top_p, active, spec_on) = _take_pools(spec, args)
             # drafts: tuple of m [slots] proposals (d_1..d_m); qprobs: tuple
             # of m [slots, vocab] draft distributions.  Stacked here, inside
             # the program, so the host loop ships the draft step's outputs
@@ -798,41 +760,24 @@ class ServingEngine:
             q_d = jnp.stack(qprobs, axis=1)      # [slots, m, vocab]
             fed = jnp.concatenate([last[:, None], d[:, :-1]], axis=1)
             positions = pos[:, None] + jnp.arange(m)[None, :]  # [slots, m]
-            x = params["tok"][fed] + params["pos"][
-                jnp.clip(positions, 0, spec.max_len - 1)
-            ]
-            pools = {"k": list(kpool), "v": list(vpool)}
-
-            def paged_attend(li):
-                def attend(q, k, v):
-                    pools["k"][li] = append_rows(pools["k"][li], tables, pos, k)
-                    pools["v"][li] = append_rows(pools["v"][li], tables, pos, v)
-                    kg = pools["k"][li][tables].reshape(s, ctx, *k.shape[-2:])
-                    vg = pools["v"][li][tables].reshape(s, ctx, *v.shape[-2:])
-                    scale = 1.0 / jnp.sqrt(q.shape[-1]).astype(q.dtype)
-                    sc = jnp.einsum("smhd,skhd->smhk", q, kg) * scale
-                    mask = jnp.arange(ctx)[None, None, :] <= positions[:, :, None]
-                    sc = jnp.where(mask[:, :, None, :], sc, -jnp.inf)
-                    out = jnp.einsum(
-                        "smhk,skhd->smhd", jax.nn.softmax(sc, axis=-1), vg
-                    )
-                    return out
-
-                return attend
-
-            for li, bp in enumerate(params["blocks"]):
-                x = _block_apply(bp, x, paged_attend(li), eps, psum=psum)
-            logits = _head_apply(params["final_ln"], params["head"], x, eps)
+            x = spec.embed(params, fed, positions)
+            for li in range(spec.num_layers):
+                layer, x = spec.window(
+                    params, li, x, {n: pools[n][li] for n in names}, tables,
+                    pos)
+                for n in names:
+                    pools[n][li] = layer[n]
+            logits = spec.head(params, x)
             out, count, accepted, new_keys = speculative_verify_tokens(
                 logits, d, q_d, keys, temp, top_k, top_p, spec_on & active)
             out = jnp.where(active[:, None], out, 0)
             # erase the rejected suffix so the pools only ever hold
-            # accepted-token K/V between iterations
-            for name in ("k", "v"):
-                pools[name] = [rollback_rows(pool, tables, pos, count, m)
-                               for pool in pools[name]]
-            return (tuple(pools["k"]), tuple(pools["v"]), out, count, accepted,
-                    new_keys)
+            # accepted-token rows between iterations
+            for n in names:
+                pools[n] = [rollback_rows(pool, tables, pos, count, m)
+                            for pool in pools[n]]
+            return _give_pools(spec, pools) + (
+                out, count, accepted, new_keys)
 
         return verify
 
@@ -903,7 +848,7 @@ class ServingEngine:
                 f"prompt length {plen} exceeds serviceable context "
                 f"(width {self._width}, model max_len {spec.max_len})"
             )
-        if int(np.max(request.prompt)) >= spec.vocab:
+        if int(np.max(request.prompt)) >= spec.vocab_size:
             raise ValueError("prompt token id out of vocabulary")
         if request.speculative and self._draft_spec is None:
             raise ValueError(
@@ -1026,7 +971,8 @@ class ServingEngine:
     def hot_swap(self, model, params=None, timeout: float = 30.0) -> None:
         """Swap the served params in place — the checkpoint hot-swap.
 
-        Geometry (dim/heads/head_dim/max_len/vocab/depth/ln_eps) must match
+        Geometry (the block's ``DecodeSpec.geometry`` and ``state``: widths,
+        heads, max_len, vocab, depth) must match
         the engine's current spec: the decode step is param-*shape*-stable,
         so the swap reuses every compiled program — no retrace, no
         recompile.  The loop applies the swap at the first iteration with
@@ -1046,19 +992,16 @@ class ServingEngine:
 
     def _hot_swap(self, model, params, timeout: float) -> None:
         new = _resolve_spec(model, params)
+        if self._mesh is not None:
+            new = self._sharded(new)
         with self._cv:
             old = self._spec
-        for f in ("dim", "heads", "head_dim", "max_len", "vocab", "ln_eps"):
-            if getattr(new, f) != getattr(old, f):
-                raise ValueError(
-                    f"hot_swap geometry mismatch on {f}: "
-                    f"{getattr(new, f)} != {getattr(old, f)}"
-                )
-        if len(new.blocks) != len(old.blocks):
+        if new.geometry != old.geometry or new.state != old.state:
+            differ = [f"{a} != {b}" for a, b in zip(
+                new.geometry + new.state, old.geometry + old.state) if a != b]
             raise ValueError(
-                f"hot_swap depth mismatch: {len(new.blocks)} blocks "
-                f"!= {len(old.blocks)}"
-            )
+                "hot_swap geometry mismatch: "
+                + (", ".join(differ) or "another kind of block"))
         with self._cv:
             if self._crashed:
                 raise EngineCrashed("engine crashed; cannot hot_swap")
@@ -1244,21 +1187,19 @@ class ServingEngine:
                 slot, : width // self._cache.page_size].copy())
             last, keys = ((self._last, self._keys) if serial
                           else (self._dev["last"], self._dev["keys"]))
-            kp, vp, tok, last, keys = self._prefill_for(width)(
-                self._spec.params(), self._cache.k_pages,
-                self._cache.v_pages, tokens_dev, table, np.int32(plen),
-                jax.random.PRNGKey(req.seed), np.float32(req.temperature),
-                np.int32(req.top_k), np.float32(req.top_p),
-                last, keys, np.int32(slot),
-            )
-            self._cache.k_pages, self._cache.v_pages = kp, vp
+            tok, last, keys, *aux = self._keep(
+                self._cache, self._prefill_for(width)(
+                    self._spec.params(), *self._pools_of(self._cache),
+                    tokens_dev, table, np.int32(plen),
+                    jax.random.PRNGKey(req.seed), np.float32(req.temperature),
+                    np.int32(req.top_k), np.float32(req.top_p),
+                    last, keys, np.int32(slot)))
             spec_on = serial and req.speculative is not False
             if spec_on:
                 dc = self._draft_cache
-                dkp, dvp = self._prefill_for(width, role="draft")(
-                    self._draft_spec.params(), dc.k_pages, dc.v_pages,
-                    tokens_dev, table)
-                dc.k_pages, dc.v_pages = dkp, dvp
+                self._keep(dc, self._prefill_for(width, role="draft")(
+                    self._draft_spec.params(), *self._pools_of(dc),
+                    tokens_dev, table))
                 # a draft chain decorrelated from the request's target chain
                 self._draft_keys[slot] = np.asarray(
                     jax.random.fold_in(jax.random.PRNGKey(req.seed), 7))
@@ -1282,7 +1223,8 @@ class ServingEngine:
         self._spec_on[slot] = spec_on
         self._dirty = True
         self._inflight.append(_InFlight(
-            tok, [(slot, state)], prefill=(t0 - pending.enqueue_t, now - t0)))
+            tok, [(slot, state)], prefill=(t0 - pending.enqueue_t, now - t0),
+            aux=aux[0] if aux else None))
         self._refresh_gauges()
         if serial:
             self._flush()
@@ -1377,10 +1319,9 @@ class ServingEngine:
         t0 = time.perf_counter()
         self._count_kv_read(self._pos)
         with self._step_span():
-            kp, vp, tok, pos, keys = self._decode(
-                self._spec.params(), self._cache.k_pages, self._cache.v_pages,
-                *self._step_inputs())
-            self._cache.k_pages, self._cache.v_pages = kp, vp
+            tok, pos, keys, *aux = self._keep(self._cache, self._decode(
+                self._spec.params(), *self._pools_of(self._cache),
+                *self._step_inputs()))
             self._dev.update(last=tok, pos=pos, keys=keys)
             self._metrics["decode_steps"].inc()
             if any(rec.prefill is None for rec in self._inflight):
@@ -1388,7 +1329,8 @@ class ServingEngine:
                 self._metrics["decode_chained"].inc()
             rows = [(int(slot), self._slots[slot])
                     for slot in np.flatnonzero(self._active)]
-            self._inflight.append(_InFlight(tok, rows))
+            self._inflight.append(_InFlight(
+                tok, rows, aux=aux[0] if aux else None))
             self._pos[self._active] += 1
             for slot, state in rows:
                 state.steps_left -= 1
@@ -1405,13 +1347,21 @@ class ServingEngine:
         them.  ``t0`` is when the caller's own work began: the ledger's
         share of the loop's time for a step."""
         while len(self._inflight) > keep:
-            rec = self._inflight.popleft()
+            # still in flight until its tokens are with their requests: a
+            # drain that sees nothing in flight may tell its caller so
+            rec = self._inflight[0]
             toks = np.asarray(rec.tok)  # device sync: that program is done
             now = time.perf_counter()
+            if rec.aux is not None:
+                step = rec.prefill is None
+                self._observe(
+                    jax.tree.map(np.asarray, rec.aux),
+                    len(rec.rows) if step else rec.rows[0][1].plen, step)
             if rec.prefill is not None:
                 self._first_token(rec, int(toks), now)
             else:
                 self._step_tokens(rec, toks, now - t0)
+            self._inflight.popleft()
 
     def _flush(self) -> None:
         """Read everything in flight: the host sees what the device made."""
@@ -1482,19 +1432,18 @@ class ServingEngine:
             drafts, qprobs = [], []
             for i in range(m):
                 self._count_kv_read(base_pos + i)
-                dc.k_pages, dc.v_pages, tok, qp, _, dkeys = self._draft_step(
-                    dparams, dc.k_pages, dc.v_pages, tables,
+                tok, qp, _, dkeys = self._keep(dc, self._draft_step(
+                    dparams, *self._pools_of(dc), tables,
                     jnp.asarray(base_pos + i), last, dkeys, temp, topk, topp,
-                    active)
+                    active))
                 drafts.append(tok)
                 qprobs.append(qp)
                 last = tok
-            kp, vp, out, count, accepted, keys = self._verify(
-                self._spec.params(), self._cache.k_pages, self._cache.v_pages,
+            out, count, accepted, keys = self._keep(self._cache, self._verify(
+                self._spec.params(), *self._pools_of(self._cache),
                 tables, jnp.asarray(base_pos), jnp.asarray(self._last),
                 tuple(drafts), tuple(qprobs), jnp.asarray(self._keys),
-                temp, topk, topp, active, jnp.asarray(self._spec_on))
-            self._cache.k_pages, self._cache.v_pages = kp, vp
+                temp, topk, topp, active, jnp.asarray(self._spec_on)))
             out = np.asarray(out)       # device sync: the iteration is done
             counts = np.asarray(count)
             acc = np.asarray(accepted)

@@ -154,10 +154,12 @@ def test_every_reader_without_a_test_of_its_own_is_here(harness):
     """Each per-layer metric of the manifest is read here, on the hand-made
     ring (``test_benchmark_span_readers.py``) or on the hand-made marks
     (``test_benchmark_decode_kv_reader.py``,
-    ``test_benchmark_decode_chained_reader.py``)."""
+    ``test_benchmark_decode_chained_reader.py``,
+    ``test_benchmark_mla_moe.py``)."""
     elsewhere = {"gather_ms", "h2d_ms", "dispatch_ms", "host_slack_ms",
                  "feed_gap_ms", "gap_unattributed_share", "decode_kv_read_share",
-                 "decode_chained_share"}
+                 "decode_chained_share", "moe_held_share",
+                 "moe_load_max_over_mean", "state_bytes_per_position"}
     names = {m["name"] for m in harness.load_manifest()["per_layer"]}
     assert names == set(ANSWERS) | elsewhere
 

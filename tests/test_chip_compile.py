@@ -90,3 +90,40 @@ def test_flash_attention_compiles_under_vmap_for_v5e(one_chip, shape):
     bwd = _compiled_text(jax.vmap(jax.grad(_loss, argnums=(0, 1, 2))), shape,
                          jnp.bfloat16, one_chip)
     assert bwd.count("tpu_custom_call") >= 3
+
+
+# ------------------------------------------------- the latent pool's layout
+
+
+def test_the_latent_step_takes_its_pool_as_it_lies(one_chip):
+    """The served latent-attention cell's step of one layer at its real
+    shapes (64 slots, 192 pages of 16 a slot, 64 heads, a 576-wide row):
+    the pool goes in and out in the row-major layout that the chip gives it,
+    and the program holds no copy of a whole pool.  A pool 576 wide is laid
+    out by the chip with the pages on the lanes, and every program re-laid
+    it out on its way in and out (2.3 GB of copies a step: PERF.md, PR 31);
+    ``pool_width`` pads a row to whole lanes, which this pins."""
+    import re
+
+    from distkeras_tpu.serving.cache import paged_latent_attention, pool_width
+
+    slots, pages, page, heads, declared = 64, 192, 16, 64, 576
+    width = pool_width(declared)
+    assert width == 640
+    pool_shape = (slots * pages + 1, page, width)
+    struct = lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+
+    def step(pool, tables, pos, q, row):
+        return paged_latent_attention(pool, tables, pos, q, row, 512, 0.1)
+
+    text = jax.jit(step, donate_argnums=(0,)).lower(
+        struct(pool_shape, jnp.bfloat16), struct((slots, pages), jnp.int32),
+        struct((slots,), jnp.int32), struct((slots, heads, declared), jnp.float32),
+        struct((slots, declared), jnp.bfloat16)).compile().as_text()
+    shape = "bf16[%d,%d,%d]" % pool_shape
+    layouts = set(re.findall(re.escape(shape) + r"\{([\d,]+)", text))
+    assert layouts == {"2,1,0"}, layouts  # row-major, everywhere it appears
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and shape in line]
+    assert "while" in text  # the loop over the live blocks
