@@ -26,9 +26,15 @@ layer which of the ``num_experts`` routed experts it holds (expert
 parallelism's share of a layer).  It routes over all of them, computes the
 held experts' terms for the tokens routed to them and **drops nothing**:
 the assignments are sorted by expert, those to absent experts past the last
-group, and a grouped product (``jax.lax.ragged_dot``) runs over the held
-groups.  What absent experts would have added is left out; nothing stands
-in for the other chips or their exchange.
+group, and the grouped product **walks row tiles**: each held group's
+sorted rows are cut into tiles, so a tile belongs to one expert, whose three
+matrices are read for it and only for it (a group's last tile is filled up
+with rows that go nowhere); a loop as long as the tiles that hold a row
+gathers a tile's rows, computes the expert's term and adds it, weighed, into
+the tokens' rows.  A touched expert's weights are read once a call where its
+rows fit one tile, and the tile's height follows the traced row count
+(:func:`tile_height`).  What absent experts would have added is left out;
+nothing stands in for the other chips or their exchange.
 
 **Two attention paths** (both write the same ``[c, k_r]`` row):
 
@@ -67,6 +73,17 @@ __all__ = ["LatentMoELM"]
 F32 = jnp.float32
 #: queries of a chunk that the expanded attention scores at a time
 QUERY_BLOCK = 512
+
+
+def tile_height(rows, experts):
+    """Rows of one tile of the experts' walk, from the traced shape alone:
+    the power of two at or under twice the mean group (``rows / experts``
+    under an even router), so that nearly every group fits one tile and its
+    expert's weights are read once; at least 16 (one packed bfloat16 tile of
+    the chip), at most 256 (beyond it the padding's products cost more than
+    a second read: PERF.md, PR 32)."""
+    return min(max(16, 1 << max((2 * rows // experts).bit_length() - 1, 0)),
+               256)
 
 
 def _dot(x, w, spec):
@@ -319,45 +336,76 @@ class LatentMoELM:
 
     def held_experts_terms(self, p, h, ids, weights, live=None):
         """The held experts' part of the layer's output for ``h [tokens,
-        dim]``, and how many assignments of the ``live`` tokens met each
-        held expert (``[count]`` int32).  Every assignment is a row: sorted
-        by expert, the absent experts' rows past the last group, a grouped
-        product over the held groups; no capacity, nothing dropped."""
+        dim]``, and the walk's counts: how many assignments of the ``live``
+        tokens met each held expert (``[count]`` int32), the tiles that did
+        work and the held experts with at least one row (scalars).  Every
+        assignment is a row: sorted by expert, the absent experts' rows
+        past the last group, each held group cut into tiles of
+        :func:`tile_height`; no capacity, nothing dropped.  The loop is as
+        long as the tiles that hold a row, so it has no reverse mode."""
         first, count = self.held
         tokens, k = ids.shape
+        rows = tokens * k
+        tile = tile_height(rows, self.num_experts)
         local = ids.reshape(-1) - first
         held = (local >= 0) & (local < count)
         group = jnp.where(held, local, count)
         order = jnp.argsort(group, stable=True)
         member = group[:, None] == jnp.arange(count)[None, :]
         sizes = jnp.sum(member, axis=0, dtype=jnp.int32)
+        tiles = -(-sizes // tile)
+        walked = jnp.sum(tiles)
+        # where a group begins, in sorted rows and in tiles, and each tile's
+        # expert: sums under a mask, because tables made with gathers and
+        # cumulative sums made every program slow to load (PERF.md, PR 32)
+        before = jnp.arange(count)[None, :] < jnp.arange(count)[:, None]
+        start = jnp.sum(jnp.where(before, sizes[None, :], 0), axis=1)
+        first_tile = jnp.sum(jnp.where(before, tiles[None, :], 0), axis=1)
+        most = min((rows + count * (tile - 1)) // tile, rows)
+        expert = jnp.minimum(count - 1, jnp.sum(
+            jnp.arange(most)[:, None] >= (first_tile + tiles)[None, :], axis=1))
+        # a group's last tile may be cut past the last sorted row
+        order = jnp.concatenate([order, jnp.full(tile, rows, order.dtype)])
+        weight = weights.reshape(-1)
         kind = p["experts_gate"].dtype
-        x = h[order // k].astype(kind)
-        ragged = lambda a, w: jax.lax.ragged_dot(
-            a, w, sizes, preferred_element_type=F32)
-        y = ragged((jax.nn.silu(ragged(x, p["experts_gate"]))
-                    * ragged(x, p["experts_up"])).astype(kind),
-                   p["experts_down"])
-        weight = jnp.where(held, weights.reshape(-1), 0.0)[order]
-        # rows past the last group are not the product's to define
-        y = jnp.where((jnp.arange(tokens * k) < jnp.sum(sizes))[:, None],
-                      y * weight[:, None], 0.0)
-        back = jnp.zeros(tokens * k, jnp.int32).at[order].set(
-            jnp.arange(tokens * k, dtype=jnp.int32))
+        x = h.astype(kind)
+        dot = lambda a, w: jnp.dot(a, w, preferred_element_type=F32)
+
+        def one_tile(i, out):
+            e = expert[i]
+            at = start[e] + (i - first_tile[e]) * tile  # the tile's first row
+            row = jax.lax.dynamic_slice_in_dim(order, at, tile)
+            mine = jnp.arange(tile) < start[e] + sizes[e] - at
+            token = jnp.where(mine, row // k, tokens)  # the rest goes nowhere
+            rows_in = x[jnp.minimum(token, tokens - 1)]
+            hidden = (jax.nn.silu(dot(rows_in, p["experts_gate"][e]))
+                      * dot(rows_in, p["experts_up"][e])).astype(kind)
+            term = (dot(hidden, p["experts_down"][e])
+                    * weight[jnp.minimum(row, rows - 1)][:, None])
+            return out.at[token].add(term, mode="drop")
+
+        out = jax.lax.fori_loop(0, walked, one_tile,
+                                jnp.zeros((tokens, h.shape[-1]), F32))
         if live is not None:
             member = member & jnp.repeat(live, k)[:, None]
-        return (y[back].reshape(tokens, k, -1).sum(axis=1),
-                jnp.sum(member, axis=0, dtype=jnp.int32))
+        return out, (jnp.sum(member, axis=0, dtype=jnp.int32), walked,
+                     jnp.sum(sizes > 0, dtype=jnp.int32))
 
-    def feed_forward(self, p, h, live=None):
-        """``(ffn(h) [tokens, dim], held counts or None)`` of one layer."""
+    def counted_feed_forward(self, p, h, live=None):
+        """``(ffn(h) [tokens, dim], the walk's counts or None)`` of one
+        layer: None for a dense one, else ``held_experts_terms``' three."""
         if "router" not in p:
             return self._gated(h, p["gate"], p["up"], p["down"]), None
         ids, weights = self.route(p, h)
-        routed, counts = self.held_experts_terms(p, h, ids, weights, live)
+        routed, walk = self.held_experts_terms(p, h, ids, weights, live)
         shared = self._gated(h, p["shared_gate"], p["shared_up"],
                              p["shared_down"])
-        return routed + shared, counts
+        return routed + shared, walk
+
+    def feed_forward(self, p, h, live=None):
+        """``(ffn(h) [tokens, dim], held counts or None)`` of one layer."""
+        y, walk = self.counted_feed_forward(p, h, live)
+        return y, None if walk is None else walk[0]
 
     # ------------------------------------------------------ full forward
 
@@ -384,9 +432,10 @@ class LatentMoELM:
         (:class:`distkeras_tpu.models.decode.DecodeSpec`): one pool a layer
         of ``row_width`` (the latent and the rotated shared key), the
         expanded attention for a prefill chunk, the absorbed one for the
-        step, and the expert layers' counts of assignments as the block's
-        own counters.  No ``window`` and no ``shard``: the engine refuses
-        ``draft_model=`` and ``mesh=`` for this block."""
+        step, and the expert layers' counts of assignments, tiles and
+        touched experts as the block's own counters.  No ``window`` and no
+        ``shard``: the engine refuses ``draft_model=`` and ``mesh=`` for
+        this block."""
         from distkeras_tpu.models.decode import DecodeSpec
 
         eps = self.rms_norm_eps
@@ -396,26 +445,37 @@ class LatentMoELM:
         def embed(params, tokens, positions):
             return params["embed"][tokens].astype(F32)
 
-        def prefill(params, li, x, positions, write, live):
-            p = params["layers"][li]
+        # a layer's work is traced and lowered once a shape, not once a
+        # layer: the expert layers are alike (XLA inlines the calls)
+        @jax.jit
+        def prefill_layer(p, x, positions, live):
             q_n, q_r, c, k_r = self.latent(
                 p, rms_norm(x, p["attn_norm"], eps), positions)
-            write("latent", jnp.concatenate([c, k_r], axis=-1)[0])
             x = x + self.expanded_attention(p, q_n, q_r, c, k_r)
-            y, counts = self.feed_forward(
+            y, walk = self.counted_feed_forward(
                 p, rms_norm(x, p["ffn_norm"], eps)[0], live[0])
-            return x + y[None], counts
+            return x + y[None], jnp.concatenate([c, k_r], axis=-1)[0], walk
 
-        def step(params, li, x, pools, tables, pos, live):
-            p = params["layers"][li]
+        def prefill(params, li, x, positions, write, live):
+            x, row, walk = prefill_layer(params["layers"][li], x, positions,
+                                         live)
+            write("latent", row)
+            return x, walk
+
+        @jax.jit
+        def step_layer(p, x, pool, tables, pos, live):
             latent = self.latent(p, rms_norm(x, p["attn_norm"], eps),
                                  pos[:, None])
-            pool, out = self.absorbed_step(p, pools["latent"], tables, pos,
-                                           *latent)
+            pool, out = self.absorbed_step(p, pool, tables, pos, *latent)
             x = x + out
-            y, counts = self.feed_forward(
+            y, walk = self.counted_feed_forward(
                 p, rms_norm(x, p["ffn_norm"], eps)[:, 0], live[:, 0])
-            return {"latent": pool}, x + y[:, None], counts
+            return pool, x + y[:, None], walk
+
+        def step(params, li, x, pools, tables, pos, live):
+            pool, x, walk = step_layer(params["layers"][li], x,
+                                       pools["latent"], tables, pos, live)
+            return {"latent": pool}, x, walk
 
         def head(params, x, at=None):
             if at is not None:  # one row of a chunk: the head runs once
@@ -438,10 +498,22 @@ class LatentMoELM:
                     help="one observation a decode step: the fullest held "
                          "expert's assignments over the held experts' mean, "
                          "averaged over the expert layers"),
+                "tiles": registry.counter(
+                    "serving_moe_tiles_total",
+                    help="row tiles of the held experts' products that did "
+                         "work, as the programs count them: each reads one "
+                         "expert's weights once"),
+                "touched": registry.counter(
+                    "serving_moe_experts_touched_total",
+                    help="held experts with at least one assignment row in "
+                         "a call, summed over the expert layers"),
             }
 
         def observe(instruments, aux, rows, step):
-            counts = np.stack([a for a in aux if a is not None])
+            counts, tiles, touched = map(np.stack, zip(
+                *(a for a in aux if a is not None)))
+            instruments["tiles"].inc(int(tiles.sum()))
+            instruments["touched"].inc(int(touched.sum()))
             instruments["assignments"].inc(
                 rows * self.num_experts_per_tok * len(counts))
             instruments["held"].inc(int(counts.sum()))

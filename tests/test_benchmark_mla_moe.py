@@ -95,6 +95,74 @@ def test_a_reader_of_the_blocks_counters(harness, metric, case):
     assert got == (pytest.approx(want) if want is not None else None)
 
 
+TILES = "serving_moe_tiles_total"
+TOUCHED = "serving_moe_experts_touched_total"
+
+
+def _walked(tiles, touched, **others):
+    return {TILES: tiles, TOUCHED: touched, ASSIGNED: 1.0, **others}
+
+
+# By hand: inside the window 3,000 held experts had a row in some call, and
+# the walk ran 3,150 tiles (150 groups were longer than one tile).
+TILE_MARKS = {
+    "both_edges": ({"open": _walked(1050.0, 1000.0),
+                    "close": _walked(4200.0, 4000.0)}, 1.05),
+    "every_expert_read_once": ({"open": _walked(10.0, 10.0),
+                                "close": _walked(70.0, 70.0)}, 1.0),
+    "first_touched_inside_the_window": (
+        {"open": {"serving_tokens_total": 0.0},
+         "close": _walked(3150.0, 3000.0)}, 1.05),
+    "one_edge_missing": ({"close": _walked(3150.0, 3000.0)}, None),
+    "no_marks": (None, None),
+    # the parent of the PR that brought the walk: it routes and counts its
+    # assignments, and walks no tiles
+    "a_program_without_the_counters": (
+        {"open": {ASSIGNED: 5.0, HELD: 1.0},
+         "close": {ASSIGNED: 9.0, HELD: 2.0}}, None),
+    "no_call_inside_the_window": ({"open": _walked(7.0, 6.0),
+                                   "close": _walked(7.0, 6.0)}, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TILE_MARKS))
+def test_the_reader_of_the_walks_counters(harness, case):
+    """``moe_tiles_per_expert``, found by the metric's file: tiles that did
+    work over experts touched, None wherever there is nothing to read."""
+    reader = harness.resolve(
+        "readers", harness.metric_spec("moe_tiles_per_expert")["reader"])
+    marks, want = TILE_MARKS[case]
+    got = reader({"marks": marks})
+    assert got == (pytest.approx(want, rel=1e-12) if want is not None else None)
+
+
+@pytest.mark.parametrize("case", ["both_edges",
+                                  "a_program_without_the_counters"])
+def test_the_line_is_made_with_and_without_the_walks_counters(harness, case):
+    """Through ``harness.result_line`` in the metric's cell: on this
+    program's marks the line carries the ratio; on the parent's (the driver
+    lays this reader over a checkout whose product walks no tiles) the
+    metric is left out and the line is made all the same."""
+    marks, want = TILE_MARKS[case]
+    manifest = harness.load_manifest()
+    entry = manifest["per_layer"][-1]
+    assert entry == {
+        "name": "moe_tiles_per_expert", "unit": "ratio", "better": "lower",
+        "source": "program_counter", "layer": "models",
+        "moves": "serve_tokens_per_s", "workloads": [CELL]}
+    run = {"correct": True, "attempted": 5, "failed": 0,
+           "facts": {"marks": marks}, "end_to_end": {},
+           "device": {"platform": "tpu"}}
+    line = harness.result_line(dict(manifest, per_layer=[entry]),
+                               {"name": CELL}, run, True)
+    if want is None:
+        assert line["metrics"] == {}
+    else:
+        assert line["metrics"] == {"moe_tiles_per_expert": {
+            "value": pytest.approx(want), "unit": "ratio"}}
+    assert line["correct"] is True and line["attempted"] == 5
+
+
 def test_the_manifest_lists_the_cell_under_what_it_reports(harness):
     manifest = harness.load_manifest()
     listed = {m["name"] for m in manifest["end_to_end"] + manifest["per_layer"]
@@ -104,10 +172,12 @@ def test_the_manifest_lists_the_cell_under_what_it_reports(harness):
         "slot_occupancy", "prefill_padding_share", "goodput_share",
         "serve_mfu", "serve_compiles_in_window", "serve_device_idle_share",
         "serve_peak_hbm_gb", "decode_kv_read_share", "decode_chained_share",
-        "moe_held_share", "moe_load_max_over_mean", "state_bytes_per_position"}
-    new = [m for m in manifest["per_layer"] if m["name"] in WANT]
-    assert [m["name"] for m in manifest["per_layer"][-3:]] == [
-        m["name"] for m in new]  # appended, at the end
+        "moe_held_share", "moe_load_max_over_mean", "state_bytes_per_position",
+        "moe_tiles_per_expert"}
+    new = [m for m in manifest["per_layer"]
+           if m["name"] in WANT or m["name"] == "moe_tiles_per_expert"]
+    assert [m["name"] for m in manifest["per_layer"][-4:]] == [
+        m["name"] for m in new]  # appended, at the end, PR 32's after PR 31's
     assert all(m["workloads"] == [CELL] and m["layer"] == "models"
                and m["moves"] == "serve_tokens_per_s" for m in new)
 
@@ -237,10 +307,11 @@ def test_the_cells_files_drive_a_run_end_to_end(harness, tiny_cell):
     assert {"decode_step_ms", "prefill_ms", "slot_occupancy", "serve_mfu",
             "goodput_share", "decode_kv_read_share", "decode_chained_share",
             "moe_held_share", "moe_load_max_over_mean",
-            "state_bytes_per_position"} <= set(traced)
+            "state_bytes_per_position", "moe_tiles_per_expert"} <= set(traced)
     assert traced["serve_compiles_in_window"]["value"] == 0
     assert 0 < traced["moe_held_share"]["value"] < 100
     assert traced["moe_load_max_over_mean"]["value"] >= 1.0
+    assert traced["moe_tiles_per_expert"]["value"] >= 1.0
     # three layers of one 24-wide float32 row
     assert traced["state_bytes_per_position"]["value"] == 3 * 24 * 4
     assert 0 < traced["serve_mfu"]["value"] < 100

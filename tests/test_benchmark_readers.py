@@ -159,7 +159,8 @@ def test_every_reader_without_a_test_of_its_own_is_here(harness):
     elsewhere = {"gather_ms", "h2d_ms", "dispatch_ms", "host_slack_ms",
                  "feed_gap_ms", "gap_unattributed_share", "decode_kv_read_share",
                  "decode_chained_share", "moe_held_share",
-                 "moe_load_max_over_mean", "state_bytes_per_position"}
+                 "moe_load_max_over_mean", "state_bytes_per_position",
+                 "moe_tiles_per_expert"}
     names = {m["name"] for m in harness.load_manifest()["per_layer"]}
     assert names == set(ANSWERS) | elsewhere
 

@@ -127,3 +127,36 @@ def test_the_latent_step_takes_its_pool_as_it_lies(one_chip):
     assert not [line for line in text.splitlines()
                 if " copy(" in line and shape in line]
     assert "while" in text  # the loop over the live blocks
+
+
+# ------------------------------------------- the expert layer's walk of tiles
+
+
+def test_the_step_reads_each_experts_weights_where_they_lie(one_chip):
+    """The served step's expert layer at the published widths (32 held
+    experts of 4096 x 2048, 64 slots x 8 = 512 assignment rows, bfloat16):
+    the walk over row tiles compiles for the chip as one loop, with no
+    grouped product of XLA's own, and takes a tile's expert out of the
+    stacked weights inside the products: the program holds no temporary as
+    large as one expert's matrix (16.8 MB; a slice that copied the weights
+    would triple the traffic that the walk removes: PERF.md, PR 32)."""
+    from distkeras_tpu.models import LatentMoELM
+    from distkeras_tpu.models.latent_moe import tile_height
+
+    model = LatentMoELM(vocab_size=65536, max_len=3072, num_hidden_layers=5,
+                        held_experts=(0, 32))
+    slots, k, dim, width = 64, model.num_experts_per_tok, 4096, 2048
+    assert tile_height(slots * k, model.num_experts) == 16
+    struct = lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    weights = {"experts_gate": struct((32, dim, width), jnp.bfloat16),
+               "experts_up": struct((32, dim, width), jnp.bfloat16),
+               "experts_down": struct((32, width, dim), jnp.bfloat16)}
+    compiled = jax.jit(model.held_experts_terms).lower(
+        weights, struct((slots, dim), jnp.float32),
+        struct((slots, k), jnp.int32), struct((slots, k), jnp.float32),
+        struct((slots,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "ragged" not in text and text.count(" while(") == 1
+    one_matrix = dim * width * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < one_matrix // 2

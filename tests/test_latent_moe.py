@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 from distkeras_tpu.models import LatentMoELM
-from distkeras_tpu.models.latent_moe import rms_norm
+from distkeras_tpu.models.latent_moe import rms_norm, tile_height
 from distkeras_tpu.serving.cache import paged_latent_attention
 
 #: hidden 64, 4 heads, latent 16, rope 8, 8 experts top-2 with 1 shared, 1
@@ -216,3 +216,114 @@ def test_yarn_keeps_fast_dimensions_and_interpolates_slow_ones():
     assert yarn_mscale(scaling, "mscale_all_dim") == pytest.approx(1.36889, 1e-5)
     model = LatentMoELM(vocab_size=8, max_len=8, rope_scaling=scaling)
     assert model.softmax_scale == pytest.approx(192 ** -0.5 * 1.36889 ** 2, 1e-5)
+
+
+# ------------------------------------------- the walk over row tiles (PR 32)
+
+HEIGHTS = (16, 32, 64, 128, 256)
+
+
+@pytest.mark.parametrize("rows,experts,want", [
+    (64 * 8, 128, 16),      # the served step: a mean group of 4
+    (512 * 8, 128, 64), (768 * 8, 128, 64), (1024 * 8, 128, 128),
+    (1792 * 8, 128, 128), (2048 * 8, 128, 256), (3072 * 8, 128, 256),
+    (7 * 2, 8, 16), (1, 128, 16)])
+def test_the_tiles_height_follows_the_traced_rows(rows, experts, want):
+    """The power of two at or under twice the mean group, within 16..256:
+    the prefill buckets and the step of the served cell, and tiny shapes."""
+    assert tile_height(rows, experts) == want
+    assert want in HEIGHTS
+
+
+def _hand_made(case, tile):
+    """``(first choices [tokens], tiles, touched)`` for a layer that holds
+    experts 2..5 of 8: ``2 * tile + 3`` tokens of two choices each (rows no
+    multiple of the tile), the second choice always an absent expert."""
+    tokens = 2 * tile + 3
+    first = {
+        # expert 3 gets nothing, between two that do
+        "an_empty_group_in_the_middle":
+            [2] * 5 + [4] * (tile - 1) + [5] * 3 + [0] * (tokens - tile - 7),
+        "one_expert_takes_every_row": [4] * tokens,
+        "one_tile_and_one_tile_plus_a_row":
+            [2] * tile + [3] * (tile + 1) + [5] * 2,
+        "no_held_row_at_all": [0] * tokens,
+    }[case]
+    sizes = np.bincount(first, minlength=8)[2:6]
+    return (np.asarray(first), int(np.ceil(sizes / tile).sum()),
+            int((sizes > 0).sum()))
+
+
+def _dense_terms(p, h, ids, weights, held):
+    """Every held expert over every token, weighed by what routed there."""
+    total = 0.0
+    for local in range(held[1]):
+        term = LatentMoELM._gated(h, p["experts_gate"][local],
+                                  p["experts_up"][local],
+                                  p["experts_down"][local])
+        share = jnp.sum(jnp.where(ids == held[0] + local, weights, 0.0), -1)
+        total = total + term * share[:, None]
+    return total
+
+
+@pytest.mark.parametrize("tile", HEIGHTS)
+@pytest.mark.parametrize("case", [
+    "an_empty_group_in_the_middle", "one_expert_takes_every_row",
+    "one_tile_and_one_tile_plus_a_row", "no_held_row_at_all"])
+def test_the_walk_equals_a_dense_loop_on_hand_made_groups(case, tile):
+    held = (2, 4)
+    model, part, _ = _expert_layer(held)
+    first, tiles, touched = _hand_made(case, tile)
+    tokens = len(first)
+    assert tile_height(tokens * 2, 8) == tile  # the rule returns this one
+    rng = np.random.default_rng(tile)
+    first = rng.permutation(first)
+    ids = jnp.asarray(np.stack([first, np.where(first == 7, 6, 7)], axis=1))
+    weights = jnp.asarray(rng.uniform(0.2, 2.0, (tokens, 2)), jnp.float32)
+    h = _unit_rows(jax.random.PRNGKey(tile), tokens)
+    live = jnp.arange(tokens) % 3 != 0
+    got, (counts, walked, met) = model.held_experts_terms(
+        part, h, ids, weights, live)
+    np.testing.assert_allclose(
+        got, _dense_terms(part, h, ids, weights, held), atol=2e-5)
+    # the counts are the routing's: the live tokens' assignments an expert
+    want = np.bincount(first[np.asarray(live)], minlength=8)[2:6]
+    np.testing.assert_array_equal(counts, want)
+    assert (int(walked), int(met)) == (tiles, touched)
+
+
+@pytest.mark.parametrize("tile", HEIGHTS)
+def test_the_walk_equals_a_dense_loop_on_a_routed_batch(tile):
+    """Both choices may be held, as the router leaves them."""
+    held = (2, 4)
+    model, part, _ = _expert_layer(held)
+    tokens = 2 * tile + 3
+    h = _unit_rows(jax.random.PRNGKey(100 + tile), tokens)
+    ids, weights = model.route(part, h)
+    got, (counts, walked, met) = model.held_experts_terms(part, h, ids, weights)
+    np.testing.assert_allclose(
+        got, _dense_terms(part, h, ids, weights, held), atol=2e-5)
+    sizes = np.bincount(np.asarray(ids).reshape(-1), minlength=8)[2:6]
+    np.testing.assert_array_equal(counts, sizes)
+    assert int(walked) == int(np.ceil(sizes / tile).sum())
+    assert int(met) == int((sizes > 0).sum())
+
+
+def test_the_blocks_counters_take_the_walks_counts():
+    """``observe`` on hand-made ``aux``: two expert layers of a step (the
+    dense layer hands None), 3 + 2 tiles over 2 + 2 touched experts."""
+    from distkeras_tpu.telemetry.metrics import Registry
+
+    model = LatentMoELM(**dict(TINY, held_experts=[2, 4]))
+    spec = model.decode_spec(None)
+    registry = Registry()
+    instruments = spec.instruments(registry)
+    counts = lambda *v: np.asarray(v, np.int32)
+    aux = (None, (counts(5, 0, 1, 0), np.int32(3), np.int32(2)),
+           (counts(0, 2, 2, 0), np.int32(2), np.int32(2)))
+    spec.observe(instruments, aux, 6, True)
+    value = lambda name: registry.snapshot()[name]["value"]
+    assert value("serving_moe_tiles_total") == 5
+    assert value("serving_moe_experts_touched_total") == 4
+    assert value("serving_moe_assignments_total") == 6 * 2 * 2
+    assert value("serving_moe_assignments_held_total") == 10

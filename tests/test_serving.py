@@ -18,7 +18,7 @@ import numpy as np
 import pytest
 
 from distkeras_tpu import telemetry
-from distkeras_tpu.models import StagedLM, TransformerLM
+from distkeras_tpu.models import LatentMoELM, StagedLM, TransformerLM
 from distkeras_tpu.models.generate import (
     greedy_generate_module,
     greedy_generate_staged,
@@ -351,6 +351,14 @@ def test_serving_metrics_schema_golden():
     m["hot_swaps"].inc(2)
     m["kv_read"].inc(768)
     m["kv_capacity"].inc(1024)
+    # and the counters that a block with experts brings itself
+    block = LatentMoELM(vocab_size=8, max_len=8).decode_spec(None).instruments(
+        registry)
+    block["assignments"].inc(96)
+    block["held"].inc(24)
+    block["load"].observe(1.5)
+    block["tiles"].inc(7)
+    block["touched"].inc(6)
     golden = open(os.path.join(GOLDEN, "serving_metrics.txt")).read()
     assert registry.to_prometheus(labels={"run_id": "fleet1234"}) == golden
     # get-or-create: a second call must hand back the same instruments
