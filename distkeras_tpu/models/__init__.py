@@ -17,6 +17,7 @@ from distkeras_tpu.models.hf import HuggingFaceModel
 from distkeras_tpu.models.hf_staged import PretrainedStagedLM, gpt2_to_staged
 from distkeras_tpu.models.generate import greedy_generate
 from distkeras_tpu.models.latent_moe import LatentMoELM
+from distkeras_tpu.models.scmoe import ShortcutMoELM
 from distkeras_tpu.models.staged import StagedLM, StagedTransformer
 from distkeras_tpu.models.transformer import (
     TransformerClassifier,
@@ -40,6 +41,7 @@ __all__ = [
     "TransformerEncoderBlock",
     "TransformerLM",
     "LatentMoELM",
+    "ShortcutMoELM",
     "StagedTransformer",
     "StagedLM",
     "greedy_generate",

@@ -39,7 +39,11 @@ class DecodeSpec:
     ``state``
         The per-layer state a position keeps, as ``(name, row_width)`` pools
         a layer: ``(("k", 768), ("v", 768))`` for GPT-2's block, ``(("latent",
-        576),)`` for latent attention.  Every layer keeps the same kinds.
+        576),)`` for latent attention, ``(("latent_0", 576), ("latent_1",
+        576))`` for a double layer of two latent attentions: two pools of the
+        same kind, each written and read by its own sub-block within one
+        ``prefill`` or ``step`` call (``num_layers`` then counts the double
+        layers).  Every layer keeps the same kinds.
     ``weights``
         The pytree every program takes as its first argument (``params()``).
     ``num_layers``, ``max_len``, ``vocab_size``

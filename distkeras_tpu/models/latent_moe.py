@@ -56,6 +56,17 @@ A plain dataclass, not a flax module: ``init(key)`` makes a parameter tree,
 ``decode_spec(params)`` is what :class:`~distkeras_tpu.serving.ServingEngine`
 serves it by.  The tensor-parallel (``mesh=``) and speculative
 (``draft_model=``) builds are not supported for this block yet.
+
+**Shared with the other latent-attention block** (``models/scmoe.py``'s
+``ShortcutMoELM``, which imports them from here): the module-level
+:func:`rms_norm`, :func:`rope`, :func:`expanded_attention`,
+:func:`absorbed_step`, :func:`gated`, :func:`held_experts_terms` with
+:func:`tile_height`, :func:`embed_tokens`, :func:`final_head`,
+:func:`geometry_of`, :func:`init_params`, :func:`moe_instruments` and
+:func:`observe_walk`.  They take what differs
+between the blocks (the softmax's scale, the latent's width, the held range,
+the router's width) as arguments; ``LatentMoELM``'s methods of the same
+names hand them its own.
 """
 
 from __future__ import annotations
@@ -136,6 +147,205 @@ def rope(x, positions, inv_freq, amplitude=1.0):
     half = x.shape[-1] // 2
     a, b = x[..., :half], x[..., half:]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+
+
+def init_params(tree, shapes, key, dtype, bias_scale):
+    """Fill ``tree`` (the containers, empty) with a leaf for every ``path:
+    (shape, fan)`` of ``shapes``: matrices normal at ``1 / sqrt(fan)`` in
+    ``dtype`` (``fan`` is the block's to say: a matrix's fan in, or one
+    number for every matrix), ``fan`` None a norm's weight (ones, float32),
+    0 a router's bias (normal at ``bias_scale``, float32); a leaf named
+    ``router`` stays float32."""
+    for index, (path, (shape, fan)) in enumerate(
+            sorted(shapes.items(), key=str)):
+        if fan is None:
+            leaf = jnp.ones(shape, F32)
+        else:
+            noise = jax.random.normal(jax.random.fold_in(key, index),
+                                      shape, F32)
+            leaf = (bias_scale * noise if fan == 0
+                    else (fan ** -0.5 * noise).astype(
+                        F32 if path[-1] == "router" else dtype))
+        node = tree
+        for part in path[:-1]:
+            node = node[part]
+        node[path[-1]] = leaf
+    return tree
+
+
+def expanded_attention(p, q_n, q_r, c, k_r, scale):
+    """Causal latent attention of a chunk that starts at position 0, in the
+    expanded form: per-head keys and values from the chunk's own latents
+    (``p["k_up"]``, ``p["v_up"]``), a block of queries at a time against the
+    keys up to the block's end, out through ``p["o"]``.  ``[batch, rows,
+    dim]``."""
+    rows = c.shape[1]
+    kind = p["k_up"].dtype
+    k_n = _dot(c, p["k_up"], "brc,chn->brhn")
+    v = _dot(c, p["v_up"], "brc,chv->brhv").astype(kind)
+    q = jnp.concatenate([q_n, q_r], axis=-1).astype(kind)
+    k = jnp.concatenate(
+        [k_n, jnp.broadcast_to(k_r[:, :, None, :],
+                               k_n.shape[:-1] + k_r.shape[-1:])],
+        axis=-1).astype(kind)
+    out = []
+    for start in range(0, rows, QUERY_BLOCK):
+        end = min(rows, start + QUERY_BLOCK)
+        s = jnp.einsum("bqhe,bkhe->bhqk", q[:, start:end], k[:, :end],
+                       preferred_element_type=F32) * scale
+        causal = (jnp.arange(end)[None, :]
+                  <= jnp.arange(start, end)[:, None])
+        weights = jax.nn.softmax(jnp.where(causal, s, -jnp.inf), axis=-1)
+        out.append(jnp.einsum("bhqk,bkhv->bqhv", weights.astype(kind),
+                              v[:, :end], preferred_element_type=F32))
+    return _dot(jnp.concatenate(out, axis=1), p["o"], "bqhv,hvd->bqd")
+
+
+def absorbed_step(p, pool, tables, pos, q_n, q_r, c, k_r, latent_width,
+                  scale):
+    """The serving step's latent attention, one token a slot (``rows`` 1),
+    in the absorbed form over one pool's paged rows: ``(pool, [slots, 1,
+    dim])``."""
+    from distkeras_tpu.serving.cache import paged_latent_attention
+
+    q_c = _dot(q_n[:, 0], p["k_up"], "shn,chn->shc")
+    row = jnp.concatenate([c, k_r], axis=-1)[:, 0].astype(pool.dtype)
+    pool, o_c = paged_latent_attention(
+        pool, tables, pos, jnp.concatenate([q_c, q_r[:, 0]], axis=-1),
+        row, latent_width, scale)
+    o = _dot(o_c, p["v_up"], "shc,chv->shv")
+    return pool, _dot(o, p["o"], "shv,hvd->sd")[:, None]
+
+
+def gated(x, gate, up, down):
+    """The gated feed-forward ``(silu(x gate) * (x up)) down``."""
+    return _dot(jax.nn.silu(_dot(x, gate, "td,dw->tw"))
+                * _dot(x, up, "td,dw->tw"), down, "tw,wd->td")
+
+
+def embed_tokens(params, tokens, positions):
+    """``DecodeSpec.embed`` of a block without a position table: the
+    tokens' rows in float32."""
+    return params["embed"][tokens].astype(F32)
+
+
+def geometry_of(model):
+    """``DecodeSpec.geometry`` of a dataclass model: every field, as text."""
+    return tuple(sorted((f.name, str(getattr(model, f.name)))
+                        for f in dataclasses.fields(model)))
+
+
+def final_head(params, x, eps, at=None):
+    """The final norm and the untied head over ``x [batch, rows, dim]``;
+    with ``at`` one row of a ``[1, width, dim]`` chunk alone: the head runs
+    once."""
+    if at is not None:
+        x = jax.lax.dynamic_index_in_dim(x[0], at, axis=0, keepdims=False)
+    return _dot(rms_norm(x, params["norm"], eps), params["head"],
+                "...d,dv->...v")
+
+
+def held_experts_terms(p, h, ids, weights, held, experts, live=None):
+    """The held experts' part of an expert layer's output for ``h [tokens,
+    dim]``, and the walk's counts: how many assignments of the ``live``
+    tokens met each held expert (``[count]`` int32), the tiles that did
+    work and the held experts with at least one row (scalars).  ``held``
+    is the ``(first, count)`` of the experts whose stacked weights ``p``
+    holds (``experts_gate``, ``experts_up``, ``experts_down``), ``experts``
+    the router's width.  Every assignment is a row: sorted by expert, the
+    rows of every id outside the held range past the last group, each held
+    group cut into tiles of :func:`tile_height`; no capacity, nothing
+    dropped.  The loop is as long as the tiles that hold a row, so it has
+    no reverse mode."""
+    first, count = held
+    tokens, k = ids.shape
+    rows = tokens * k
+    tile = tile_height(rows, experts)
+    local = ids.reshape(-1) - first
+    held = (local >= 0) & (local < count)
+    group = jnp.where(held, local, count)
+    order = jnp.argsort(group, stable=True)
+    member = group[:, None] == jnp.arange(count)[None, :]
+    sizes = jnp.sum(member, axis=0, dtype=jnp.int32)
+    tiles = -(-sizes // tile)
+    walked = jnp.sum(tiles)
+    # where a group begins, in sorted rows and in tiles, and each tile's
+    # expert: sums under a mask, because tables made with gathers and
+    # cumulative sums made every program slow to load (PERF.md, PR 32)
+    before = jnp.arange(count)[None, :] < jnp.arange(count)[:, None]
+    start = jnp.sum(jnp.where(before, sizes[None, :], 0), axis=1)
+    first_tile = jnp.sum(jnp.where(before, tiles[None, :], 0), axis=1)
+    most = min((rows + count * (tile - 1)) // tile, rows)
+    expert = jnp.minimum(count - 1, jnp.sum(
+        jnp.arange(most)[:, None] >= (first_tile + tiles)[None, :], axis=1))
+    # a group's last tile may be cut past the last sorted row
+    order = jnp.concatenate([order, jnp.full(tile, rows, order.dtype)])
+    weight = weights.reshape(-1)
+    kind = p["experts_gate"].dtype
+    x = h.astype(kind)
+    dot = lambda a, w: jnp.dot(a, w, preferred_element_type=F32)
+
+    def one_tile(i, out):
+        e = expert[i]
+        at = start[e] + (i - first_tile[e]) * tile  # the tile's first row
+        row = jax.lax.dynamic_slice_in_dim(order, at, tile)
+        mine = jnp.arange(tile) < start[e] + sizes[e] - at
+        token = jnp.where(mine, row // k, tokens)  # the rest goes nowhere
+        rows_in = x[jnp.minimum(token, tokens - 1)]
+        hidden = (jax.nn.silu(dot(rows_in, p["experts_gate"][e]))
+                  * dot(rows_in, p["experts_up"][e])).astype(kind)
+        term = (dot(hidden, p["experts_down"][e])
+                * weight[jnp.minimum(row, rows - 1)][:, None])
+        return out.at[token].add(term, mode="drop")
+
+    out = jax.lax.fori_loop(0, walked, one_tile,
+                            jnp.zeros((tokens, h.shape[-1]), F32))
+    if live is not None:
+        member = member & jnp.repeat(live, k)[:, None]
+    return out, (jnp.sum(member, axis=0, dtype=jnp.int32), walked,
+                 jnp.sum(sizes > 0, dtype=jnp.int32))
+
+
+def moe_instruments(registry):
+    """The counters that an expert block's walk feeds, under the names the
+    benchmark's readers know."""
+    return {
+        "assignments": registry.counter(
+            "serving_moe_assignments_total",
+            help="expert assignments routed: live tokens x experts a "
+                 "token x expert layers, over all the experts"),
+        "held": registry.counter(
+            "serving_moe_assignments_held_total",
+            help="expert assignments that met an expert held here"),
+        "load": registry.histogram(
+            "serving_moe_expert_load_max_over_mean",
+            help="one observation a decode step: the fullest held "
+                 "expert's assignments over the held experts' mean, "
+                 "averaged over the expert layers"),
+        "tiles": registry.counter(
+            "serving_moe_tiles_total",
+            help="row tiles of the held experts' products that did "
+                 "work, as the programs count them: each reads one "
+                 "expert's weights once"),
+        "touched": registry.counter(
+            "serving_moe_experts_touched_total",
+            help="held experts with at least one assignment row in "
+                 "a call, summed over the expert layers"),
+    }
+
+
+def observe_walk(instruments, counts, tiles, touched, assigned, step):
+    """Feed :func:`moe_instruments` from the expert layers' stacked counts
+    (``counts [layers, held]``, ``tiles`` and ``touched`` ``[layers]``) of
+    one program that routed ``assigned`` assignments."""
+    instruments["tiles"].inc(int(tiles.sum()))
+    instruments["touched"].inc(int(touched.sum()))
+    instruments["assignments"].inc(assigned)
+    instruments["held"].inc(int(counts.sum()))
+    mean = counts.mean(axis=1)
+    if step and (mean > 0).all():
+        instruments["load"].observe(
+            float((counts.max(axis=1) / mean).mean()))
 
 
 @dataclasses.dataclass(frozen=True, eq=False)
@@ -245,18 +455,7 @@ class LatentMoELM:
         bias float32 (the bias non-zero, so that picking differs from
         weighing)."""
         tree = {"layers": [{} for _ in range(self.num_hidden_layers)]}
-        for index, (path, (shape, fan)) in enumerate(
-                sorted(self.param_shapes().items(), key=str)):
-            if fan is None:
-                leaf = jnp.ones(shape, F32)
-            else:
-                noise = jax.random.normal(jax.random.fold_in(key, index),
-                                          shape, F32)
-                leaf = (0.01 * noise if fan == 0 else (fan ** -0.5 * noise).astype(
-                    F32 if path[-1] == "router" else dtype))
-            node = tree if len(path) == 1 else tree["layers"][path[1]]
-            node[path[-1]] = leaf
-        return tree
+        return init_params(tree, self.param_shapes(), key, dtype, 0.01)
 
     # ---------------------------------------------------------- attention
 
@@ -275,50 +474,17 @@ class LatentMoELM:
                 rope(k_r, positions, inv_freq, amplitude))
 
     def expanded_attention(self, p, q_n, q_r, c, k_r):
-        """Causal attention of a chunk that starts at position 0, in the
-        expanded form: per-head keys and values from the chunk's own
-        latents, a block of queries at a time against the keys up to the
-        block's end.  ``[batch, rows, dim]``."""
-        rows = c.shape[1]
-        kind = p["k_up"].dtype
-        k_n = _dot(c, p["k_up"], "brc,chn->brhn")
-        v = _dot(c, p["v_up"], "brc,chv->brhv").astype(kind)
-        q = jnp.concatenate([q_n, q_r], axis=-1).astype(kind)
-        k = jnp.concatenate(
-            [k_n, jnp.broadcast_to(k_r[:, :, None, :],
-                                   k_n.shape[:-1] + k_r.shape[-1:])],
-            axis=-1).astype(kind)
-        out = []
-        for start in range(0, rows, QUERY_BLOCK):
-            end = min(rows, start + QUERY_BLOCK)
-            s = jnp.einsum("bqhe,bkhe->bhqk", q[:, start:end], k[:, :end],
-                           preferred_element_type=F32) * self.softmax_scale
-            causal = (jnp.arange(end)[None, :]
-                      <= jnp.arange(start, end)[:, None])
-            weights = jax.nn.softmax(jnp.where(causal, s, -jnp.inf), axis=-1)
-            out.append(jnp.einsum("bhqk,bkhv->bqhv", weights.astype(kind),
-                                  v[:, :end], preferred_element_type=F32))
-        return _dot(jnp.concatenate(out, axis=1), p["o"], "bqhv,hvd->bqd")
+        """:func:`expanded_attention` at this model's softmax scale."""
+        return expanded_attention(p, q_n, q_r, c, k_r, self.softmax_scale)
 
     def absorbed_step(self, p, pool, tables, pos, q_n, q_r, c, k_r):
-        """The serving step's attention, one token a slot (``rows`` 1), in
-        the absorbed form over the paged rows: ``(pool, [slots, 1, dim])``."""
-        from distkeras_tpu.serving.cache import paged_latent_attention
-
-        q_c = _dot(q_n[:, 0], p["k_up"], "shn,chn->shc")
-        row = jnp.concatenate([c, k_r], axis=-1)[:, 0].astype(pool.dtype)
-        pool, o_c = paged_latent_attention(
-            pool, tables, pos, jnp.concatenate([q_c, q_r[:, 0]], axis=-1),
-            row, self.kv_lora_rank, self.softmax_scale)
-        o = _dot(o_c, p["v_up"], "shc,chv->shv")
-        return pool, _dot(o, p["o"], "shv,hvd->sd")[:, None]
+        """:func:`absorbed_step` at this model's latent width and scale."""
+        return absorbed_step(p, pool, tables, pos, q_n, q_r, c, k_r,
+                             self.kv_lora_rank, self.softmax_scale)
 
     # ------------------------------------------------------- feed-forward
 
-    @staticmethod
-    def _gated(x, gate, up, down):
-        return _dot(jax.nn.silu(_dot(x, gate, "td,dw->tw"))
-                    * _dot(x, up, "td,dw->tw"), down, "tw,wd->td")
+    _gated = staticmethod(gated)
 
     def route(self, p, h):
         """``(ids, weights) [tokens, k]``: the top k of ``sigmoid(W_r h) +
@@ -335,61 +501,10 @@ class LatentMoELM:
         return ids, weights * self.routed_scaling_factor
 
     def held_experts_terms(self, p, h, ids, weights, live=None):
-        """The held experts' part of the layer's output for ``h [tokens,
-        dim]``, and the walk's counts: how many assignments of the ``live``
-        tokens met each held expert (``[count]`` int32), the tiles that did
-        work and the held experts with at least one row (scalars).  Every
-        assignment is a row: sorted by expert, the absent experts' rows
-        past the last group, each held group cut into tiles of
-        :func:`tile_height`; no capacity, nothing dropped.  The loop is as
-        long as the tiles that hold a row, so it has no reverse mode."""
-        first, count = self.held
-        tokens, k = ids.shape
-        rows = tokens * k
-        tile = tile_height(rows, self.num_experts)
-        local = ids.reshape(-1) - first
-        held = (local >= 0) & (local < count)
-        group = jnp.where(held, local, count)
-        order = jnp.argsort(group, stable=True)
-        member = group[:, None] == jnp.arange(count)[None, :]
-        sizes = jnp.sum(member, axis=0, dtype=jnp.int32)
-        tiles = -(-sizes // tile)
-        walked = jnp.sum(tiles)
-        # where a group begins, in sorted rows and in tiles, and each tile's
-        # expert: sums under a mask, because tables made with gathers and
-        # cumulative sums made every program slow to load (PERF.md, PR 32)
-        before = jnp.arange(count)[None, :] < jnp.arange(count)[:, None]
-        start = jnp.sum(jnp.where(before, sizes[None, :], 0), axis=1)
-        first_tile = jnp.sum(jnp.where(before, tiles[None, :], 0), axis=1)
-        most = min((rows + count * (tile - 1)) // tile, rows)
-        expert = jnp.minimum(count - 1, jnp.sum(
-            jnp.arange(most)[:, None] >= (first_tile + tiles)[None, :], axis=1))
-        # a group's last tile may be cut past the last sorted row
-        order = jnp.concatenate([order, jnp.full(tile, rows, order.dtype)])
-        weight = weights.reshape(-1)
-        kind = p["experts_gate"].dtype
-        x = h.astype(kind)
-        dot = lambda a, w: jnp.dot(a, w, preferred_element_type=F32)
-
-        def one_tile(i, out):
-            e = expert[i]
-            at = start[e] + (i - first_tile[e]) * tile  # the tile's first row
-            row = jax.lax.dynamic_slice_in_dim(order, at, tile)
-            mine = jnp.arange(tile) < start[e] + sizes[e] - at
-            token = jnp.where(mine, row // k, tokens)  # the rest goes nowhere
-            rows_in = x[jnp.minimum(token, tokens - 1)]
-            hidden = (jax.nn.silu(dot(rows_in, p["experts_gate"][e]))
-                      * dot(rows_in, p["experts_up"][e])).astype(kind)
-            term = (dot(hidden, p["experts_down"][e])
-                    * weight[jnp.minimum(row, rows - 1)][:, None])
-            return out.at[token].add(term, mode="drop")
-
-        out = jax.lax.fori_loop(0, walked, one_tile,
-                                jnp.zeros((tokens, h.shape[-1]), F32))
-        if live is not None:
-            member = member & jnp.repeat(live, k)[:, None]
-        return out, (jnp.sum(member, axis=0, dtype=jnp.int32), walked,
-                     jnp.sum(sizes > 0, dtype=jnp.int32))
+        """:func:`held_experts_terms` for this model's held range and its
+        router's width."""
+        return held_experts_terms(p, h, ids, weights, self.held,
+                                  self.num_experts, live)
 
     def counted_feed_forward(self, p, h, live=None):
         """``(ffn(h) [tokens, dim], the walk's counts or None)`` of one
@@ -416,14 +531,13 @@ class LatentMoELM:
         batch, rows = tokens.shape
         positions = jnp.broadcast_to(jnp.arange(rows)[None], tokens.shape)
         eps = self.rms_norm_eps
-        x = params["embed"][tokens].astype(F32)
+        x = embed_tokens(params, tokens, positions)
         for p in params["layers"]:
             latent = self.latent(p, rms_norm(x, p["attn_norm"], eps), positions)
             x = x + self.expanded_attention(p, *latent)
             h = rms_norm(x, p["ffn_norm"], eps).reshape(batch * rows, -1)
             x = x + self.feed_forward(p, h)[0].reshape(x.shape)
-        return _dot(rms_norm(x, params["norm"], eps), params["head"],
-                    "brd,dv->brv")
+        return final_head(params, x, eps)
 
     # ------------------------------------------------------------ serving
 
@@ -441,9 +555,6 @@ class LatentMoELM:
         eps = self.rms_norm_eps
         expert_layers = sum(self.is_expert_layer(i)
                             for i in range(self.num_hidden_layers))
-
-        def embed(params, tokens, positions):
-            return params["embed"][tokens].astype(F32)
 
         # a layer's work is traced and lowered once a shape, not once a
         # layer: the expert layers are alike (XLA inlines the calls)
@@ -478,57 +589,19 @@ class LatentMoELM:
             return {"latent": pool}, x, walk
 
         def head(params, x, at=None):
-            if at is not None:  # one row of a chunk: the head runs once
-                x = jax.lax.dynamic_index_in_dim(x[0], at, axis=0,
-                                                 keepdims=False)
-            return _dot(rms_norm(x, params["norm"], eps), params["head"],
-                        "...d,dv->...v")
-
-        def instruments(registry):
-            return {
-                "assignments": registry.counter(
-                    "serving_moe_assignments_total",
-                    help="expert assignments routed: live tokens x experts a "
-                         "token x expert layers, over all the experts"),
-                "held": registry.counter(
-                    "serving_moe_assignments_held_total",
-                    help="expert assignments that met an expert held here"),
-                "load": registry.histogram(
-                    "serving_moe_expert_load_max_over_mean",
-                    help="one observation a decode step: the fullest held "
-                         "expert's assignments over the held experts' mean, "
-                         "averaged over the expert layers"),
-                "tiles": registry.counter(
-                    "serving_moe_tiles_total",
-                    help="row tiles of the held experts' products that did "
-                         "work, as the programs count them: each reads one "
-                         "expert's weights once"),
-                "touched": registry.counter(
-                    "serving_moe_experts_touched_total",
-                    help="held experts with at least one assignment row in "
-                         "a call, summed over the expert layers"),
-            }
+            return final_head(params, x, eps, at)
 
         def observe(instruments, aux, rows, step):
             counts, tiles, touched = map(np.stack, zip(
                 *(a for a in aux if a is not None)))
-            instruments["tiles"].inc(int(tiles.sum()))
-            instruments["touched"].inc(int(touched.sum()))
-            instruments["assignments"].inc(
-                rows * self.num_experts_per_tok * len(counts))
-            instruments["held"].inc(int(counts.sum()))
-            mean = counts.mean(axis=1)
-            if step and (mean > 0).all():
-                instruments["load"].observe(
-                    float((counts.max(axis=1) / mean).mean()))
+            observe_walk(instruments, counts, tiles, touched,
+                         rows * self.num_experts_per_tok * len(counts), step)
 
         return DecodeSpec(
             state=(("latent", self.row_width),), weights=params,
             num_layers=self.num_hidden_layers, max_len=int(self.max_len),
             vocab_size=int(self.vocab_size),
-            geometry=tuple(sorted(
-                (f.name, str(getattr(self, f.name)))
-                for f in dataclasses.fields(self))),
-            embed=embed, prefill=prefill, step=step, head=head,
-            instruments=instruments if expert_layers else None,
+            geometry=geometry_of(self),
+            embed=embed_tokens, prefill=prefill, step=step, head=head,
+            instruments=moe_instruments if expert_layers else None,
             observe=observe if expert_layers else None)

@@ -10,7 +10,7 @@ a service that admits requests whenever they arrive:
   :class:`~distkeras_tpu.models.decode.DecodeSpec` (the block contract: the
   kinds of state a layer keeps a position, and the model's own embedding,
   prefill layer, step layer and head): ``TransformerLM``, ``StagedLM``,
-  ``LatentMoELM``;
+  ``LatentMoELM``, ``ShortcutMoELM``;
 * :mod:`~distkeras_tpu.serving.cache` — the paged cache: slot page tables
   over shared pools, one tuple of per-layer pools for each kind of state the
   block declares (keys and values; or latent attention's one row for all
@@ -40,8 +40,8 @@ prefill width ladder; ``draft_model``/``spec_tokens`` — speculative
 decoding with exact accept/resample semantics; ``mesh`` — tensor-parallel
 decode over the local devices.  The last two are builds a block opts into
 (``DecodeSpec.window`` / ``.shard``): GPT-2's block brings both,
-``LatentMoELM``'s neither yet, and the engine refuses those combinations at
-construction.
+the two latent-attention blocks neither yet, and the engine refuses those
+combinations at construction.
 """
 
 from distkeras_tpu.serving.cache import (

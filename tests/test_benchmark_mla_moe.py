@@ -145,11 +145,14 @@ def test_the_line_is_made_with_and_without_the_walks_counters(harness, case):
     metric is left out and the line is made all the same."""
     marks, want = TILE_MARKS[case]
     manifest = harness.load_manifest()
-    entry = manifest["per_layer"][-1]
+    entry = next(m for m in manifest["per_layer"]
+                 if m["name"] == "moe_tiles_per_expert")
     assert entry == {
         "name": "moe_tiles_per_expert", "unit": "ratio", "better": "lower",
         "source": "program_counter", "layer": "models",
-        "moves": "serve_tokens_per_s", "workloads": [CELL]}
+        "moves": "serve_tokens_per_s",
+        # a later expert configuration's cell reads the same counters
+        "workloads": [CELL] + entry["workloads"][1:]}
     run = {"correct": True, "attempted": 5, "failed": 0,
            "facts": {"marks": marks}, "end_to_end": {},
            "device": {"platform": "tpu"}}
@@ -174,11 +177,12 @@ def test_the_manifest_lists_the_cell_under_what_it_reports(harness):
         "serve_peak_hbm_gb", "decode_kv_read_share", "decode_chained_share",
         "moe_held_share", "moe_load_max_over_mean", "state_bytes_per_position",
         "moe_tiles_per_expert"}
+    names = [m["name"] for m in manifest["per_layer"]]
     new = [m for m in manifest["per_layer"]
            if m["name"] in WANT or m["name"] == "moe_tiles_per_expert"]
-    assert [m["name"] for m in manifest["per_layer"][-4:]] == [
-        m["name"] for m in new]  # appended, at the end, PR 32's after PR 31's
-    assert all(m["workloads"] == [CELL] and m["layer"] == "models"
+    at = names.index(new[0]["name"])  # appended in a run, PR 32's after PR
+    assert names[at:at + 4] == [m["name"] for m in new]  # 31's; later PRs' behind
+    assert all(m["workloads"][0] == CELL and m["layer"] == "models"
                and m["moves"] == "serve_tokens_per_s" for m in new)
 
 

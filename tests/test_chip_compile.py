@@ -160,3 +160,61 @@ def test_the_step_reads_each_experts_weights_where_they_lie(one_chip):
     assert "ragged" not in text and text.count(" while(") == 1
     one_matrix = dim * width * 2
     assert compiled.memory_analysis().temp_size_in_bytes < one_matrix // 2
+
+
+# ------------------------------------- two latent pools a layer, one step
+
+
+def test_a_double_layers_step_copies_neither_pool_nor_the_experts(one_chip):
+    """The shortcut-expert cell's step of one double layer at its real
+    shapes (128 slots, 128 pages of 16 a slot, 64 heads, two 576-wide pools,
+    16 held experts of 6144 x 2048 and a 768-output router, bfloat16): two
+    ``paged_latent_attention`` calls against two pools inside one ``step``.
+    Both pools go in and out row-major as they lie, the program holds no
+    copy of either nor of the stacked expert weights or of a dense
+    feed-forward's matrix, and its temporaries stay far under one pool."""
+    import re
+
+    from distkeras_tpu.models import ShortcutMoELM
+    from distkeras_tpu.models.latent_moe import tile_height
+    from distkeras_tpu.serving.cache import pool_width
+
+    slots, pages, page = 128, 128, 16
+    model = ShortcutMoELM(vocab_size=16384, max_len=pages * page,
+                          num_layers=1, held_experts=(0, 16))
+    assert tile_height(slots * model.moe_topk, model.router_width) == 16
+    struct = lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: struct(a.shape, a.dtype),
+        jax.eval_shape(lambda key: model.init(key, jnp.bfloat16),
+                       jax.random.PRNGKey(0)))
+    spec = model.decode_spec(None)
+    assert spec.state == (("latent_0", 576), ("latent_1", 576))
+    pool_shape = (slots * pages + 1, page, pool_width(576))
+
+    def step(params, first, second, x, tables, pos, live):
+        pools, x, counts = spec.step(
+            params, 0, x, {"latent_0": first, "latent_1": second}, tables,
+            pos, live)
+        return pools["latent_0"], pools["latent_1"], x, counts
+
+    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, struct(pool_shape, jnp.bfloat16),
+        struct(pool_shape, jnp.bfloat16),
+        struct((slots, 1, 6144), jnp.float32),
+        struct((slots, pages), jnp.int32), struct((slots,), jnp.int32),
+        struct((slots, 1), jnp.bool_)).compile()
+    text = compiled.as_text()
+    pool = "bf16[%d,%d,%d]" % pool_shape
+    layouts = set(re.findall(re.escape(pool) + r"\{([\d,]+)", text))
+    assert layouts == {"2,1,0"}, layouts  # row-major, everywhere it appears
+    copied = [line for line in text.splitlines() if " copy(" in line and any(
+        shape in line for shape in (
+            pool, "bf16[16,6144,2048]", "bf16[16,2048,6144]",
+            "bf16[6144,12288]", "bf16[12288,6144]"))]
+    assert not copied, copied[:3]
+    # the two pools' loops over live blocks and the walk over row tiles
+    assert text.count(" while(") == 3 and "ragged" not in text
+    one_pool = pool_shape[0] * pool_shape[1] * pool_shape[2] * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < one_pool // 4
