@@ -127,6 +127,7 @@ from distkeras_tpu.serving.sampling import (
     modified_probs,
     sample_one,
     sample_tokens,
+    sampling_level,
     speculative_verify_tokens,
 )
 
@@ -191,6 +192,18 @@ def serving_metrics(registry=None) -> dict:
             help="decode steps dispatched while the step before them was "
                  "still unread by the host: their token and key inputs were "
                  "that step's device outputs",
+        ),
+        "decode_sampled": registry.counter(
+            "serving_decode_steps_sampled_total",
+            help="decode steps in which some active slot sampled "
+                 "(temperature > 0), so the step drew from a distribution; "
+                 "the other steps took the argmax and nothing else",
+        ),
+        "decode_sorted": registry.counter(
+            "serving_decode_steps_sorted_total",
+            help="decode steps that sorted every slot's vocabulary: some "
+                 "active sampling slot had a top-k or a top-p, or the step "
+                 "was a speculative verify iteration",
         ),
         "spec_proposed": registry.counter(
             "serving_spec_proposed_total",
@@ -384,7 +397,13 @@ class ServingEngine:
     changed them.  Tokens reach the host, and ``ttft_s`` is stamped, one
     program behind.  ``serving_decode_steps_chained_total`` over
     ``serving_decode_steps_total`` says how often a step was dispatched
-    with the step before it still unread.  ``cancel`` / ``drain`` /
+    with the step before it still unread.  The step's sampling takes the
+    cheapest path that gives the same tokens, chosen inside the program from
+    those knobs (``sampling.sampling_level``): an ``argmax`` alone while no
+    active slot samples, the sort over the vocabulary only where some
+    sampling slot truncates; ``serving_decode_steps_sampled_total`` and
+    ``serving_decode_steps_sorted_total`` count the steps that took more
+    than the ``argmax``.  ``cancel`` / ``drain`` /
     ``hot_swap`` / ``stop`` and a crash read everything in flight first:
     the tokens a caller gets back are those the device made.  With a
     ``draft_model`` the loop is serial (the accepted count decides the
@@ -459,6 +478,9 @@ class ServingEngine:
         # of paged_decode_attention's block, for the kv_read counter)
         self._kv_block = self._cache.page_size * decode_block_pages(
             self._cache.page_size, self._cache.pages_per_slot)
+        # the logits' width, for the host's twin of the program's choice of
+        # a sampling path (geometry: a hot swap keeps it)
+        self._vocab = spec.vocab_size
         self._buckets = _resolve_buckets(
             prefill_buckets, self._cache.page_size, self._width)
         self._queue = RequestQueue(queue_size)
@@ -533,6 +555,9 @@ class ServingEngine:
             "keys": jnp.zeros((s, 2), jnp.uint32),
         }
         self._dirty = True
+        # sampling_level of the knobs as last uploaded: the path the decode
+        # step takes, for the counters
+        self._level = 0
         # programs whose tokens are still on the device, oldest first
         self._inflight: collections.deque = collections.deque()
 
@@ -717,7 +742,7 @@ class ServingEngine:
             logits = spec.head(params, x)[:, 0]
             split = jax.vmap(jax.random.split)(keys)
             new_keys, subs = split[:, 0], split[:, 1]
-            tok = sample_tokens(logits, subs, temp, top_k, top_p)
+            tok = sample_tokens(logits, subs, temp, top_k, top_p, active)
             tok = jnp.where(active, tok, 0)
             outs = _give_pools(spec, pools) + (tok,)
             if qprobs:
@@ -1300,6 +1325,9 @@ class ServingEngine:
              dev["top_p"], dev["active"]) = jax.device_put(
                 (tables, self._pos * active, self._temp.copy(),
                  self._topk.copy(), self._topp.copy(), active))
+            # the path the program will choose from these same arrays
+            self._level = int(sampling_level(
+                self._temp, self._topk, self._topp, active, self._vocab))
             self._dirty = False
         return (dev["tables"], dev["pos"], dev["last"], dev["keys"],
                 dev["temp"], dev["top_k"], dev["top_p"], dev["active"])
@@ -1324,6 +1352,10 @@ class ServingEngine:
                 *self._step_inputs()))
             self._dev.update(last=tok, pos=pos, keys=keys)
             self._metrics["decode_steps"].inc()
+            if self._level >= 1:
+                self._metrics["decode_sampled"].inc()
+            if self._level >= 2:
+                self._metrics["decode_sorted"].inc()
             if any(rec.prefill is None for rec in self._inflight):
                 # the step before is unread: this one took its outputs
                 self._metrics["decode_chained"].inc()
@@ -1452,6 +1484,10 @@ class ServingEngine:
         dt = time.perf_counter() - t0
         self._metrics["token_latency"].observe(dt)
         self._metrics["decode_steps"].inc()
+        # the verify program judges every window under the modified
+        # distributions: the sort, whatever the knobs
+        self._metrics["decode_sampled"].inc()
+        self._metrics["decode_sorted"].inc()
         spec_slots = self._active & self._spec_on
         n_spec = int(spec_slots.sum())
         if n_spec:

@@ -15,6 +15,22 @@ Conventions (matching the common HF/vLLM semantics):
   probability-sorted tokens with cumulative mass ``>= top_p`` is kept
   (the token that crosses the threshold is always kept).
 
+Which path a batch takes (:func:`sampling_level`) is data too, decided once
+for the whole batch from the knobs of its active slots, inside the program
+(a ``lax.switch``: one compiled step, the branches not taken do not run):
+
+* level 0, no active slot samples — the ``argmax`` alone: no sort over the
+  vocabulary, no softmax, no cumulative sum, no Gumbel draw;
+* level 1, some active slot samples and none truncates — the
+  temperature-scaled logits straight into ``jax.random.categorical``;
+* level 2, some active sampling slot has a top-k or a top-p — the sort,
+  for every slot.
+
+The tokens are the same at every level: a batch is at the level of its most
+demanding active slot, and a lower level leaves out only what the knobs make
+a no-op (both masks of :func:`filtered_logits` all true) or what the final
+``where(temperature > 0, ...)`` would throw away.
+
 Speculative decoding (:func:`speculative_verify`) builds on the same
 filtered distributions: the acceptance test and the rejection-resample both
 use the **modified** distribution (after temperature/top-k/top-p), which is
@@ -32,9 +48,16 @@ __all__ = [
     "modified_probs",
     "sample_one",
     "sample_tokens",
+    "sampling_level",
     "speculative_verify",
     "speculative_verify_tokens",
 ]
+
+
+def _scaled(logits, temperature):
+    """Temperature-scaled logits (the traced divide-by-zero is guarded even
+    though the greedy branch wins the final where)."""
+    return logits / jnp.where(temperature > 0, temperature, 1.0)
 
 
 def filtered_logits(logits, temperature, top_k, top_p):
@@ -44,10 +67,7 @@ def filtered_logits(logits, temperature, top_k, top_p):
     shared with the speculative accept/resample path."""
     vocab = logits.shape[-1]
 
-    # temperature-scaled working copy (guard the traced divide-by-zero even
-    # though the greedy branch wins the final where)
-    safe_t = jnp.where(temperature > 0, temperature, 1.0)
-    scaled = logits / safe_t
+    scaled = _scaled(logits, temperature)
 
     desc = jnp.sort(scaled)[::-1]  # [vocab], descending
 
@@ -78,19 +98,63 @@ def modified_probs(logits, temperature, top_k, top_p):
     return jax.nn.softmax(filtered_logits(logits, temperature, top_k, top_p))
 
 
-def sample_one(logits, key, temperature, top_k, top_p):
-    """Sample one token id from ``logits [vocab]``; every argument after
-    ``logits`` is a traced scalar.  Returns an int32 scalar."""
+def sampling_level(temperature, top_k, top_p, active, vocab):
+    """What the sampling of a batch needs, from its knobs alone: 0, the
+    ``argmax``; 1, a draw from the temperature-scaled logits; 2, the sort
+    (some top-k or top-p truncates).  Only slots that are ``active`` and
+    sample (``temperature > 0``) count: a greedy or an idle slot's ``top_k``
+    and ``top_p`` mean nothing.  Written on operators and ``.any()`` alone,
+    so the program (traced arrays) and the host's counter of the path taken
+    (``numpy`` mirrors of the same arrays) share the one rule."""
+    samples = active & (temperature > 0)
+    truncates = samples & (((top_k > 0) & (top_k < vocab)) | (top_p < 1.0))
+    return samples.any().astype("int32") + truncates.any().astype("int32")
+
+
+def _draw(logits, key, temperature, shaped):
+    """One row's token: a draw from the ``shaped`` logits, or the exact
+    ``argmax`` of the row's own for a greedy row."""
+    sampled = jax.random.categorical(key, shaped).astype(jnp.int32)
     greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    masked = filtered_logits(logits, temperature, top_k, top_p)
-    sampled = jax.random.categorical(key, masked).astype(jnp.int32)
     return jnp.where(temperature > 0, sampled, greedy_tok)
 
 
-def sample_tokens(logits, keys, temperature, top_k, top_p):
-    """Vmapped :func:`sample_one` over a slot batch: ``logits [slots,
-    vocab]``, ``keys [slots]`` PRNG keys, per-slot scalar knob arrays."""
-    return jax.vmap(sample_one)(logits, keys, temperature, top_k, top_p)
+def _sample_plain(logits, key, temperature):
+    """Level 1 for one row: what :func:`_sample_sorted` computes when
+    neither mask of :func:`filtered_logits` truncates."""
+    return _draw(logits, key, temperature, _scaled(logits, temperature))
+
+
+def _sample_sorted(logits, key, temperature, top_k, top_p):
+    """Level 2 for one row, right for every knob."""
+    return _draw(logits, key, temperature,
+                 filtered_logits(logits, temperature, top_k, top_p))
+
+
+def sample_tokens(logits, keys, temperature, top_k, top_p, active=True):
+    """One token for every slot of a batch: ``logits [slots, vocab]``,
+    ``keys [slots]`` PRNG keys, per-slot scalar knob arrays.  Returns int32
+    ``[slots]``.  The path is chosen once for the batch (under ``vmap`` a
+    choice a row would become a select, and every path would run), from the
+    knobs of the slots that are ``active`` (``[slots]`` bool; an idle
+    slot's stale knobs ask for nothing)."""
+    temperature, top_k, top_p = map(jnp.asarray, (temperature, top_k, top_p))
+    level = sampling_level(temperature, top_k, top_p, active,
+                           logits.shape[-1])
+    return jax.lax.switch(
+        level,
+        (lambda *_: jnp.argmax(logits, axis=-1).astype(jnp.int32),
+         lambda keys, t, *_: jax.vmap(_sample_plain)(logits, keys, t),
+         lambda *knobs: jax.vmap(_sample_sorted)(logits, *knobs)),
+        keys, temperature, top_k, top_p)
+
+
+def sample_one(logits, key, temperature, top_k, top_p):
+    """Sample one token id from ``logits [vocab]``; every argument after
+    ``logits`` is a traced scalar.  Returns an int32 scalar.  A batch of
+    one: the path follows the row's own knobs."""
+    return sample_tokens(logits[None], key[None], *(
+        jnp.asarray(knob)[None] for knob in (temperature, top_k, top_p)))[0]
 
 
 def speculative_verify(logits, drafts, draft_probs, key, temperature, top_k,
@@ -160,7 +224,7 @@ def speculative_verify(logits, drafts, draft_probs, key, temperature, top_k,
     accepted = jnp.minimum(lead, count)
     out = jnp.where(temperature > 0, jnp.where(ok, drafts, resampled), targets)
 
-    plain = sample_one(logits[0], sub_plain, temperature, top_k, top_p)
+    plain = _sample_sorted(logits[0], sub_plain, temperature, top_k, top_p)
     out = jnp.where(speculate, out, out.at[0].set(plain))
     count = jnp.where(speculate, count, 1).astype(jnp.int32)
     accepted = jnp.where(speculate, accepted, 0).astype(jnp.int32)

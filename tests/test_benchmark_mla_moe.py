@@ -175,6 +175,7 @@ def test_the_manifest_lists_the_cell_under_what_it_reports(harness):
         "slot_occupancy", "prefill_padding_share", "goodput_share",
         "serve_mfu", "serve_compiles_in_window", "serve_device_idle_share",
         "serve_peak_hbm_gb", "decode_kv_read_share", "decode_chained_share",
+        "decode_argmax_share",
         "moe_held_share", "moe_load_max_over_mean", "state_bytes_per_position",
         "moe_tiles_per_expert"}
     names = [m["name"] for m in manifest["per_layer"]]
@@ -310,6 +311,7 @@ def test_the_cells_files_drive_a_run_end_to_end(harness, tiny_cell):
                                  True)["metrics"]
     assert {"decode_step_ms", "prefill_ms", "slot_occupancy", "serve_mfu",
             "goodput_share", "decode_kv_read_share", "decode_chained_share",
+            "decode_argmax_share",
             "moe_held_share", "moe_load_max_over_mean",
             "state_bytes_per_position", "moe_tiles_per_expert"} <= set(traced)
     assert traced["serve_compiles_in_window"]["value"] == 0

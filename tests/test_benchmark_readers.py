@@ -71,6 +71,8 @@ TRAIN = {
 #   serve_mfu 2e8 operations a token x 98,500 tokens/s over 197e12 = 10 %
 #   the serving trace: 40 decode steps in 0.8 s, 0.48 s busy: idle 40 %
 #   one backend compile inside (10, 15]: at 12.0
+#   decode_argmax_share: of the window's 150 steps 30 sampled: 80 % took the
+#   argmax alone
 SERVE = {
     "cell": {"config_spec": {}},
     "window": (10.0, 15.0),
@@ -78,11 +80,15 @@ SERVE = {
         "open": {"serving_token_latency_seconds": (2.0, 100),
                  "serving_prefill_seconds": (1.0, 10),
                  "serving_prefill_padded_tokens": 1000.0,
-                 "serving_tokens_total": 500.0},
+                 "serving_tokens_total": 500.0,
+                 "serving_decode_steps_total": 100.0,
+                 "serving_decode_steps_sampled_total": 10.0},
         "close": {"serving_token_latency_seconds": (5.0, 250),
                   "serving_prefill_seconds": (1.85, 110),
                   "serving_prefill_padded_tokens": 4000.0,
-                  "serving_tokens_total": 2500.0}},
+                  "serving_tokens_total": 2500.0,
+                  "serving_decode_steps_total": 250.0,
+                  "serving_decode_steps_sampled_total": 40.0}},
     "samples": [(10.05, {"active_slots": 18, "slots_total": 24, "queue_depth": 3}),
                 (10.10, {"active_slots": 24, "slots_total": 24, "queue_depth": 9}),
                 (10.15, {"active_slots": 12, "slots_total": 24, "queue_depth": 0})],
@@ -113,6 +119,7 @@ ANSWERS = {
     "slot_occupancy": (SERVE, 75.0),
     "prefill_padding_share": (SERVE, 25.0),
     "goodput_share": (SERVE, 95.0),
+    "decode_argmax_share": (SERVE, 80.0),
     # the serving cell's entries of the same readers
     "serve_compiles_in_window": (SERVE, 1),
     "serve_device_idle_share": (SERVE, 40.0),
@@ -270,6 +277,63 @@ def test_a_histogram_that_did_not_move_gives_no_mean(harness):
     for name in ("decode_step_ms", "prefill_ms", "prefill_padding_share",
                  "goodput_share"):
         assert _reader(harness, name)(one_edge) is None
+
+
+def _steps(steps, sampled=None, **others):
+    edge = {"serving_decode_steps_total": steps, **others}
+    if sampled is not None:
+        edge["serving_decode_steps_sampled_total"] = sampled
+    return edge
+
+
+# decode_argmax_share on hand-made marks: 820 steps inside the window, 205
+# of them with a sampling slot: 75 % took the argmax alone.
+SAMPLING_PATHS = {
+    "both_counters": ({"open": _steps(100.0, 5.0),
+                       "close": _steps(920.0, 210.0)}, 75.0),
+    "every_step_greedy": ({"open": _steps(10.0, 0.0),
+                           "close": _steps(50.0, 0.0)}, 100.0),
+    "every_step_sampled": ({"open": _steps(10.0, 10.0),
+                            "close": _steps(50.0, 50.0)}, 0.0),
+    # first touched inside the window: it stood at nought at the opening edge
+    "counter_missing_at_the_opening_edge": (
+        {"open": _steps(100.0), "close": _steps(300.0, 50.0)}, 75.0),
+    # the parent of the PR that brought the counter sorts on every step and
+    # counts none of it: nothing, not 100 %
+    "the_counter_absent": ({"open": _steps(100.0, serving_tokens_total=5.0),
+                            "close": _steps(920.0, serving_tokens_total=9.0)},
+                           None),
+    "no_steps_inside_the_window": ({"open": _steps(9.0, 2.0),
+                                    "close": _steps(9.0, 2.0)}, None),
+    "one_edge_missing": ({"close": _steps(820.0, 205.0)}, None),
+    "no_marks": (None, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SAMPLING_PATHS))
+def test_decode_argmax_share_on_hand_made_marks(harness, case):
+    marks, want = SAMPLING_PATHS[case]
+    entry, line = _line(harness, "decode_argmax_share", {"marks": marks})
+    assert entry["workloads"][:3] == [
+        "gpt2_small.serve_prefill_heavy", "sarvam_105b.serve_closed_decode",
+        "longcat_flash_omni.serve_closed_reasoning"]
+    if want is None:
+        # left out, and the line is made all the same (the parent's side)
+        assert line["metrics"] == {}
+    else:
+        assert line["metrics"] == {"decode_argmax_share": {
+            "value": pytest.approx(want, rel=1e-12), "unit": "%"}}
+    assert line["correct"] is True and line["attempted"] == 5
+
+
+def test_the_counters_the_sampling_reader_reads_are_the_engines():
+    from distkeras_tpu.serving import serving_metrics
+    from distkeras_tpu.telemetry.metrics import Registry
+
+    registry = Registry()
+    serving_metrics(registry)
+    assert {"serving_decode_steps_total", "serving_decode_steps_sampled_total",
+            "serving_decode_steps_sorted_total"} <= set(registry.snapshot())
 
 
 def test_peaks_for_the_v5e_as_jax_names_it(harness):

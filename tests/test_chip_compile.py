@@ -218,3 +218,41 @@ def test_a_double_layers_step_copies_neither_pool_nor_the_experts(one_chip):
     assert text.count(" while(") == 3 and "ragged" not in text
     one_pool = pool_shape[0] * pool_shape[1] * pool_shape[2] * 2
     assert compiled.memory_analysis().temp_size_in_bytes < one_pool // 4
+
+
+# ------------------------------------------- the sampling's choice of a path
+
+
+def test_the_sampling_keeps_its_sort_inside_a_branch(one_chip):
+    """``sample_tokens`` at the latent cell's step shape (64 slots x 65,536
+    float32 logits): the chip's compiler keeps the choice of a path as one
+    ``conditional`` (it does not flatten it to a select that would run every
+    path), and the sort over the vocabulary with its cumulative sum stands
+    in a branch: the entry computation, which every step runs, holds
+    neither (the sort was 13-16% of two serving cells' device time while
+    every request was greedy: PERF.md, PR 34)."""
+    import re
+
+    from distkeras_tpu.serving.sampling import sample_tokens
+
+    slots, vocab = 64, 65536
+    struct = lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    text = jax.jit(sample_tokens).lower(
+        struct((slots, vocab), jnp.float32), struct((slots, 2), jnp.uint32),
+        struct((slots,), jnp.float32), struct((slots,), jnp.int32),
+        struct((slots,), jnp.float32), struct((slots,), jnp.bool_)
+    ).compile().as_text()
+    # computations by name; the entry is the one every call runs
+    bodies = dict(re.findall(
+        r"^(?:ENTRY )?(%[\w.\-]+) \(.*?\) -> .*? \{\n(.*?)^\}", text,
+        re.M | re.S))
+    (entry,) = re.findall(r"^ENTRY (%[\w.\-]+)", text, re.M)
+    ops = lambda body: re.findall(r" ([\w\-]+)\(", body)
+    assert ops(bodies[entry]).count("conditional") == 1
+    assert not {"sort", "reduce-window"} & set(ops(bodies[entry]))
+    (branches,) = re.findall(r"branch_computations=\{([^}]*)\}", text)
+    greedy, plain, sorted_ = [bodies[name.strip()]
+                              for name in branches.split(",")]
+    assert "sort" in ops(sorted_) and "reduce-window" in ops(sorted_)
+    assert not {"sort", "reduce-window"} & set(ops(greedy) + ops(plain))

@@ -346,6 +346,8 @@ def test_serving_metrics_schema_golden():
     m["prefill_padded"].inc(13)
     m["decode_steps"].inc(17)
     m["decode_chained"].inc(15)
+    m["decode_sampled"].inc(4)
+    m["decode_sorted"].inc(3)
     m["spec_proposed"].inc(24)
     m["spec_accepted"].inc(19)
     m["hot_swaps"].inc(2)

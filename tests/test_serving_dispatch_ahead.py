@@ -63,6 +63,13 @@ def _counter(registry, name):
     return 0.0 if entry is None else float(entry["value"])
 
 
+def _idle(engine):
+    """Wait until the loop has read everything it dispatched."""
+    deadline = time.monotonic() + 10
+    while engine._inflight and time.monotonic() < deadline:
+        time.sleep(0.005)
+
+
 def make_serial(engine):
     """The same engine with the host reading every program as soon as it has
     dispatched it: the serial order, the reference for what chaining may not
@@ -297,15 +304,77 @@ def test_chained_counter_after_a_known_run(make_engine):
     for new in (7, 4):
         assert len(engine.generate([3, 1, 4], max_new_tokens=new,
                                    timeout=120).tokens) == new
-        deadline = time.monotonic() + 10
-        while engine._inflight and time.monotonic() < deadline:
-            time.sleep(0.005)
+        _idle(engine)
     assert _counter(registry, "serving_decode_steps_total") == 6 + 3
     assert _counter(registry, "serving_decode_steps_chained_total") == 5 + 2
     # an answer of one token takes no step at all
     assert len(engine.generate([3, 1, 4], max_new_tokens=1,
                                timeout=120).tokens) == 1
     assert _counter(registry, "serving_decode_steps_total") == 9
+
+
+def test_sampling_path_counters_after_a_known_run(make_engine):
+    """One answer at a time, so every step's level is its one request's:
+    the two counters read what the schedule implies (an answer of n tokens
+    takes n - 1 steps), and knobs that mean nothing ask for nothing."""
+    registry = Registry()
+    engine = make_engine(registry=registry)
+    sampled = lambda: _counter(registry, "serving_decode_steps_sampled_total")
+    sorted_ = lambda: _counter(registry, "serving_decode_steps_sorted_total")
+    # (knobs, tokens, steps that sampled, steps that sorted)
+    schedule = [
+        (dict(), 7, 0, 0),
+        (dict(top_k=5, top_p=0.5), 4, 0, 0),          # greedy: knobs ignored
+        (dict(temperature=0.7, seed=5), 5, 4, 0),
+        (dict(temperature=0.7, top_k=VOCAB, seed=5), 3, 2, 0),
+        (dict(temperature=0.9, top_p=0.8, seed=77), 4, 3, 3),
+        (dict(temperature=1.3, top_k=6, seed=9), 6, 5, 5),
+        (dict(), 3, 0, 0),                            # and back to the argmax
+        (dict(temperature=0.9, top_k=6, seed=1), 1, 0, 0),  # no step at all
+    ]
+    steps = want_sampled = want_sorted = 0
+    for knobs, new, more_sampled, more_sorted in schedule:
+        assert len(engine.generate([3, 1, 4], max_new_tokens=new, timeout=120,
+                                   **knobs).tokens) == new
+        _idle(engine)
+        steps += new - 1
+        want_sampled += more_sampled
+        want_sorted += more_sorted
+        assert _counter(registry, "serving_decode_steps_total") == steps
+        assert (sampled(), sorted_()) == (want_sampled, want_sorted)
+    assert engine._decode._cache_size() == 1
+
+
+def test_a_sampled_request_joins_a_greedy_batch_without_a_compile(lm, gated):
+    """A greedy answer is a few steps in when a request that samples with a
+    top-k is admitted beside it, and one of a single token that never
+    steps: the steps they share are at the sampling slot's level, the rest
+    take the argmax, the greedy tokens are ``generate``'s, and the one
+    compiled step served all of it."""
+    module, params, _ = lm
+    engine, registry, gate = gated(3)
+    engine.generate([2, 7], max_new_tokens=2, timeout=120)  # warm both programs
+    _idle(engine)
+    compiled = engine._decode._cache_size()
+    before = _counter(registry, "serving_decode_steps_total")
+    gate.reached.clear()
+    greedy = engine.submit(GenerateRequest(prompt=[5, 9, 2], max_new_tokens=30))
+    assert gate.reached.wait(60)
+    warm = engine.submit(GenerateRequest(
+        prompt=[4, 4], max_new_tokens=6, temperature=0.9, top_k=4, seed=3))
+    lone = engine.submit(GenerateRequest(
+        prompt=[8, 1], max_new_tokens=1, temperature=0.9, top_p=0.5, seed=4))
+    gate.open()
+    assert greedy.result(timeout=120).tokens == _ref(module, params,
+                                                     [5, 9, 2], 30)
+    assert len(warm.result(timeout=120).tokens) == 6
+    assert len(lone.result(timeout=120).tokens) == 1
+    _idle(engine)
+    # the sampling request's five steps all fell among the greedy one's 29
+    assert _counter(registry, "serving_decode_steps_total") - before == 29
+    assert _counter(registry, "serving_decode_steps_sampled_total") == 5
+    assert _counter(registry, "serving_decode_steps_sorted_total") == 5
+    assert engine._decode._cache_size() == compiled == 1
 
 
 # -------------------------------- the host must see the truth: the flushes
