@@ -55,7 +55,7 @@ def epoch_arrays(
     # C++ library is available, bit-identical numpy fallback otherwise.
     from distkeras_tpu import native, telemetry
 
-    with telemetry.trace.epoch_span("epoch_arrays", phase="data",
+    with telemetry.trace.loop_span("epoch_arrays", phase="data",
                                     rows=int(total)) as span:
         xs = native.gather_rows(features, idx)
         ys = native.gather_rows(labels, idx)

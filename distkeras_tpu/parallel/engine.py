@@ -1001,7 +1001,7 @@ class WindowedEngine:
         closed by the telemetry package's readiness thread, not here.  Only
         ``stats["loss"]`` goes to that thread: the state is donated by the
         next dispatch."""
-        with telemetry.trace.epoch_span("dispatch", windows=int(xs.shape[1])):
+        with telemetry.trace.loop_span("dispatch", windows=int(xs.shape[1])):
             new_state, stats = self._enqueue(fn, state, xs, ys)
         telemetry.trace.probe(stats["loss"], "device_epoch",
                               time.perf_counter(), phase="step")
@@ -1368,7 +1368,7 @@ class WindowedEngine:
         readiness thread.  ``bytes`` is the count at the boundary."""
         t0 = time.perf_counter()
         nbytes = int(xs.nbytes) + int(ys.nbytes)
-        with telemetry.trace.epoch_span("h2d", bytes=nbytes):
+        with telemetry.trace.loop_span("h2d", bytes=nbytes):
             out = self._put_batches(xs, ys)
         telemetry.trace.probe(out, "h2d_transfer", t0, phase="h2d",
                               bytes=nbytes)

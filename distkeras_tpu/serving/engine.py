@@ -153,11 +153,16 @@ def serving_metrics(registry=None) -> dict:
         ),
         "token_latency": registry.histogram(
             "serving_token_latency_seconds",
-            help="wall time of one continuous-batching decode step",
+            help="the loop thread's time in one decode step's call: the "
+                 "dispatch of this step, then the wait for and read-back of "
+                 "what lies behind it (the step before, this iteration's "
+                 "prefills); not this step's device time",
         ),
         "prefill_seconds": registry.histogram(
             "serving_prefill_seconds",
-            help="wall time of one prefill dispatch (bucketed width)",
+            help="the host's dispatch of one prefill alone (bucketed "
+                 "width): inputs built and uploaded, the program enqueued; "
+                 "its first token is read one program behind",
         ),
         "queue_depth": registry.gauge(
             "serving_queue_depth", help="requests waiting for a batch slot"
@@ -230,6 +235,58 @@ def serving_metrics(registry=None) -> dict:
             "serving_state_per_position_bytes",
             help="bytes of paged state one position keeps over all layers "
                  "and kinds of state the served block declares",
+        ),
+        # the loop thread's account of its own time: one observation a span
+        # of the same name in the flight-recorder ring (serving.loop*)
+        "loop_iteration": registry.histogram(
+            "serving_loop_iteration_seconds",
+            help="one iteration of the host loop that admitted or stepped: "
+                 "from its top to the return of its decode step's call",
+        ),
+        "loop_dispatch": registry.histogram(
+            "serving_loop_dispatch_seconds",
+            help="a decode step's cost to the host before it turns to "
+                 "reading: counters, inputs (uploaded when an admit or a "
+                 "retire changed them), the program's dispatch, bookkeeping",
+        ),
+        "loop_wait": registry.histogram(
+            "serving_loop_wait_seconds",
+            help="one blocking read of a dispatched program's tokens: the "
+                 "loop thread with nothing to do but wait for the device",
+        ),
+        "loop_emit": registry.histogram(
+            "serving_loop_emit_seconds",
+            help="handing one read program's tokens to their requests: the "
+                 "block's counters, the per-slot bookkeeping, the callers "
+                 "woken",
+        ),
+        "loop_idle": registry.histogram(
+            "serving_loop_idle_seconds",
+            help="an iteration that found nothing to do: its read of what "
+                 "was in flight and its sleep",
+        ),
+        "queue_wait": registry.histogram(
+            "serving_queue_wait_seconds",
+            help="an admitted request's time in the queue: its enqueue to "
+                 "its prefill",
+        ),
+        "dispatches": registry.counter(
+            "serving_dispatches_total",
+            help="programs dispatched to the device: prefills, decode steps, "
+                 "draft and verify steps",
+        ),
+        "dispatches_starved": registry.counter(
+            "serving_dispatches_starved_total",
+            help="dispatches that found the device empty while the host "
+                 "worked: the newest unread program was already finished, or "
+                 "none was unread and the loop had not slept for want of "
+                 "requests since its last dispatch (the serial speculative "
+                 "loop keeps none unread, so all of its count)",
+        ),
+        "gc_pause": registry.counter(
+            "serving_gc_pause_seconds_total",
+            help="seconds the interpreter's garbage collections took, on "
+                 "any thread, while the loop was inside an iteration",
         ),
     }
 
@@ -359,13 +416,15 @@ class _InFlight:
     ``tok`` is the device array (``[slots]`` of a decode step, a scalar of a
     prefill), ``rows`` the ``(slot, state)`` pairs it sampled for, and
     ``prefill`` a prefill's ``(queue wait, loop time)`` for the ledger,
-    ``aux`` what a block with counters of its own handed back beside."""
+    ``aux`` what a block with counters of its own handed back beside,
+    ``seq`` its place in the engine's order of dispatch."""
 
-    __slots__ = ("tok", "rows", "prefill", "aux")
+    __slots__ = ("tok", "rows", "prefill", "aux", "seq")
 
-    def __init__(self, tok, rows, prefill=None, aux=None):
+    def __init__(self, tok, rows, seq, prefill=None, aux=None):
         self.tok = tok
         self.rows = rows
+        self.seq = seq
         self.prefill = prefill
         self.aux = aux  # the block's own counts, for its ``observe``
 
@@ -425,6 +484,23 @@ class ServingEngine:
     ``jax.sharding.Mesh``; ``heads`` must divide by its size).  The last two
     need the block's ``window`` and ``shard``; ``LatentMoELM`` brings
     neither yet, and the constructor says so.
+
+    The loop thread accounts for all of its own time, whether or not
+    telemetry is on: every pass is a ``serving.loop`` span in the
+    flight-recorder ring (``serving.loop.idle`` for one that found nothing
+    to do) over ``serving.loop.admit`` / ``.prefill`` / ``.dispatch`` /
+    ``.wait`` / ``.emit``, the timed ones also one observation of
+    ``serving_loop_{iteration,dispatch,wait,emit,idle}_seconds`` from the
+    same two clock reads; ``serving_loop_wait_seconds`` is the host's slack
+    and iteration less wait its own work.  Every program dispatched gets a
+    ``seq`` (dispatch order, which on one stream is execution order) and
+    counts in ``serving_dispatches_total``, in
+    ``serving_dispatches_starved_total`` too if it found the device empty
+    while the host worked; ``serving_queue_wait_seconds`` is an admitted
+    request's time in the queue and ``serving_gc_pause_seconds_total`` the
+    interpreter's collections inside the loop's iterations.  The request's
+    own spans (``serving.admit``, ``.queue_wait``, ``.prefill``,
+    ``.decode_step``, ids and tenants on them) stay behind the switch.
 
     A block may bring counters of its own (``DecodeSpec.instruments`` /
     ``observe``: ``LatentMoELM``'s ``serving_moe_*``): its programs hand a
@@ -560,6 +636,16 @@ class ServingEngine:
         self._level = 0
         # programs whose tokens are still on the device, oldest first
         self._inflight: collections.deque = collections.deque()
+        # the loop thread's account of itself (the serving.loop* spans and
+        # the serving_loop_* instruments): the programs dispatched so far
+        # (the next one's ``seq``), the loop's passes, whether it has slept
+        # for want of requests since its last dispatch, this pass's starved
+        # dispatches, and the requests resolved so far
+        self._seq = 0
+        self._iter = 0
+        self._slept = True
+        self._starved = 0
+        self._resolved = 0
 
         self._cv = lockwatch.maybe_wrap(threading.Condition(), "serving.engine")
         self._running = False
@@ -810,6 +896,7 @@ class ServingEngine:
 
     def start(self) -> None:
         """Start the host loop thread (idempotent; ``submit`` calls this)."""
+        _trace.watch_gc()  # the interpreter's pauses stall the loop thread
         with self._cv:
             if self._running:
                 return
@@ -1061,8 +1148,37 @@ class ServingEngine:
         passes under device time.  Wherever the host must see the truth it
         reads everything first (``_flush``): before it sleeps, before a
         hot-swap is applied, before a cancel, on a crash and on the way
-        out."""
-        while True:
+        out.
+
+        The loop accounts for all of its own time, always (``_phase``): a
+        pass that admitted or stepped is a ``serving.loop`` span, one that
+        found nothing to do a ``serving.loop.idle`` (its read of what was in
+        flight and its sleep), both numbered by ``iter``, and what it does
+        inside lies under ``serving.loop.admit`` / ``.prefill`` /
+        ``.dispatch`` / ``.wait`` / ``.emit``."""
+        while self._iterate():
+            pass
+
+    def _phase(self, name: str, timed: Optional[str] = None, **attrs):
+        """Open one phase of the loop thread's account of its own time: a
+        span in the flight-recorder ring whether or not telemetry is on
+        (``trace.loop_span``: it carries its iteration's ``iter``) and,
+        where ``timed`` names a histogram, one observation of it from the
+        span's own two clock reads, so that span and number cannot drift
+        apart.  Attributes known only at the end go into the span's
+        ``attrs`` before it closes."""
+        return _trace.loop_span(
+            name, observe=self._metrics[timed].observe if timed else None,
+            **attrs)
+
+    def _iterate(self) -> bool:
+        """One pass of the loop; False when it was the last."""
+        number = self._iter
+        self._iter += 1
+        gc_before = _trace.gc_seconds
+        self._starved = 0
+        span = self._phase("serving.loop", "loop_iteration", iter=number)
+        with span:
             try:
                 with self._cv:
                     running = self._running
@@ -1070,31 +1186,43 @@ class ServingEngine:
                     swap_pending = self._swap is not None
                     paused = self._draining or swap_pending
                 if not running:
+                    span.keep = bool(self._inflight)
                     self._flush()  # stop() gets back what the device made
-                    return
+                    return False
                 self._cancel_requested()
                 if swap_pending and not self._active.any():
                     self._flush()  # what is in flight ran on the old params
                     self._apply_swap()
                     with self._cv:
                         paused = self._draining
-                progressed = False if paused else self._admit()
+                admitted = 0 if paused else self._admit()
                 if _chaos.enabled() and self._active.any():
                     # the kill_replica site: only busy iterations count, so
                     # a seeded kill always lands mid-decode with requests in
                     # flight (the failover path is what's under test)
                     _chaos.fault("replica")
-                progressed = self._decode_once() or progressed
-                if not progressed:
-                    self._flush()  # nothing new to run behind: read it all
-                    with self._cv:
-                        if (self._running and self._swap is None
-                                and not self._cancelled
-                                and (paused or len(self._queue) == 0)):
-                            self._cv.wait(timeout=0.05)
+                active = self._decode_once()
             except _chaos.ChaosKilled:
                 self._crash()
-                return
+                return False
+            span.keep = progressed = bool(admitted or active)
+            span.attrs.update(admitted=admitted, active=active,
+                              starved=self._starved)
+        if progressed:
+            collecting_s = _trace.gc_seconds - gc_before
+            if collecting_s:
+                self._metrics["gc_pause"].inc(collecting_s)
+            return True
+        with self._phase("serving.loop.idle", "loop_idle", iter=number):
+            self._slept = True  # nobody asked for anything: the next
+            # dispatch finds the device empty and is not starved
+            self._flush()  # nothing new to run behind: read it all
+            with self._cv:
+                if (self._running and self._swap is None
+                        and not self._cancelled
+                        and (paused or len(self._queue) == 0)):
+                    self._cv.wait(timeout=0.05)
+        return True
 
     def _cancel_requested(self) -> None:
         """Retire every slot whose request was cancelled (loop thread only)."""
@@ -1148,29 +1276,53 @@ class ServingEngine:
             self._finish(pending, [], "aborted", 0.0)
         self._metrics["queue_depth"].set(0)
 
-    def _admit(self) -> bool:
-        """Move queued requests into free slots (prefill).  FIFO with
-        head-of-line blocking: when the page pool can't fit the next
-        request yet, it waits for a retirement rather than being skipped —
-        no starvation of big requests."""
-        admitted = False
-        while True:
-            free = [i for i, st in enumerate(self._slots) if st is None]
-            if not free:
-                break
-            pending = self._queue.pop()
-            if pending is None:
-                break
-            need = self._cache.pages_needed(
-                len(pending.request.prompt) + pending.max_new
-            )
-            if not self._cache.can_alloc(need):
-                self._queue.requeue_front(pending)
-                break
-            self._prefill_into(free[0], pending, need)
-            admitted = True
-        self._metrics["queue_depth"].set(len(self._queue))
+    def _admit(self) -> int:
+        """Move queued requests into free slots (prefill); how many it
+        moved.  FIFO with head-of-line blocking: when the page pool can't
+        fit the next request yet, it waits for a retirement rather than
+        being skipped — no starvation of big requests."""
+        admitted = 0
+        with self._phase("serving.loop.admit") as span:
+            while True:
+                free = [i for i, st in enumerate(self._slots) if st is None]
+                if not free:
+                    break
+                pending = self._queue.pop()
+                if pending is None:
+                    break
+                need = self._cache.pages_needed(
+                    len(pending.request.prompt) + pending.max_new
+                )
+                if not self._cache.can_alloc(need):
+                    self._queue.requeue_front(pending)
+                    break
+                self._prefill_into(free[0], pending, need)
+                admitted += 1
+            self._metrics["queue_depth"].set(len(self._queue))
+            # an engine with nothing to run writes its idle span and no other
+            span.keep = bool(admitted) or bool(self._active.any())
+            span.attrs["admitted"] = admitted
         return admitted
+
+    def _next_seq(self) -> int:
+        """Count the program about to be dispatched and give it its ``seq``,
+        its place in the engine's order of dispatch (on one stream, the
+        device's order of execution).  The dispatch is *starved* when it
+        finds the device empty while the host worked: the newest unread
+        program is already finished (one non-blocking ``is_ready()`` of its
+        tokens, which no program donates), or none is unread although the
+        loop has not slept for want of requests since its last dispatch."""
+        if self._inflight:
+            starved = self._inflight[-1].tok.is_ready()
+        else:
+            starved = not self._slept
+        self._slept = False
+        self._metrics["dispatches"].inc()
+        if starved:
+            self._metrics["dispatches_starved"].inc()
+            self._starved += 1
+        self._seq += 1
+        return self._seq - 1
 
     def _prefill_into(self, slot: int, pending: _Pending, need: int) -> None:
         """Dispatch one prefill into ``slot`` and do not wait for it: the
@@ -1180,93 +1332,103 @@ class ServingEngine:
         engine's loop is serial and reads it at once."""
         req = pending.request
         plen = len(req.prompt)
-        self._cache.alloc(slot, need)
         # smallest bucket that fits the prompt (the ladder always ends at
         # max_context and submit bounded plen, so next() can't exhaust)
         width = next(w for w in self._buckets if w >= plen)
         serial = self._draft_spec is not None
-        t0 = time.perf_counter()
-        span = NOOP_SPAN
-        if _truntime.enabled():
-            # the loop thread serves every request, so the ids ride span
-            # args (no thread-bound context here); queue wait spans the gap
-            # between the admission thread's enqueue and this prefill
-            _trace.record(
-                "serving.queue_wait", pending.enqueue_t, t0,
-                request_id=req.request_id, trace_id=req.trace_id,
-                parent="serving.admit")
-            attrs: Dict[str, Any] = dict(
-                request_id=req.request_id, trace_id=req.trace_id,
-                parent="serving.admit", slot=slot, width=width, plen=plen)
-            if req.tenant:
-                attrs["tenant"] = req.tenant
-            span = _trace.span("serving.prefill", **attrs)
-        state = _SlotState(pending, plen)
-        state.pages = need
-        with span:
-            tokens = np.zeros((1, width), np.int32)
-            tokens[0, :plen] = req.prompt
-            tokens_dev = jnp.asarray(tokens)
-            # a copy: the host goes on writing the table while this runs
-            table = jnp.asarray(self._cache.tables[
-                slot, : width // self._cache.page_size].copy())
-            last, keys = ((self._last, self._keys) if serial
-                          else (self._dev["last"], self._dev["keys"]))
-            tok, last, keys, *aux = self._keep(
-                self._cache, self._prefill_for(width)(
-                    self._spec.params(), *self._pools_of(self._cache),
-                    tokens_dev, table, np.int32(plen),
-                    jax.random.PRNGKey(req.seed), np.float32(req.temperature),
-                    np.int32(req.top_k), np.float32(req.top_p),
-                    last, keys, np.int32(slot)))
-            spec_on = serial and req.speculative is not False
-            if spec_on:
-                dc = self._draft_cache
-                self._keep(dc, self._prefill_for(width, role="draft")(
-                    self._draft_spec.params(), *self._pools_of(dc),
-                    tokens_dev, table))
-                # a draft chain decorrelated from the request's target chain
-                self._draft_keys[slot] = np.asarray(
-                    jax.random.fold_in(jax.random.PRNGKey(req.seed), 7))
-            if serial:
-                self._keys = np.array(keys)  # np.array: a writable host copy
-            else:
-                self._dev["last"], self._dev["keys"] = last, keys
-        now = time.perf_counter()
-        self._metrics["prefill_seconds"].observe(now - t0)
-        self._metrics["prefill_padded"].inc(width - plen)
+        with self._phase("serving.loop.prefill", slot=slot, width=width,
+                         plen=plen) as phase:
+            self._cache.alloc(slot, need)
+            t0 = time.perf_counter()
+            self._metrics["queue_wait"].observe(t0 - pending.enqueue_t)
+            span = NOOP_SPAN
+            if _truntime.enabled():
+                # the loop thread serves every request, so the ids ride span
+                # args (no thread-bound context here); queue wait spans the
+                # gap between the admission thread's enqueue and this prefill
+                _trace.record(
+                    "serving.queue_wait", pending.enqueue_t, t0,
+                    request_id=req.request_id, trace_id=req.trace_id,
+                    parent="serving.admit")
+                attrs: Dict[str, Any] = dict(
+                    request_id=req.request_id, trace_id=req.trace_id,
+                    parent="serving.admit", slot=slot, width=width, plen=plen)
+                if req.tenant:
+                    attrs["tenant"] = req.tenant
+                span = _trace.span("serving.prefill", **attrs)
+            state = _SlotState(pending, plen)
+            state.pages = need
+            with span:
+                tokens = np.zeros((1, width), np.int32)
+                tokens[0, :plen] = req.prompt
+                tokens_dev = jnp.asarray(tokens)
+                # a copy: the host goes on writing the table while this runs
+                table = jnp.asarray(self._cache.tables[
+                    slot, : width // self._cache.page_size].copy())
+                last, keys = ((self._last, self._keys) if serial
+                              else (self._dev["last"], self._dev["keys"]))
+                key = jax.random.PRNGKey(req.seed)
+                seq = phase.attrs["seq"] = self._next_seq()
+                tok, last, keys, *aux = self._keep(
+                    self._cache, self._prefill_for(width)(
+                        self._spec.params(), *self._pools_of(self._cache),
+                        tokens_dev, table, np.int32(plen), key,
+                        np.float32(req.temperature), np.int32(req.top_k),
+                        np.float32(req.top_p), last, keys, np.int32(slot)))
+                spec_on = serial and req.speculative is not False
+                if spec_on:
+                    dc = self._draft_cache
+                    self._next_seq()
+                    self._keep(dc, self._prefill_for(width, role="draft")(
+                        self._draft_spec.params(), *self._pools_of(dc),
+                        tokens_dev, table))
+                    # a draft chain decorrelated from the request's target
+                    # chain
+                    self._draft_keys[slot] = np.asarray(
+                        jax.random.fold_in(jax.random.PRNGKey(req.seed), 7))
+                if serial:
+                    # np.array: a writable host copy
+                    self._keys = np.array(keys)
+                else:
+                    self._dev["last"], self._dev["keys"] = last, keys
+            now = time.perf_counter()
+            self._metrics["prefill_seconds"].observe(now - t0)
+            self._metrics["prefill_padded"].inc(width - plen)
 
-        state.admit_t = now
-        self._slots[slot] = state
-        self._pos[slot] = plen
-        self._temp[slot] = req.temperature
-        self._topk[slot] = req.top_k
-        self._topp[slot] = req.top_p
-        # an answer of one token takes no decode step: its slot only waits
-        # for the read
-        self._active[slot] = state.steps_left > 0
-        self._spec_on[slot] = spec_on
-        self._dirty = True
-        self._inflight.append(_InFlight(
-            tok, [(slot, state)], prefill=(t0 - pending.enqueue_t, now - t0),
-            aux=aux[0] if aux else None))
-        self._refresh_gauges()
+            state.admit_t = now
+            self._slots[slot] = state
+            self._pos[slot] = plen
+            self._temp[slot] = req.temperature
+            self._topk[slot] = req.top_k
+            self._topp[slot] = req.top_p
+            # an answer of one token takes no decode step: its slot only
+            # waits for the read
+            self._active[slot] = state.steps_left > 0
+            self._spec_on[slot] = spec_on
+            self._dirty = True
+            self._inflight.append(_InFlight(
+                tok, [(slot, state)], seq,
+                prefill=(t0 - pending.enqueue_t, now - t0),
+                aux=aux[0] if aux else None))
+            self._refresh_gauges()
         if serial:
             self._flush()
             if self._slots[slot] is state:
                 self._last[slot] = state.tokens[0]
 
-    def _decode_once(self) -> bool:
+    def _decode_once(self) -> int:
         """One engine iteration over every active slot: a plain decode
         step dispatched ahead of the last one's read, or (with a draft
-        model, serially) m draft steps + one verify step."""
-        if not self._active.any():
-            return False
+        model, serially) m draft steps + one verify step.  How many slots
+        it stepped: 0 when none is active."""
+        active = int(self._active.sum())
+        if not active:
+            return 0
         if self._draft_spec is not None:
-            self._spec_once()
+            self._spec_once(active)
         else:
-            self._plain_once()
-        return True
+            self._plain_once(active)
+        return active
 
     def _step_span(self):
         """A ``serving.decode_step`` span for one engine iteration.  One
@@ -1332,7 +1494,7 @@ class ServingEngine:
         return (dev["tables"], dev["pos"], dev["last"], dev["keys"],
                 dev["temp"], dev["top_k"], dev["top_p"], dev["active"])
 
-    def _plain_once(self) -> None:
+    def _plain_once(self, active: int) -> None:
         """Dispatch the next decode step, then read what is behind it.  The
         step's token, position and key inputs are the device outputs of the
         program before (no host round trip); the host advances its mirror
@@ -1343,31 +1505,39 @@ class ServingEngine:
         therefore seen one step late: that slot rides this step too and its
         overrun token is dropped.  The call's wall time (dispatch of this
         step, wait for and read-back of the previous) is one observation of
-        ``serving_token_latency_seconds``."""
+        ``serving_token_latency_seconds``; ``serving.loop.dispatch`` is its
+        first part, up to where the reading begins."""
         t0 = time.perf_counter()
-        self._count_kv_read(self._pos)
         with self._step_span():
-            tok, pos, keys, *aux = self._keep(self._cache, self._decode(
-                self._spec.params(), *self._pools_of(self._cache),
-                *self._step_inputs()))
-            self._dev.update(last=tok, pos=pos, keys=keys)
-            self._metrics["decode_steps"].inc()
-            if self._level >= 1:
-                self._metrics["decode_sampled"].inc()
-            if self._level >= 2:
-                self._metrics["decode_sorted"].inc()
-            if any(rec.prefill is None for rec in self._inflight):
-                # the step before is unread: this one took its outputs
-                self._metrics["decode_chained"].inc()
-            rows = [(int(slot), self._slots[slot])
-                    for slot in np.flatnonzero(self._active)]
-            self._inflight.append(_InFlight(
-                tok, rows, aux=aux[0] if aux else None))
-            self._pos[self._active] += 1
-            for slot, state in rows:
-                state.steps_left -= 1
-                if state.steps_left <= 0:
-                    self._release(slot)
+            with self._phase("serving.loop.dispatch", "loop_dispatch",
+                             active=active) as phase:
+                self._count_kv_read(self._pos)
+                uploaded = self._dirty
+                inputs = self._step_inputs()
+                seq = self._next_seq()
+                tok, pos, keys, *aux = self._keep(self._cache, self._decode(
+                    self._spec.params(), *self._pools_of(self._cache),
+                    *inputs))
+                self._dev.update(last=tok, pos=pos, keys=keys)
+                self._metrics["decode_steps"].inc()
+                if self._level >= 1:
+                    self._metrics["decode_sampled"].inc()
+                if self._level >= 2:
+                    self._metrics["decode_sorted"].inc()
+                if any(rec.prefill is None for rec in self._inflight):
+                    # the step before is unread: this one took its outputs
+                    self._metrics["decode_chained"].inc()
+                rows = [(int(slot), self._slots[slot])
+                        for slot in np.flatnonzero(self._active)]
+                self._inflight.append(_InFlight(
+                    tok, rows, seq, aux=aux[0] if aux else None))
+                self._pos[self._active] += 1
+                for slot, state in rows:
+                    state.steps_left -= 1
+                    if state.steps_left <= 0:
+                        self._release(slot)
+                phase.attrs.update(seq=seq, uploaded=uploaded,
+                                   level=self._level)
             self._read_behind(1, t0)
         self._metrics["token_latency"].observe(time.perf_counter() - t0)
 
@@ -1376,24 +1546,31 @@ class ServingEngine:
     def _read_behind(self, keep: int, t0: float) -> None:
         """Read the tokens of every program in flight but the newest
         ``keep``, oldest first, blocking on each until the device has made
-        them.  ``t0`` is when the caller's own work began: the ledger's
-        share of the loop's time for a step."""
+        them (``serving.loop.wait``: the host's slack), then hand them to
+        their requests (``serving.loop.emit``).  ``t0`` is when the caller's
+        own work began: the ledger's share of the loop's time for a step."""
         while len(self._inflight) > keep:
             # still in flight until its tokens are with their requests: a
             # drain that sees nothing in flight may tell its caller so
             rec = self._inflight[0]
-            toks = np.asarray(rec.tok)  # device sync: that program is done
-            now = time.perf_counter()
-            if rec.aux is not None:
-                step = rec.prefill is None
-                self._observe(
-                    jax.tree.map(np.asarray, rec.aux),
-                    len(rec.rows) if step else rec.rows[0][1].plen, step)
-            if rec.prefill is not None:
-                self._first_token(rec, int(toks), now)
-            else:
-                self._step_tokens(rec, toks, now - t0)
-            self._inflight.popleft()
+            step = rec.prefill is None
+            with self._phase("serving.loop.wait", "loop_wait", seq=rec.seq,
+                             kind="step" if step else "prefill"):
+                toks = np.asarray(rec.tok)  # device sync: that program is done
+            with self._phase("serving.loop.emit", "loop_emit", seq=rec.seq,
+                             rows=len(rec.rows)) as phase:
+                now = time.perf_counter()
+                resolved = self._resolved
+                if rec.aux is not None:
+                    self._observe(
+                        jax.tree.map(np.asarray, rec.aux),
+                        len(rec.rows) if step else rec.rows[0][1].plen, step)
+                if step:
+                    self._step_tokens(rec, toks, now - t0)
+                else:
+                    self._first_token(rec, int(toks), now)
+                self._inflight.popleft()
+                phase.attrs["finished"] = self._resolved - resolved
 
     def _flush(self) -> None:
         """Read everything in flight: the host sees what the device made."""
@@ -1444,44 +1621,66 @@ class ServingEngine:
             self._release(slot)
         self._resolve(state, reason)
 
-    def _spec_once(self) -> None:
+    def _spec_once(self, active_n: int) -> None:
         """One speculative iteration: chain m draft steps (device arrays
         flow straight between dispatches — no host syncs), verify the
-        window in one target step, then emit each slot's accepted prefix."""
+        window in one target step, then emit each slot's accepted prefix.
+        The same three phases as the plain loop's, around serial steps: the
+        ``serving.loop.dispatch`` holds all m + 1 programs and carries the
+        verify's ``seq``, whose tokens the ``wait`` blocks on."""
         t0 = time.perf_counter()
         m = self._spec_tokens
         with self._step_span():
-            tables = jnp.asarray(self._cache.tables)
-            temp = jnp.asarray(self._temp)
-            topk = jnp.asarray(self._topk)
-            topp = jnp.asarray(self._topp)
-            active = jnp.asarray(self._active)
-            base_pos = self._pos
-            last = jnp.asarray(self._last)
-            dkeys = jnp.asarray(self._draft_keys)
-            dc = self._draft_cache
-            dparams = self._draft_spec.params()
-            drafts, qprobs = [], []
-            for i in range(m):
-                self._count_kv_read(base_pos + i)
-                tok, qp, _, dkeys = self._keep(dc, self._draft_step(
-                    dparams, *self._pools_of(dc), tables,
-                    jnp.asarray(base_pos + i), last, dkeys, temp, topk, topp,
-                    active))
-                drafts.append(tok)
-                qprobs.append(qp)
-                last = tok
-            out, count, accepted, keys = self._keep(self._cache, self._verify(
-                self._spec.params(), *self._pools_of(self._cache),
-                tables, jnp.asarray(base_pos), jnp.asarray(self._last),
-                tuple(drafts), tuple(qprobs), jnp.asarray(self._keys),
-                temp, topk, topp, active, jnp.asarray(self._spec_on)))
-            out = np.asarray(out)       # device sync: the iteration is done
-            counts = np.asarray(count)
-            acc = np.asarray(accepted)
+            with self._phase("serving.loop.dispatch", "loop_dispatch",
+                             active=active_n, uploaded=True,
+                             level=2) as phase:
+                tables = jnp.asarray(self._cache.tables)
+                temp = jnp.asarray(self._temp)
+                topk = jnp.asarray(self._topk)
+                topp = jnp.asarray(self._topp)
+                active = jnp.asarray(self._active)
+                base_pos = self._pos
+                last = jnp.asarray(self._last)
+                dkeys = jnp.asarray(self._draft_keys)
+                dc = self._draft_cache
+                dparams = self._draft_spec.params()
+                drafts, qprobs = [], []
+                for i in range(m):
+                    self._count_kv_read(base_pos + i)
+                    self._next_seq()
+                    tok, qp, _, dkeys = self._keep(dc, self._draft_step(
+                        dparams, *self._pools_of(dc), tables,
+                        jnp.asarray(base_pos + i), last, dkeys, temp, topk,
+                        topp, active))
+                    drafts.append(tok)
+                    qprobs.append(qp)
+                    last = tok
+                inputs = (
+                    tables, jnp.asarray(base_pos), jnp.asarray(self._last),
+                    tuple(drafts), tuple(qprobs), jnp.asarray(self._keys),
+                    temp, topk, topp, active, jnp.asarray(self._spec_on))
+                seq = phase.attrs["seq"] = self._next_seq()
+                out, count, accepted, keys = self._keep(
+                    self._cache, self._verify(
+                        self._spec.params(), *self._pools_of(self._cache),
+                        *inputs))
+            with self._phase("serving.loop.wait", "loop_wait", seq=seq,
+                             kind="step"):
+                out = np.asarray(out)   # device sync: the iteration is done
+                counts = np.asarray(count)
+                acc = np.asarray(accepted)
+        with self._phase("serving.loop.emit", "loop_emit", seq=seq,
+                         rows=active_n) as phase:
+            resolved = self._resolved
+            self._spec_emit(out, counts, acc, keys, dkeys,
+                            time.perf_counter() - t0)
+            phase.attrs["finished"] = self._resolved - resolved
+
+    def _spec_emit(self, out, counts, acc, keys, dkeys, dt: float) -> None:
+        """Hand a verified window's accepted tokens to their requests."""
+        m = self._spec_tokens
         self._keys = np.array(keys)
         self._draft_keys = np.array(dkeys)
-        dt = time.perf_counter() - t0
         self._metrics["token_latency"].observe(dt)
         self._metrics["decode_steps"].inc()
         # the verify program judges every window under the modified
@@ -1558,6 +1757,7 @@ class ServingEngine:
 
     def _resolve(self, state: _SlotState, reason: str) -> None:
         state.done = True
+        self._resolved += 1
         self._finish(state.pending, state.tokens, reason, state.ttft_s)
 
     def _finish(self, pending: _Pending, tokens: List[int], reason: str,

@@ -13,11 +13,15 @@ One subsystem, three surfaces:
   flight-recorder ring with crash blackbox dumps, and the fleet ``run_id``
   stamped into every trace event and scrape.
 
-**Always recorded**: the epoch-grain spans of the training loop
-(``epoch``, ``epoch_arrays``, ``h2d``, ``h2d_transfer``, ``dispatch``,
-``device_epoch``, ``stats_wait``; :mod:`.trace`), a handful an epoch, into
-the flight-recorder ring with their absolute ``perf_counter`` times (what
-that costs, the readiness thread's wake-ups included: :mod:`.trace`).
+**Always recorded**: the spans of a loop's own iteration — the training
+loop's (``epoch``, ``epoch_arrays``, ``h2d``, ``h2d_transfer``, ``dispatch``,
+``device_epoch``, ``stats_wait``), a handful an epoch, and the serving
+loop's (``serving.loop`` and its ``.admit`` / ``.prefill`` / ``.dispatch`` /
+``.wait`` / ``.emit``, ``serving.loop.idle``), some seven an iteration — and
+a ``gc`` span for a garbage collection that held the interpreter over a
+millisecond, into the flight-recorder ring with their absolute
+``perf_counter`` times (what that costs, the readiness thread's wake-ups
+included: :mod:`.trace`).
 **Governed by** ``DISTKERAS_TELEMETRY`` (see :mod:`.runtime`): everything
 dear — every other ``trace.span()`` (a shared no-op when unset), the trace
 and metrics files, histograms, the HTTP scrape, the crash blackbox.

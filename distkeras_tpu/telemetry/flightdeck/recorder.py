@@ -4,14 +4,22 @@ The flush-at-exit telemetry files answer "what happened over the whole run";
 the flight recorder answers "what happened in the last few seconds before it
 died".  It is a fixed-size ring — a preallocated list plus a monotonically
 increasing index, both touched under one cheap lock — fed by the correlated
-tracer (every finished span while telemetry is on, and the epoch-grain spans
-of the training loop always: see :mod:`..trace`), the metrics registry
+tracer (every finished span while telemetry is on; always, the spans of a
+loop's own iteration: the training loop's epoch-grain spans, the serving
+loop's ``serving.loop*`` with their ``iter`` and each program's ``seq``, its
+place in dispatch order, and a ``gc`` span for a collection that held the
+interpreter over a millisecond: see :mod:`..trace`), the metrics registry
 (every counter/gauge delta while telemetry is on), the
 :class:`DivergenceWatchdog` (every observation), and the sanitizer (every
 violation).  Recording is a tuple store; the per-event overhead is pinned by
 test next to the span fast path.  :meth:`FlightRecorder.spans` gives the
 ring's spans with their absolute ``perf_counter`` times, for a reader in the
-same process (the benchmark's ``readers/spans.py``).
+same process (the benchmark's ``readers/spans.py`` and
+``readers/serve_loop.py``).  A busy serving engine writes some seven spans
+an iteration at 100-170 programs a second, so in a serving process the
+ring's ``DEFAULT_CAPACITY`` entries are the loop's last two to three
+seconds: what a black box is for (the serving metrics read the engine's
+histograms, which a wrapped ring costs nothing).
 
 On an unhandled trainer exception, a watchdog halt, a strict sanitizer
 violation, or a daemon job crash, :func:`blackbox_dump` serialises the ring
@@ -50,8 +58,8 @@ class FlightRecorder:
     export), ``data`` a small JSON-safe payload, ``event`` the full Chrome
     trace event dict for spans recorded with telemetry on.  A span's ``data``
     is ``(t0, t1, thread, parent, attrs)``: absolute ``perf_counter``
-    seconds, the recording thread's name, the enclosing span's name, and the
-    span's attributes (``epoch`` among them).
+    seconds, the name of the thread it ran on, the enclosing span's name,
+    and the span's attributes (``epoch`` or ``iter`` among them).
     """
 
     def __init__(self, capacity: int = DEFAULT_CAPACITY):

@@ -2,7 +2,7 @@
 
 Usage at a host boundary (never inside jitted code):
 
-    with telemetry.trace.epoch_span("epoch", epoch=3):
+    with telemetry.trace.loop_span("epoch", epoch=3):
         with telemetry.trace.span("window_dispatch", window=0):
             ...
 
@@ -11,16 +11,38 @@ nest per-thread, and are recorded as complete ("ph": "X") events whose
 ts/dur containment gives Perfetto the nesting; each event also carries an
 explicit ``args.parent`` so tests and scripts need no interval math.
 
-**What is always recorded.**  The spans of the per-epoch loop — ``epoch``,
-``epoch_arrays``, ``h2d``, ``h2d_transfer``, ``dispatch``, ``device_epoch``,
-``stats_wait``: a handful an epoch — are opened with :meth:`Tracer.epoch_span`
-or closed by :meth:`Tracer.probe` and go into the flight-recorder ring
-whether or not ``DISTKERAS_TELEMETRY`` is set: two ``perf_counter`` reads and
-a tuple store each on the thread that opens them, and the readiness thread's
-wake-ups (below).  The ring keeps, for every span, its name, its absolute
-``perf_counter`` start and end in seconds, the thread, ``parent``, and the
-``epoch`` index that the spans of one epoch share
-(``flightdeck.recorder.spans()``).  **What the switch governs** is what is
+**What is always recorded.**  The spans of a loop's own iteration, opened
+with :meth:`Tracer.loop_span` or closed by :meth:`Tracer.probe`, go into the
+flight-recorder ring whether or not ``DISTKERAS_TELEMETRY`` is set: two
+``perf_counter`` reads and a tuple store each on the thread that opens them,
+and the readiness thread's wake-ups (below).  The ring keeps, for every span,
+its name, its absolute ``perf_counter`` start and end in seconds, the thread,
+``parent``, and the index that the spans of one iteration share
+(``flightdeck.recorder.spans()``).  Three kinds:
+
+* the per-epoch training loop's ``epoch``, ``epoch_arrays``, ``h2d``,
+  ``h2d_transfer``, ``dispatch``, ``device_epoch``, ``stats_wait``: a
+  handful an epoch, sharing ``epoch``;
+* the serving loop's (``serving/engine.py``): ``serving.loop`` (one
+  iteration that admitted or stepped: ``iter``, ``admitted``, ``active``,
+  ``starved``), and beneath it ``serving.loop.admit``,
+  ``serving.loop.prefill``, ``serving.loop.dispatch``, ``serving.loop.wait``
+  (the loop thread blocked on the device: the host's slack) and
+  ``serving.loop.emit``; ``serving.loop.idle`` for an iteration that found
+  nothing to do (its last read and its sleep).  Some seven an iteration, all
+  on the loop thread, sharing ``iter``; the programs' spans carry ``seq``,
+  the engine's count of the programs it has dispatched, in dispatch order,
+  which on one stream is the device's order of execution: the end of a
+  ``serving.loop.wait`` is the moment the host saw the end of program
+  ``seq``.  At 100-170 programs a second the ring (2048 entries) is a busy
+  engine's last two to three seconds;
+* ``gc``: a garbage collection of any generation that held the interpreter
+  for over a millisecond (:meth:`Tracer.watch_gc`: ``generation``,
+  ``collected``), on whatever thread ran it and under whatever span was open
+  there.  It enters the ring with the next span of a loop that does (a
+  collection can begin while its thread holds the ring's lock).
+
+**What the switch governs** is what is
 dear: every other ``span()`` (per-window, per-request), the tracer's own
 unbounded event list and the files written from it, the ``phase_*``
 histograms, the HTTP scrape.  With the switch off ``span()`` returns a shared
@@ -72,6 +94,8 @@ explicit span args instead.  ``tools/dktrace critical-path`` joins on them.
 
 from __future__ import annotations
 
+import collections
+import gc
 import json
 import os
 import queue
@@ -155,27 +179,59 @@ class Span:
         return False
 
 
-class _EpochSpan(Span):
-    """An epoch-grain span (:meth:`Tracer.epoch_span`).  One that is given
-    ``epoch=`` makes it the thread's current epoch while it is open; one that
-    is not takes the current epoch as its own, so that a span opened layers
-    below the loop (``data.epoch_arrays``, ``engine.shard_batches``) carries
-    the identifier its epoch's other spans share."""
+#: the attributes that number a loop's iterations: the training loop's and
+#: the serving loop's
+ITERATION_KEYS = ("epoch", "iter")
 
-    __slots__ = ("_outer",)
+
+class _LoopSpan(Span):
+    """A span of a loop's own iteration (:meth:`Tracer.loop_span`).  One
+    that is given ``epoch=`` or ``iter=`` makes it the thread's current
+    iteration while it is open; one that is not takes the current one as its
+    own, so that a span opened layers below the loop (``data.epoch_arrays``,
+    ``engine.shard_batches``, the serving loop's phases) carries the
+    identifier its iteration's other spans share.  ``observe``, if given, is
+    called with the span's seconds when it is recorded: the same two clock
+    reads as the span's own.  A span whose opener sets ``keep`` false before
+    it closes leaves no record (an iteration that found nothing to do);
+    ``attrs`` may be filled in until then."""
+
+    __slots__ = ("_outer", "_observe", "keep")
+
+    def __init__(self, tracer, name, phase, attrs, observe=None):
+        super().__init__(tracer, name, phase, attrs)
+        self._observe = observe
+        self.keep = True
 
     def __enter__(self):
         tls = self._tracer._tls
-        self._outer = getattr(tls, "epoch", None)
-        if "epoch" in self.attrs:
-            tls.epoch = self.attrs["epoch"]
-        elif self._outer is not None:
-            self.attrs["epoch"] = self._outer
+        self._outer = outer = getattr(tls, "iteration", None)
+        attrs = self.attrs
+        for key in ITERATION_KEYS:
+            if key in attrs:
+                tls.iteration = (key, attrs[key])
+                break
+        else:
+            if outer is not None:
+                attrs[outer[0]] = outer[1]
         return super().__enter__()
 
     def __exit__(self, exc_type, exc, tb):
-        self._tracer._tls.epoch = self._outer
-        return super().__exit__(exc_type, exc, tb)
+        tracer = self._tracer
+        t1 = tracer._clock()
+        parent = tracer._pop()
+        tracer._tls.iteration = self._outer
+        if self.keep:
+            tracer._record_loop(self.name, self._t0, t1, parent, self.attrs,
+                                self.phase)
+            if self._observe is not None:
+                self._observe(t1 - self._t0)
+        return False
+
+
+#: a collection that held the interpreter longer than this leaves a ``gc``
+#: span (every collection counts in ``Tracer.gc_seconds``)
+GC_SPAN_S = 1e-3
 
 
 #: probes the readiness thread may hold unfinished; one more is dropped
@@ -341,6 +397,14 @@ class Tracer:
         self._origin = clock()
         self.anchor = (self._origin, time.time_ns())
         self._probe = _ReadinessProbe(self)
+        # the interpreter's own pauses (watch_gc): seconds and count of every
+        # collection since the hook went in; the long ones wait here for the
+        # next span to take them into the ring
+        self.gc_seconds = 0.0
+        self.gc_collections = 0
+        self._gc_watched = False
+        self._gc_t0 = None
+        self._gc_pending = collections.deque()
 
     # ------------------------------------------------------------- recording
 
@@ -349,25 +413,63 @@ class Tracer:
             return NOOP_SPAN
         return Span(self, name, phase, attrs)
 
-    def epoch_span(self, name, phase=None, **attrs):
-        """A span of the per-epoch loop: recorded into the flight-recorder
-        ring whether or not telemetry is on (module docstring), and stamped
-        with the ``epoch`` of the epoch-grain span that encloses it.  A
-        handful an epoch, never one per window or per request."""
-        return _EpochSpan(self, name, phase, attrs)
+    def loop_span(self, name, phase=None, observe=None, **attrs):
+        """A span of a loop's own iteration (the training loop's epoch, the
+        serving loop's): recorded into the flight-recorder ring whether or
+        not telemetry is on (module docstring), and stamped with the
+        ``epoch`` or ``iter`` of the span of that grain that encloses it.
+        A handful an iteration, never one per window or per request;
+        attributes small and fixed (no request's ids).  ``observe(seconds)``
+        is called when the span is recorded."""
+        return _LoopSpan(self, name, phase, attrs, observe)
 
     def probe(self, arrays, name, t0, phase=None, **attrs):
         """Record the span ``t0 -> arrays ready on the device`` without
-        waiting here: the readiness thread watches them.  Epoch-grain like
-        :meth:`epoch_span` (always recorded; ``epoch`` and ``parent`` are
-        this thread's current ones).  Never pass a buffer that a later
-        dispatch donates.  False if the probe was dropped (queue full) and
-        counted in :attr:`probes_lost`."""
-        epoch = getattr(self._tls, "epoch", None)
-        if epoch is not None:
-            attrs.setdefault("epoch", epoch)
+        waiting here: the readiness thread watches them.  Loop-grain like
+        :meth:`loop_span` (always recorded; the iteration's index and
+        ``parent`` are this thread's current ones).  Never pass a buffer
+        that a later dispatch donates.  False if the probe was dropped (queue
+        full) and counted in :attr:`probes_lost`."""
+        iteration = getattr(self._tls, "iteration", None)
+        if iteration is not None:
+            attrs.setdefault(*iteration)
         return self._probe.submit(arrays, name, t0, phase, self.current(),
                                   attrs)
+
+    def watch_gc(self) -> None:
+        """Time the interpreter's garbage collections from now on (one
+        ``gc.callbacks`` hook a tracer, however often this is called; the
+        first ``ServingEngine.start()`` or trainer fit of the process calls
+        it on the module's :data:`trace`, switch or no switch).  A collection
+        holds the interpreter, so it stalls every thread whoever triggered
+        it: :attr:`gc_seconds` and :attr:`gc_collections` count them all, and
+        one of over ``GC_SPAN_S`` leaves a ``gc`` span (``generation``,
+        ``collected``)."""
+        with self._lock:
+            if self._gc_watched:
+                return
+            self._gc_watched = True
+        gc.callbacks.append(self._on_gc)
+
+    def _on_gc(self, phase, info):
+        # the collector runs this on the thread that tipped it over, with the
+        # interpreter held and possibly inside a lock of the ring's or of
+        # this tracer's: it touches no lock and records nothing itself
+        if phase == "start":
+            self._gc_t0 = self._clock()
+            return
+        t0, self._gc_t0 = self._gc_t0, None
+        if t0 is None:
+            return  # the hook went in during this collection
+        t1 = self._clock()
+        self.gc_seconds += t1 - t0
+        self.gc_collections += 1
+        if t1 - t0 > GC_SPAN_S:
+            self._gc_pending.append((
+                t0, t1, self.current(),
+                {"generation": info.get("generation"),
+                 "collected": info.get("collected")},
+                (threading.current_thread().name, threading.get_ident())))
 
     def drain(self, timeout=1.0) -> bool:
         """Wait, at most ``timeout`` seconds, for the readiness thread to
@@ -436,10 +538,25 @@ class Tracer:
             return
         self._record(name, t0, t1, None, attrs)
 
-    def _record(self, name, t0, t1, parent, attrs, phase=None):
-        thread = threading.current_thread().name
+    def _record_loop(self, name, t0, t1, parent, attrs, phase):
+        """A loop-grain span, behind the collections' spans that ``_on_gc``
+        left for it (only this grain takes them in: where no loop runs,
+        nothing always-on is recorded)."""
+        while self._gc_pending:
+            try:
+                pause = self._gc_pending.popleft()
+            except IndexError:
+                break  # another thread took it
+            self._record("gc", *pause[:4], origin=pause[4])
+        self._record(name, t0, t1, parent, attrs, phase)
+
+    def _record(self, name, t0, t1, parent, attrs, phase=None, origin=None):
+        """``origin`` is the ``(name, ident)`` of the thread the span ran
+        on, where that is not the one recording it."""
+        thread, ident = origin or (threading.current_thread().name,
+                                   threading.get_ident())
         if not runtime.enabled():
-            # an epoch-grain span with telemetry off: the ring alone
+            # a loop-grain span with telemetry off: the ring alone
             if self._correlated:
                 _flight_recorder.record_timed_span(
                     name, t0, t1, thread, parent, attrs)
@@ -449,14 +566,16 @@ class Tracer:
                 f"phase_{phase}_seconds",
                 help=f"seconds in the {phase} phase",
             ).observe(t1 - t0)
-        ident = threading.get_ident()
         args = dict(attrs)
         ctx = getattr(self._tls, "ctx", None)
         if ctx:
             for key, value in ctx.items():
                 args.setdefault(key, value)
         if parent is not None:
-            args["parent"] = parent
+            # a parent that the span names itself (a request's span on the
+            # serving loop's thread names the span of the thread that
+            # admitted it) is not the enclosing span's to overwrite
+            args.setdefault("parent", parent)
         if self._correlated:
             rid = _correlate.current()
             if rid is not None:
@@ -480,6 +599,7 @@ class Tracer:
 
     def reset(self):
         self._probe.drain(1.0)
+        self._gc_pending.clear()
         with self._lock:
             self._events.clear()
             self._tids.clear()

@@ -414,8 +414,12 @@ class Trainer:
         when one is configured, and — on ANY unhandled exception, including
         watchdog halts and strict sanitizer violations — dumps the
         flight-recorder blackbox into the telemetry dir before re-raising.
-        With telemetry off this is one cached-bool check per fit.
+        With telemetry off this is one cached-bool check per fit.  Switch or
+        no switch, the interpreter's collections are timed from the first
+        fit on (``trace.watch_gc``: a ``gc`` span in the ring beside the
+        epoch's for one that held the host over a millisecond).
         """
+        telemetry.trace.watch_gc()
         if telemetry.enabled():
             telemetry.flightdeck.activate()
         try:
@@ -685,7 +689,7 @@ class Trainer:
         def _materialise(stats, epoch_idx):
             # the one place the training thread waits for the device: how
             # long it had nothing to do but wait (always recorded)
-            with telemetry.trace.epoch_span("stats_wait"):
+            with telemetry.trace.loop_span("stats_wait"):
                 stats = jax.tree.map(np.asarray, stats)
             dyn = stats.get("dynamics")
             summary = None
@@ -758,7 +762,7 @@ class Trainer:
                     _chaos.fault("epoch")  # seeded kill entering this epoch
                 if prof is not None:
                     prof.on_step(epoch)
-                with telemetry.trace.epoch_span(
+                with telemetry.trace.loop_span(
                         "epoch", epoch=epoch, epochs=1):
                     if self.streaming:
                         from distkeras_tpu.data import epoch_window_iter, plan_epoch
@@ -1025,7 +1029,7 @@ class Trainer:
                 prof.on_step(start_epoch + chunk_idx)
             # "epoch" span per chunk dispatch (attrs carry how many epochs it
             # covers), around the same spans as the per-epoch loop's
-            with telemetry.trace.epoch_span("epoch", epoch=epoch, epochs=chunk):
+            with telemetry.trace.loop_span("epoch", epoch=epoch, epochs=chunk):
                 state, stats = engine.run_epochs(
                     state, xs, ys, chunk, shuffle_seed=shuffle_seed)
                 if ps is not None:

@@ -175,7 +175,9 @@ def test_the_manifest_lists_the_cell_under_what_it_reports(harness):
         "slot_occupancy", "prefill_padding_share", "goodput_share",
         "serve_mfu", "serve_compiles_in_window", "serve_device_idle_share",
         "serve_peak_hbm_gb", "decode_kv_read_share", "decode_chained_share",
-        "decode_argmax_share",
+        "decode_argmax_share", "loop_host_ms", "loop_wait_share",
+        "step_dispatch_ms", "emit_ms", "queue_wait_ms",
+        "dispatch_starved_share", "gc_pause_share",
         "moe_held_share", "moe_load_max_over_mean", "state_bytes_per_position",
         "moe_tiles_per_expert"}
     names = [m["name"] for m in manifest["per_layer"]]

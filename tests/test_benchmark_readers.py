@@ -162,13 +162,16 @@ def test_every_reader_without_a_test_of_its_own_is_here(harness):
     ring (``test_benchmark_span_readers.py``) or on the hand-made marks
     (``test_benchmark_decode_kv_reader.py``,
     ``test_benchmark_decode_chained_reader.py``,
-    ``test_benchmark_mla_moe.py``, ``test_benchmark_scmoe.py``)."""
+    ``test_benchmark_mla_moe.py``, ``test_benchmark_scmoe.py``,
+    ``test_benchmark_serve_loop_reader.py``)."""
     elsewhere = {"gather_ms", "h2d_ms", "dispatch_ms", "host_slack_ms",
                  "feed_gap_ms", "gap_unattributed_share", "decode_kv_read_share",
                  "decode_chained_share", "moe_held_share",
                  "moe_load_max_over_mean", "state_bytes_per_position",
                  "moe_tiles_per_expert", "moe_zero_share",
-                 "moe_real_picks_max_over_mean"}
+                 "moe_real_picks_max_over_mean", "loop_host_ms",
+                 "loop_wait_share", "step_dispatch_ms", "emit_ms",
+                 "queue_wait_ms", "dispatch_starved_share", "gc_pause_share"}
     names = {m["name"] for m in harness.load_manifest()["per_layer"]}
     assert names == set(ANSWERS) | elsewhere
 

@@ -353,6 +353,17 @@ def test_serving_metrics_schema_golden():
     m["hot_swaps"].inc(2)
     m["kv_read"].inc(768)
     m["kv_capacity"].inc(1024)
+    # the loop thread's account of its own time
+    m["loop_iteration"].observe(0.011)
+    m["loop_dispatch"].observe(0.0012)
+    for _ in range(2):
+        m["loop_wait"].observe(0.004)
+        m["loop_emit"].observe(0.0003)
+    m["loop_idle"].observe(0.05)
+    m["queue_wait"].observe(1.9)
+    m["dispatches"].inc(21)
+    m["dispatches_starved"].inc(2)
+    m["gc_pause"].inc(0.03)
     # and the counters that a block with experts brings itself
     block = LatentMoELM(vocab_size=8, max_len=8).decode_spec(None).instruments(
         registry)
