@@ -19,6 +19,7 @@ from distkeras_tpu.models.generate import greedy_generate
 from distkeras_tpu.models.latent_moe import LatentMoELM
 from distkeras_tpu.models.scmoe import ShortcutMoELM
 from distkeras_tpu.models.staged import StagedLM, StagedTransformer
+from distkeras_tpu.models.window_moe import WindowMoELM
 from distkeras_tpu.models.transformer import (
     TransformerClassifier,
     TransformerEncoderBlock,
@@ -42,6 +43,7 @@ __all__ = [
     "TransformerLM",
     "LatentMoELM",
     "ShortcutMoELM",
+    "WindowMoELM",
     "StagedTransformer",
     "StagedLM",
     "greedy_generate",

@@ -37,13 +37,33 @@ class DecodeSpec:
     """What a causal LM hands the serving engine.
 
     ``state``
-        The per-layer state a position keeps, as ``(name, row_width)`` pools
-        a layer: ``(("k", 768), ("v", 768))`` for GPT-2's block, ``(("latent",
+        The kinds of state a layer keeps, as ``(name, row_width)`` pairs:
+        ``(("k", 768), ("v", 768))`` for GPT-2's block, ``(("latent",
         576),)`` for latent attention, ``(("latent_0", 576), ("latent_1",
         576))`` for a double layer of two latent attentions: two pools of the
         same kind, each written and read by its own sub-block within one
         ``prefill`` or ``step`` call (``num_layers`` then counts the double
-        layers).  Every layer keeps the same kinds.
+        layers).  A kind is paged a position (one pool a layer, through the
+        slots' page tables) unless ``sliding`` bounds it.
+    ``layer_kinds``
+        Optional: the names of the kinds each layer keeps, a tuple a layer
+        (``(("k", "v"), ("sliding_k", "sliding_v"), ...)`` for a block of
+        global and sliding-window layers).  None: every layer keeps every
+        kind of ``state``.  ``prefill`` and ``step`` of a layer see and
+        write its own kinds alone.
+    ``sliding``
+        Optional: ``(name, window)`` of each kind that is bounded by a
+        window: a sliding-window layer's keys and values, of which a query
+        sees the last ``window`` positions alone.  Such a kind is not paged:
+        it lives in a store of ``window`` rows a slot and layer, ``[slots +
+        1, window, row_width]``, a ring at ``position % window`` whose last
+        slot is scratch (``serving/cache.py``).  ``prefill``'s ``write`` of
+        such a kind keeps the prompt's last ``window`` rows of the chunk in
+        the slot's ring; ``step`` gets the whole store and writes row
+        ``pos % window`` of each ``live`` slot itself (the slot's index is its
+        row in the batch; a slot that is not ``live`` writes to the scratch
+        slot).  (Not the ``window`` function below, which is the speculative
+        verify.)
     ``weights``
         The pytree every program takes as its first argument (``params()``).
     ``num_layers``, ``max_len``, ``vocab_size``
@@ -61,7 +81,8 @@ class DecodeSpec:
         block's own counters read of this layer (below), else None.
     ``step(weights, layer, x, pools, tables, pos, live) -> (pools, x, aux)``
         One layer, one token a slot: ``x [slots, 1, dim]``, ``pools`` a dict
-        ``name -> [pages, page_size, row_width]`` of this layer, ``tables
+        ``name -> [pages, page_size, row_width]`` of this layer's kinds (a
+        sliding kind's store as above), ``tables
         [slots, pages_per_slot]``, ``pos [slots]``.  Writes the step's rows
         at ``pos`` and attends over positions ``0 .. pos``.  ``live [slots,
         1]`` marks the slots that hold a request.
@@ -103,11 +124,25 @@ class DecodeSpec:
     pool_specs: Any = None
     instruments: Optional[Callable] = None
     observe: Optional[Callable] = None
+    layer_kinds: Optional[Tuple[Tuple[str, ...], ...]] = None
+    sliding: Tuple[Tuple[str, int], ...] = ()
 
     def params(self):
         """The pytree passed (not closed over) to the jitted programs, so
         big leaves ride as runtime buffers rather than baked constants."""
         return self.weights
+
+    def kinds(self, layer: int) -> Tuple[str, ...]:
+        """The names of the kinds of state that ``layer`` keeps."""
+        if self.layer_kinds is None:
+            return tuple(name for name, _ in self.state)
+        return self.layer_kinds[layer]
+
+    @property
+    def layout(self) -> Tuple:
+        """What two specs must share for their programs to take the same
+        pools: the kinds, which layer keeps which, and their windows."""
+        return (self.state, self.layer_kinds, self.sliding)
 
 
 # ------------------------------------------------- the GPT-2-shaped block

@@ -57,13 +57,14 @@ A plain dataclass, not a flax module: ``init(key)`` makes a parameter tree,
 serves it by.  The tensor-parallel (``mesh=``) and speculative
 (``draft_model=``) builds are not supported for this block yet.
 
-**Shared with the other latent-attention block** (``models/scmoe.py``'s
-``ShortcutMoELM``, which imports them from here): the module-level
-:func:`rms_norm`, :func:`rope`, :func:`expanded_attention`,
-:func:`absorbed_step`, :func:`gated`, :func:`held_experts_terms` with
-:func:`tile_height`, :func:`embed_tokens`, :func:`final_head`,
-:func:`geometry_of`, :func:`init_params`, :func:`moe_instruments` and
-:func:`observe_walk`.  They take what differs
+**Shared with the other expert blocks** (``models/scmoe.py``'s
+``ShortcutMoELM`` and ``models/window_moe.py``'s ``WindowMoELM``, which
+import them from here): the module-level :func:`rms_norm`, :func:`rope`,
+:func:`expanded_attention`, :func:`absorbed_step`, :func:`gated`,
+:func:`sigmoid_route`, :func:`held_experts_terms` with :func:`tile_height`,
+:func:`embed_tokens`, :func:`final_head`, :func:`geometry_of`,
+:func:`init_params`, :func:`moe_instruments` and :func:`observe_walk`.
+They take what differs
 between the blocks (the softmax's scale, the latent's width, the held range,
 the router's width) as arguments; ``LatentMoELM``'s methods of the same
 names hand them its own.
@@ -243,6 +244,20 @@ def final_head(params, x, eps, at=None):
         x = jax.lax.dynamic_index_in_dim(x[0], at, axis=0, keepdims=False)
     return _dot(rms_norm(x, params["norm"], eps), params["head"],
                 "...d,dv->...v")
+
+
+def sigmoid_route(p, h, k, scaling):
+    """``(ids, weights) [tokens, k]``: the top ``k`` of ``sigmoid(W_r h) +
+    b``, weighed by the scores without the bias, normalised to 1 and scaled
+    by ``scaling``.  Float32 at the highest precision: a near tie at rank k
+    falls the same way here as in a plain reference."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        h.astype(F32), p["router"].astype(F32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, ids = jax.lax.top_k(scores + p["router_bias"].astype(F32), k)
+    picked = jnp.take_along_axis(scores, ids, axis=-1)
+    weights = picked / jnp.sum(picked, -1, keepdims=True)
+    return ids, weights * scaling
 
 
 def held_experts_terms(p, h, ids, weights, held, experts, live=None):
@@ -487,18 +502,9 @@ class LatentMoELM:
     _gated = staticmethod(gated)
 
     def route(self, p, h):
-        """``(ids, weights) [tokens, k]``: the top k of ``sigmoid(W_r h) +
-        b``, weighed by the scores without the bias, normalised to 1 and
-        scaled.  Float32 at the highest precision: a near tie at rank k
-        falls the same way here as in a plain reference."""
-        scores = jax.nn.sigmoid(jnp.dot(
-            h.astype(F32), p["router"].astype(F32),
-            precision=jax.lax.Precision.HIGHEST))
-        _, ids = jax.lax.top_k(scores + p["router_bias"].astype(F32),
-                               self.num_experts_per_tok)
-        picked = jnp.take_along_axis(scores, ids, axis=-1)
-        weights = picked / jnp.sum(picked, -1, keepdims=True)
-        return ids, weights * self.routed_scaling_factor
+        """:func:`sigmoid_route` at this model's top k and scaling."""
+        return sigmoid_route(p, h, self.num_experts_per_tok,
+                             self.routed_scaling_factor)
 
     def held_experts_terms(self, p, h, ids, weights, live=None):
         """:func:`held_experts_terms` for this model's held range and its

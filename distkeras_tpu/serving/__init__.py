@@ -8,13 +8,16 @@ a service that admits requests whenever they arrive:
   (fixed slot ring, ONE jitted step, prefill-on-admission / retire-on-EOS).
   It serves any model whose ``decode_spec(params)`` returns a
   :class:`~distkeras_tpu.models.decode.DecodeSpec` (the block contract: the
-  kinds of state a layer keeps a position, and the model's own embedding,
-  prefill layer, step layer and head): ``TransformerLM``, ``StagedLM``,
-  ``LatentMoELM``, ``ShortcutMoELM``;
+  kinds of state a layer keeps, which layer keeps which, and the model's
+  own embedding, prefill layer, step layer and head): ``TransformerLM``,
+  ``StagedLM``, ``LatentMoELM``, ``ShortcutMoELM``, ``WindowMoELM``;
 * :mod:`~distkeras_tpu.serving.cache` — the paged cache: slot page tables
   over shared pools, one tuple of per-layer pools for each kind of state the
-  block declares (keys and values; or latent attention's one row for all
-  heads, written by an expanded prefill and read by an absorbed step);
+  block declares (keys and values, multi-head or grouped-query; or latent
+  attention's one row for all heads, written by an expanded prefill and
+  read by an absorbed step), and for a kind that a window bounds (a
+  sliding-window layer's keys and values) a ring of ``window`` rows a slot
+  instead of pages;
 * :mod:`~distkeras_tpu.serving.sampling` — temperature / top-k / top-p
   with per-request seeds, all traced (no recompiles);
 * :mod:`~distkeras_tpu.serving.frontend` — request/response dataclasses,
@@ -40,7 +43,7 @@ prefill width ladder; ``draft_model``/``spec_tokens`` — speculative
 decoding with exact accept/resample semantics; ``mesh`` — tensor-parallel
 decode over the local devices.  The last two are builds a block opts into
 (``DecodeSpec.window`` / ``.shard``): GPT-2's block brings both,
-the two latent-attention blocks neither yet, and the engine refuses those
+the three expert blocks neither yet, and the engine refuses those
 combinations at construction.
 """
 
@@ -49,6 +52,7 @@ from distkeras_tpu.serving.cache import (
     append_rows,
     paged_decode_attention,
     rollback_rows,
+    sliding_decode_attention,
 )
 from distkeras_tpu.serving.engine import EngineCrashed, ServingEngine, serving_metrics
 from distkeras_tpu.serving.frontend import (
@@ -100,6 +104,7 @@ __all__ = [
     "install_tier_endpoint",
     "modified_probs",
     "paged_decode_attention",
+    "sliding_decode_attention",
     "rollback_rows",
     "sample_one",
     "sample_tokens",

@@ -18,14 +18,32 @@ all addressed by the same tables:
 
 * full attention (GPT-2's block): ``k`` and ``v``, a token's heads side by
   side in a row of ``heads * head_dim``;
+* grouped-query attention (``WindowMoELM``'s global layers): ``k`` and
+  ``v`` of the key-value heads alone, a row of ``kv_heads * head_dim`` and
+  one of ``kv_heads * v_dim`` (768 and 512 for the published shapes);
 * latent attention (``LatentMoELM``): one ``latent`` row for all heads, the
   normalised latent and the rotated shared key (``kv_lora_rank +
   qk_rope_head_dim``, 576 for the published shapes: 1,152 bytes a position
   and layer in bfloat16 where the same heads uncompressed keep 40,960).
 
+A block may keep different kinds in different layers
+(``DecodeSpec.layer_kinds``: a layer's entry of a kind it does not keep is
+None), and a kind may be **bounded by a window** (``DecodeSpec.sliding``): a
+sliding-window layer sees a slot's last ``window`` positions alone, so its
+rows live in a per-slot store of ``window`` rows, a ring indexed by
+``position % window``, and not in pages: its size does not grow with the
+context (``WindowMoELM``'s sliding layers: 128 rows a slot, where their
+global layers page up to 13,312).  The prefill sets a slot's ring up at
+admission (:func:`sliding_prefill_rows`), the step writes one row in place
+and reads the ring (:func:`sliding_decode_attention`).  What is paged counts
+a position (:meth:`PagedKVCache.bytes_per_position`), what is stored a slot
+(:meth:`PagedKVCache.bytes_per_slot`).
+
 Layout::
 
     pools[name] : num_layers arrays [num_pages, page_size, pool_width(row_width)]
+                  (a sliding kind: [num_slots + 1, window, pool_width(row_width)];
+                  None in a layer that does not keep the kind)
     tables      : [num_slots, pages_per_slot] int32 (host, numpy)
 
 One array a layer, a position's state in one row: a row fills the TPU's 128
@@ -43,8 +61,9 @@ and inactive slots point at it, so masked-off lanes of the decode step write
 garbage there instead of corrupting live pages.  Attention masks by position
 (``key_pos <= pos``), so scratch garbage is never read.
 
-What a decode step reads: :func:`paged_decode_attention` (keys and values)
-and :func:`paged_latent_attention` (a latent row) write the step's rows
+What a decode step reads: :func:`paged_decode_attention` (keys and values,
+multi-head or grouped-query) and :func:`paged_latent_attention` (a latent
+row) write the step's rows
 through the table and then walk each slot's pages a block at a time, as far
 as the longest live slot reaches (``pos``), with a running maximum,
 denominator and weighted sum — never a slot's whole window at once, and
@@ -72,6 +91,7 @@ import numpy as np
 
 __all__ = ["PagedKVCache", "append_rows", "rollback_rows",
            "paged_decode_attention", "paged_latent_attention",
+           "sliding_decode_attention", "sliding_prefill_rows",
            "decode_block_pages", "pool_width", "fit_rows"]
 
 
@@ -188,16 +208,35 @@ def _attend_live_blocks(block, weigh, pos, span):
     return l, acc
 
 
+def _grouped(q, k, v):
+    """``(slots, kv_heads, group, head_dim, v_dim)`` of a step's query
+    ``[slots, 1, heads, head_dim]`` and its key and value rows ``[slots, 1,
+    kv_heads, .]``: each key-value head serves ``group = heads / kv_heads``
+    consecutive query heads (1: multi-head attention)."""
+    slots, _, heads, head_dim = q.shape
+    kv_heads, v_dim = v.shape[2], v.shape[3]
+    if heads % kv_heads or k.shape[2] != kv_heads:
+        raise ValueError(f"{heads} query heads cannot share {k.shape[2]} key "
+                         f"and {kv_heads} value heads")
+    return slots, kv_heads, heads // kv_heads, head_dim, v_dim
+
+
 def paged_decode_attention(kpool, vpool, tables, pos, q, k, v):
     """The width-1 step of one layer over all slots: write the step's K and V
     row at ``pos`` through ``tables`` (in place: the pools are donated), then
     attend ``q`` over each slot's positions ``0 .. pos``.
 
-    ``kpool``/``vpool`` ``[pages, page_size, heads*head_dim]``; ``tables
-    [slots, pages_per_slot]``; ``pos [slots]``; ``q``/``k``/``v`` ``[slots,
-    1, heads, head_dim]`` (the head count is read off them, so the
-    tensor-parallel build hands in its local heads and its local pools).
-    Returns ``(kpool, vpool, out [slots, 1, heads, head_dim])``.
+    ``kpool`` ``[pages, page_size, kv_heads*head_dim]``, ``vpool`` ``[pages,
+    page_size, kv_heads*v_dim]``; ``tables [slots, pages_per_slot]``; ``pos
+    [slots]``; ``q [slots, 1, heads, head_dim]``, ``k [slots, 1, kv_heads,
+    head_dim]``, ``v [slots, 1, kv_heads, v_dim]`` (the head counts and
+    widths are read off them, so the tensor-parallel build hands in its
+    local heads and its local pools).  Grouped-query attention where
+    ``kv_heads`` divides ``heads``: key-value head ``i`` serves query heads
+    ``i * group .. (i + 1) * group - 1``, and one gather of a block serves
+    them all; multi-head where the two are equal.  The scores are scaled by
+    ``1 / sqrt(head_dim)``.  Returns ``(kpool, vpool, out [slots, 1, heads,
+    v_dim])``.
 
     The read goes block by block (:func:`decode_block_pages` pages of every
     slot at a time, gathered through the table) with an online softmax in
@@ -209,7 +248,8 @@ def paged_decode_attention(kpool, vpool, tables, pos, q, k, v):
     inactive slot's table points at).  Block 0 always holds position 0, so
     every slot has a finite maximum before a fully masked block can meet it.
     """
-    slots, _, heads, head_dim = q.shape
+    slots, kv_heads, group, head_dim, v_dim = _grouped(q, k, v)
+    heads = kv_heads * group
     page_size = kpool.shape[1]
     pages_per_slot = tables.shape[1]
     kpool = append_rows(kpool, tables, pos, k)
@@ -220,23 +260,89 @@ def paged_decode_attention(kpool, vpool, tables, pos, q, k, v):
     nblocks = -(-pages_per_slot // bp)
     # a last, partial block reads the scratch page for the rows it lacks
     padded = jnp.pad(tables, ((0, 0), (0, nblocks * bp - pages_per_slot)))
-    q0 = q[:, 0].astype(jnp.float32)
+    q0 = q[:, 0].astype(jnp.float32).reshape(slots, kv_heads, group, head_dim)
     scale = 1.0 / jnp.sqrt(head_dim).astype(jnp.float32)
 
     def block(j):
         """Scores ``[slots, heads, span]`` and values of block ``j``."""
         tb = jax.lax.dynamic_slice_in_dim(padded, j * bp, bp, axis=1)
-        kb = kpool[tb].reshape(slots, span, heads, head_dim)
-        vb = vpool[tb].reshape(slots, span, heads, head_dim)
-        sc = jnp.einsum("shd,skhd->shk", q0, kb.astype(jnp.float32)) * scale
+        kb = kpool[tb].reshape(slots, span, kv_heads, head_dim)
+        vb = vpool[tb].reshape(slots, span, kv_heads, v_dim)
+        sc = jnp.einsum("skgd,sjkd->skgj", q0, kb.astype(jnp.float32)) * scale
         key_pos = j * span + jnp.arange(span)
         live = key_pos[None, :] <= pos[:, None]
-        return jnp.where(live[:, None, :], sc, -jnp.inf), vb
+        return jnp.where(live[:, None, :], sc.reshape(slots, heads, span),
+                         -jnp.inf), vb
 
-    weigh = lambda p, vb: jnp.einsum("shk,skhd->shd", p, vb.astype(jnp.float32))
+    def weigh(p, vb):
+        p = p.reshape(slots, kv_heads, group, span)
+        return jnp.einsum("skgj,sjkd->skgd", p, vb.astype(
+            jnp.float32)).reshape(slots, heads, v_dim)
+
     l, acc = _attend_live_blocks(block, weigh, pos, span)
     out = (acc / l[..., None]).astype(q.dtype)
     return kpool, vpool, out[:, None]
+
+
+def sliding_prefill_rows(store, slot, rows, length):
+    """Set up ``slot``'s rows of one sliding-window layer's ``store
+    [slots + 1, window, width]`` from a prefill chunk ``rows [width, ...]``
+    whose first ``length`` rows are the prompt's: position ``j`` of the
+    prompt's last ``window`` lands at ``j % window``, and the rows before
+    position 0 (a prompt shorter than the window) are zeroed.  All ``window``
+    rows of the slot are written, so nothing of the request that held the
+    slot before survives its admission."""
+    window = store.shape[1]
+    at = length - window + jnp.arange(window)
+    taken = rows.reshape(rows.shape[0], -1)[jnp.clip(at, 0, rows.shape[0] - 1)]
+    kept = jnp.where((at >= 0)[:, None], taken, 0)
+    return store.at[slot, at % window].set(fit_rows(kept, store))
+
+
+def sliding_decode_attention(kstore, vstore, pos, live, q, k, v, sinks,
+                             scale):
+    """The width-1 step of one **sliding-window** layer over all slots: the
+    query at ``pos`` sees the keys at ``pos - window < j <= pos``.
+
+    ``kstore [slots + 1, window, kv_heads*head_dim]`` and ``vstore [slots +
+    1, window, kv_heads*v_dim]`` hold each slot's last ``window`` rows as a
+    ring, position ``j`` at row ``j % window``; row ``slots`` is a scratch
+    slot.  The step's rows are written in place at ``pos % window`` (a slot
+    that is not ``live [slots]`` writes to the scratch slot), then the whole
+    ring of every slot is read, once for all the query heads of a key-value
+    head (``q``, ``k``, ``v`` as :func:`paged_decode_attention` takes them).
+    A ring row whose position would lie before 0 is masked.  ``sinks
+    [heads]`` (float32, or None) are learned logits that stand in the
+    softmax's denominator and weigh no value: ``p_j = exp(s_j) / (exp(sink)
+    + sum_k exp(s_k))``.  Scores at ``scale``, in float32.  Returns
+    ``(kstore, vstore, out [slots, 1, heads, v_dim])``."""
+    slots, kv_heads, group, head_dim, v_dim = _grouped(q, k, v)
+    window = kstore.shape[1]
+    row = jnp.where(live, jnp.arange(slots), slots)
+    kstore = kstore.at[row, pos % window].set(
+        fit_rows(k[:, 0].reshape(slots, -1), kstore))
+    vstore = vstore.at[row, pos % window].set(
+        fit_rows(v[:, 0].reshape(slots, -1), vstore))
+    kb = kstore[:slots, :, :kv_heads * head_dim].reshape(
+        slots, window, kv_heads, head_dim)
+    vb = vstore[:slots, :, :kv_heads * v_dim].reshape(
+        slots, window, kv_heads, v_dim)
+    q0 = q[:, 0].astype(jnp.float32).reshape(slots, kv_heads, group, head_dim)
+    sc = jnp.einsum("skgd,sjkd->skgj", q0, kb.astype(jnp.float32)) * scale
+    # ring row r holds position pos - ((pos - r) mod window)
+    back = (pos[:, None] - jnp.arange(window)[None, :]) % window
+    sc = jnp.where((back <= pos[:, None])[:, None, None, :], sc, -jnp.inf)
+    m = jnp.max(sc, axis=-1)
+    if sinks is not None:
+        sink = sinks.astype(jnp.float32).reshape(1, kv_heads, group)
+        m = jnp.maximum(m, sink)
+    p = jnp.exp(sc - m[..., None])
+    l = jnp.sum(p, axis=-1)
+    if sinks is not None:
+        l = l + jnp.exp(sink - m)
+    out = jnp.einsum("skgj,sjkd->skgd", p, vb.astype(jnp.float32))
+    out = (out / l[..., None]).reshape(slots, 1, kv_heads * group, v_dim)
+    return kstore, vstore, out.astype(q.dtype)
 
 
 def paged_latent_attention(pool, tables, pos, q, row, latent_width, scale):
@@ -300,6 +406,17 @@ class PagedKVCache:
     ``heads``/``head_dim`` without ``state`` mean the keys and values of
     that geometry.
 
+    ``layer_kinds`` (``DecodeSpec.layer_kinds``) names the kinds each layer
+    keeps; a layer's entry of a kind it does not keep is None (default:
+    every layer keeps every kind).  A kind named in ``sliding`` (``(name,
+    window)`` pairs) keeps a slot's last ``window`` positions alone, in a
+    **store** ``[num_slots + 1, window, row_width]`` a layer instead of
+    pages: a ring indexed by slot and ``position % window``, whose last row
+    is a scratch slot for the slots that take no part in a step.  It is the
+    slot's from its admission (the prefill writes all ``window`` rows:
+    :func:`sliding_prefill_rows`) to its retirement, so nothing of it is
+    allocated or freed by the tables.
+
     ``pages_per_slot`` rows of the table bound each slot's context to
     ``pages_per_slot * page_size`` tokens; ``num_pages`` bounds the fleet of
     pages (default: enough for every slot at full context, plus the scratch
@@ -308,7 +425,7 @@ class PagedKVCache:
 
     def __init__(self, *, num_layers, num_slots, page_size, pages_per_slot,
                  state=None, heads=None, head_dim=None, num_pages=None,
-                 dtype=jnp.float32):
+                 dtype=jnp.float32, layer_kinds=None, sliding=()):
         if page_size < 1 or pages_per_slot < 1 or num_slots < 1:
             raise ValueError("page_size, pages_per_slot, num_slots must be >= 1")
         if state is None:
@@ -319,15 +436,32 @@ class PagedKVCache:
         self.num_slots = int(num_slots)
         self.page_size = int(page_size)
         self.pages_per_slot = int(pages_per_slot)
+        self.sliding = {str(name): int(window) for name, window in sliding}
+        names = tuple(name for name, _ in self.state)
+        self.layer_kinds = (tuple(tuple(kinds) for kinds in layer_kinds)
+                            if layer_kinds is not None
+                            else (names,) * self.num_layers)
+        if (len(self.layer_kinds) != self.num_layers or not set(
+                self.sliding) | {k for kinds in self.layer_kinds
+                                 for k in kinds} <= set(names)):
+            raise ValueError(f"layer_kinds {self.layer_kinds} and sliding "
+                             f"{sliding} name kinds of {names}, a layer each")
         if num_pages is None:
             num_pages = num_slots * pages_per_slot + 1  # +1 scratch
         if num_pages < 2:
             raise ValueError("need at least one real page beyond scratch")
         self.num_pages = int(num_pages)
+        self.dtype = jnp.dtype(dtype)
+
+        def store(name, width):
+            rows = ((self.num_slots + 1, self.sliding[name])
+                    if name in self.sliding
+                    else (self.num_pages, self.page_size))
+            return jnp.zeros(rows + (pool_width(width),), dtype)
+
         self.pools = {
-            name: tuple(jnp.zeros((self.num_pages, self.page_size,
-                                   pool_width(width)), dtype)
-                        for _ in range(self.num_layers))
+            name: tuple(store(name, width) if name in kinds else None
+                        for kinds in self.layer_kinds)
             for name, width in self.state}
         # host-side: table rows point at scratch (page 0) until allocated
         self.tables = np.zeros((self.num_slots, self.pages_per_slot), np.int32)
@@ -344,12 +478,23 @@ class PagedKVCache:
 
     # ------------------------------------------------------------- queries
 
+    def _layers_keeping(self, name) -> int:
+        return sum(name in kinds for kinds in self.layer_kinds)
+
     def bytes_per_position(self) -> int:
-        """Bytes of state the block keeps a position, over all layers and
-        kinds: its declared rows in the pools' type (a pool's lane padding,
-        :func:`pool_width`, is not the block's)."""
-        return sum(width * self.pools[name][0].dtype.itemsize * self.num_layers
-                   for name, width in self.state)
+        """Bytes of paged state the block keeps a position, over all layers
+        and paged kinds: its declared rows in the pools' type (a pool's lane
+        padding, :func:`pool_width`, is not the block's)."""
+        return sum(width * self.dtype.itemsize * self._layers_keeping(name)
+                   for name, width in self.state if name not in self.sliding)
+
+    def bytes_per_slot(self) -> int:
+        """Bytes of fixed state the block keeps a slot, whatever its length:
+        the sliding kinds' ``window`` declared rows over the layers that keep
+        them, in the stores' type."""
+        return sum(width * self.dtype.itemsize * self.sliding[name]
+                   * self._layers_keeping(name)
+                   for name, width in self.state if name in self.sliding)
 
     def pages_needed(self, length: int) -> int:
         """Pages required to hold ``length`` tokens of context."""

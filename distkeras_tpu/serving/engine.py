@@ -15,9 +15,11 @@ continuous-batching formulation (Orca/vLLM):
   supplies its own embedding, prefill layer, single-token step layer and
   head; the engine keeps slots, pages, programs, sampling and the loop, and
   names no submodule and holds no attention arithmetic of any block.
-  ``TransformerLM`` and ``StagedLM`` (two pools a layer, keys and values)
-  and ``LatentMoELM`` (one latent pool a layer) are served by the same loop
-  and the same programs' builders;
+  ``TransformerLM`` and ``StagedLM`` (two pools a layer, keys and values),
+  ``LatentMoELM`` (one latent pool a layer), ``ShortcutMoELM`` (two latent
+  pools a double layer) and ``WindowMoELM`` (paged keys and values in its
+  global layers, a ring of the window's rows a slot in its sliding ones)
+  are served by the same loop and the same programs' builders;
 * ONE jitted single-token **decode step** over all slots — every
   per-request quantity (position, last token, RNG key,
   temperature/top-k/top-p, active flag, speculative opt-in) is *data*, so
@@ -30,7 +32,11 @@ continuous-batching formulation (Orca/vLLM):
   reads the pages block by block as far as the longest live slot reaches
   (:func:`~distkeras_tpu.serving.cache.paged_decode_attention` for keys and
   values, :func:`~distkeras_tpu.serving.cache.paged_latent_attention` for a
-  latent row): its cost follows what the slots hold, not what they could;
+  latent row): its cost follows what the slots hold, not what they could.
+  A kind that a window bounds (``DecodeSpec.sliding``) lives in a ring of
+  ``window`` rows a slot instead, set up whole by the admission's prefill
+  and read whole by the step
+  (:func:`~distkeras_tpu.serving.cache.sliding_decode_attention`);
 * between decode steps the host loop **admits** queued requests into free
   slots (prefill) and **retires** finished ones (EOS / max-new-tokens), so
   a long request never convoys short ones;
@@ -117,6 +123,7 @@ from distkeras_tpu.serving.cache import (
     decode_block_pages,
     fit_rows,
     rollback_rows,
+    sliding_prefill_rows,
 )
 from distkeras_tpu.serving.frontend import (
     GenerateRequest,
@@ -225,7 +232,9 @@ def serving_metrics(registry=None) -> dict:
         "kv_read": registry.counter(
             "serving_decode_kv_positions_read_total",
             help="cache positions the single-token steps' attention was told "
-                 "to cover: per active slot, pos + 1 rounded up to the block",
+                 "to cover in the paged layers: per active slot, pos + 1 "
+                 "rounded up to the block (a sliding-window layer's ring is "
+                 "the block's own to count)",
         ),
         "kv_capacity": registry.counter(
             "serving_decode_kv_positions_capacity_total",
@@ -235,6 +244,12 @@ def serving_metrics(registry=None) -> dict:
             "serving_state_per_position_bytes",
             help="bytes of paged state one position keeps over all layers "
                  "and kinds of state the served block declares",
+        ),
+        "slot_state_bytes": registry.gauge(
+            "serving_state_per_slot_bytes",
+            help="bytes of fixed state one slot keeps whatever its length: "
+                 "the rows of the kinds that a window bounds (sliding-window "
+                 "layers), over the layers that keep them",
         ),
         # the loop thread's account of its own time: one observation a span
         # of the same name in the flight-recorder ring (serving.loop*)
@@ -505,8 +520,10 @@ class ServingEngine:
     A block may bring counters of its own (``DecodeSpec.instruments`` /
     ``observe``: ``LatentMoELM``'s ``serving_moe_*``): its programs hand a
     few small arrays back beside the tokens, read one program behind with
-    them.  ``serving_state_per_position_bytes`` is the declared state's size
-    for any block.
+    them.  ``serving_state_per_position_bytes`` is the declared paged state's
+    size for any block, ``serving_state_per_slot_bytes`` the fixed state a
+    slot keeps whatever its length (the rings of sliding-window layers);
+    ``stats()`` has both.
     """
 
     def __init__(self, model, params=None, *, num_slots: int = 4,
@@ -542,6 +559,7 @@ class ServingEngine:
             num_layers=spec.num_layers, num_slots=num_slots,
             page_size=page_size, pages_per_slot=pages_per_slot,
             state=spec.state, num_pages=num_pages, dtype=dtype,
+            layer_kinds=spec.layer_kinds, sliding=spec.sliding,
         )
         if mesh is not None:
             from jax.sharding import NamedSharding
@@ -562,6 +580,7 @@ class ServingEngine:
         self._queue = RequestQueue(queue_size)
         self._metrics = serving_metrics(registry)
         self._metrics["state_bytes"].set(self._cache.bytes_per_position())
+        self._metrics["slot_state_bytes"].set(self._cache.bytes_per_slot())
         # the block's own counters (a DecodeSpec with ``instruments``): its
         # programs hand their small arrays back with the tokens
         self._observe = None
@@ -584,6 +603,11 @@ class ServingEngine:
             if self._spec_tokens < 1:
                 raise ValueError("spec_tokens must be >= 1")
             dspec = _resolve_spec(draft_model, draft_params)
+            if dspec.sliding:
+                raise ValueError(
+                    "the draft's block keeps sliding-window state, which its "
+                    "cache-only prefill cannot set up: pick a draft without "
+                    "(draft_model=)")
             if dspec.vocab_size != spec.vocab_size:
                 raise ValueError(
                     f"draft vocab {dspec.vocab_size} != target vocab "
@@ -752,17 +776,23 @@ class ServingEngine:
         ps = self._cache.page_size
         npages = width // ps
         counted = sample and spec.observe is not None
+        windows = dict(spec.sliding)
 
-        def trunk(params, pools, tokens, table, live):
+        def trunk(params, pools, tokens, table, live, length=None, slot=None):
             # tokens [1, width] right-padded; table [npages].
             positions = jnp.arange(width)[None]
             x = spec.embed(params, tokens, positions)
             aux = []
             for li in range(spec.num_layers):
                 def write(name, rows, li=li):
+                    pool = pools[name][li]
+                    if name in windows:
+                        # the prompt's last rows into the slot's ring
+                        pools[name][li] = sliding_prefill_rows(
+                            pool, slot, rows, length)
+                        return
                     # the whole padded chunk into this slot's pages; rows
                     # past the prompt land on scratch/overwritten pages
-                    pool = pools[name][li]
                     pools[name][li] = pool.at[table].set(
                         fit_rows(rows.reshape(npages, ps, -1), pool))
 
@@ -786,7 +816,7 @@ class ServingEngine:
             pools, (tokens, table, length, key, temp, top_k, top_p, last,
                     keys, slot) = _take_pools(spec, args)
             x, aux = trunk(params, pools, tokens, table,
-                           jnp.arange(width)[None] < length)
+                           jnp.arange(width)[None] < length, length, slot)
             row = spec.head(params, x, at=length - 1)
             key, sub = jax.random.split(key)
             tok = sample_one(row, sub, temp, top_k, top_p)
@@ -808,7 +838,6 @@ class ServingEngine:
         the pools its outputs are the tokens, ``pos`` advanced for the
         active slots and the keys: the next step's ``last``, ``pos`` and
         ``keys``."""
-        names = [n for n, _ in spec.state]
 
         def step(params, *args):
             pools, (tables, pos, last, keys, temp, top_k, top_p,
@@ -819,6 +848,7 @@ class ServingEngine:
             x = spec.embed(params, last[:, None], pos[:, None])
             aux = []
             for li in range(spec.num_layers):
+                names = spec.kinds(li)
                 layer, x, extra = spec.step(
                     params, li, x, {n: pools[n][li] for n in names}, tables,
                     pos, active[:, None])
@@ -1001,6 +1031,9 @@ class ServingEngine:
             "pages_in_use": float(self._cache.pages_in_use),
             "pages_free": float(self._cache.pages_free),
             "slots_total": float(self.num_slots),
+            "state_per_position_bytes": float(
+                self._cache.bytes_per_position()),
+            "state_per_slot_bytes": float(self._cache.bytes_per_slot()),
         }
 
     @property
@@ -1108,9 +1141,10 @@ class ServingEngine:
             new = self._sharded(new)
         with self._cv:
             old = self._spec
-        if new.geometry != old.geometry or new.state != old.state:
+        if new.geometry != old.geometry or new.layout != old.layout:
             differ = [f"{a} != {b}" for a, b in zip(
-                new.geometry + new.state, old.geometry + old.state) if a != b]
+                new.geometry + new.layout, old.geometry + old.layout)
+                if a != b]
             raise ValueError(
                 "hot_swap geometry mismatch: "
                 + (", ".join(differ) or "another kind of block"))

@@ -256,3 +256,101 @@ def test_the_sampling_keeps_its_sort_inside_a_branch(one_chip):
                               for name in branches.split(",")]
     assert "sort" in ops(sorted_) and "reduce-window" in ops(sorted_)
     assert not {"sort", "reduce-window"} & set(ops(greedy) + ops(plain))
+
+
+# ------------------------- sliding-window and global layers, pages and rings
+
+
+def _window_cut(one_chip, pattern, moe_layer_freq):
+    """The sliding-window expert cell's block cut to the layers of
+    ``pattern`` at the published widths (16 held experts, 19,072 rows of the
+    vocabulary), with its weights' shapes on the described chip."""
+    from distkeras_tpu.models import WindowMoELM
+
+    model = WindowMoELM(vocab_size=19072, max_len=13312,
+                        num_hidden_layers=len(pattern),
+                        hybrid_layer_pattern=pattern,
+                        moe_layer_freq=moe_layer_freq, held_experts=(0, 16))
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda key: model.init(key, jnp.bfloat16),
+                       jax.random.PRNGKey(0)))
+    return model, params
+
+
+def test_a_global_and_a_sliding_layers_step_copies_no_store(one_chip):
+    """The sliding-window cell's step through a global and a sliding expert
+    layer at its real shapes (32 slots, 832 pages of 16 a slot, 4 and 8
+    key-value heads of 192 and 128, a ring of 128 rows a slot, 16 held
+    experts of 4096 x 2048, bfloat16): the grouped-query read of the pages
+    and the ring's read with the sinks in one program.  No page pool, ring
+    or stacked expert weights are copied, and the temporaries stay far
+    under one page pool."""
+    import re
+
+    from distkeras_tpu.serving.cache import pool_width
+
+    model, params = _window_cut(one_chip, [0, 1], [1, 1])
+    spec = model.decode_spec(None)
+    assert spec.state == (("k", 768), ("v", 512), ("sliding_k", 1536),
+                          ("sliding_v", 1024))
+    slots, pages, page = 32, 832, 16
+    struct = lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    shapes = {"k": (slots * pages + 1, page, 768),
+              "v": (slots * pages + 1, page, 512),
+              "sliding_k": (slots + 1, 128, pool_width(1536)),
+              "sliding_v": (slots + 1, 128, pool_width(1024))}
+    stores = {name: struct(shape, jnp.bfloat16)
+              for name, shape in shapes.items()}
+
+    def step(params, stores, x, tables, pos, live):
+        stores = dict(stores)
+        for li in range(2):
+            kinds = spec.kinds(li)
+            layer, x, _ = spec.step(params, li, x,
+                                    {n: stores[n] for n in kinds}, tables,
+                                    pos, live)
+            stores.update(layer)
+        return stores, x
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, stores, struct((slots, 1, 4096), jnp.float32),
+        struct((slots, pages), jnp.int32), struct((slots,), jnp.int32),
+        struct((slots, 1), jnp.bool_)).compile()
+    text = compiled.as_text()
+    big = ["bf16[%d,%d,%d]" % shape for shape in shapes.values()] + [
+        "bf16[16,4096,2048]", "bf16[16,2048,4096]"]
+    copied = [line for line in text.splitlines()
+              if " copy(" in line and any(shape in line for shape in big)]
+    assert not copied, copied[:3]
+    for shape in big[:4]:  # row-major, everywhere a store appears
+        assert set(re.findall(re.escape(shape) + r"\{([\d,]+)", text)) <= {
+            "2,1,0"}, shape
+    one_pool = 2 * (slots * pages + 1) * page * 768
+    assert compiled.memory_analysis().temp_size_in_bytes < one_pool // 8
+
+
+def test_a_12288_wide_prefill_holds_no_whole_score_matrix(one_chip):
+    """A 12,288-wide prefill of the global dense layer at the published
+    widths, the layer whose queries see the most keys: its blocks of 512
+    queries, each projected, attended and carried out through ``W_o`` on its
+    own, keep the temporaries under 2 GB (its dense float32 scores, ``[64,
+    W, W]``, would be 38.7 GB).  (The whole seven-layer prefill compiles to
+    3.4 GB: PERF.md, section 4.)"""
+    model, params = _window_cut(one_chip, [0], [0])
+    spec = model.decode_spec(None)
+    width = 12288
+    struct = lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+
+    def prefill(params, x, live):
+        rows = []
+        x, _ = spec.prefill(params, 0, x, jnp.arange(width)[None],
+                            lambda name, r: rows.append(r), live)
+        return x, rows
+
+    compiled = jax.jit(prefill).lower(
+        params, struct((1, width, 4096), jnp.float32),
+        struct((1, width), jnp.bool_)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 10**9
