@@ -63,11 +63,16 @@ garbage there instead of corrupting live pages.  Attention masks by position
 
 What a decode step reads: :func:`paged_decode_attention` (keys and values,
 multi-head or grouped-query) and :func:`paged_latent_attention` (a latent
-row) write the step's rows
-through the table and then walk each slot's pages a block at a time, as far
-as the longest live slot reaches (``pos``), with a running maximum,
-denominator and weighted sum — never a slot's whole window at once, and
-nothing of the pages past the live length.
+row) write the step's rows through the table and then walk each slot's
+pages a block at a time with a running maximum, denominator and weighted
+sum — never a slot's whole window at once, and nothing of the pages past
+the live length.  On a TPU, for pools whose shapes it takes, the keys and
+values are read by one Pallas kernel (:mod:`distkeras_tpu.ops.pallas.
+paged_attention`) that copies each slot's own pages through its table and
+stops after the block that holds that slot's ``pos``: no gather of every
+slot, no re-layout by head (PERF.md).  Elsewhere, and for the
+latent row, XLA gathers every slot's block through the tables as far as
+the longest live slot reaches.
 
 **The two latent-attention paths.**  A prefill chunk runs the *expanded*
 form in the block itself (per-head keys and values made from the chunk's
@@ -84,6 +89,8 @@ nothing there is traced.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -171,19 +178,20 @@ def rollback_rows(pool, tables, pos, count, m):
 
 def decode_block_pages(page_size: int, pages_per_slot: int) -> int:
     """Pages that :func:`paged_decode_attention` reads at a time: some 128
-    positions (a block of ``[slots, 128, width]`` is a few megabytes: large
-    enough to run at the memory's rate, small enough that a slot shorter
-    than the longest wastes little), never more than a slot has."""
+    positions (large enough to run at the memory's rate, small enough that
+    a slot's last block wastes little), never more than a slot has."""
     return max(1, min(pages_per_slot, 128 // page_size))
 
 
 def _attend_live_blocks(block, weigh, pos, span):
-    """The online softmax over the live blocks, shared by the two single-token
-    attentions.  ``block(j)`` gives block ``j``'s masked float32 scores
-    ``[slots, heads, span]`` and its values; ``weigh(p, values)`` their
-    weighted sum under the block's weights ``p``.  Returns the denominator
-    ``[slots, heads]`` and the weighted sum, both float32, after the block
-    that holds the longest slot's ``pos``."""
+    """The online softmax over the live blocks of XLA's reads (the latent
+    row's, and the keys' and values' where the Pallas kernel does not run):
+    ``block(j)`` gives block ``j``'s masked float32 scores ``[slots, heads,
+    span]`` and its values, gathered for every slot; ``weigh(p, values)``
+    their weighted sum under the block's weights ``p``.  Returns the
+    denominator ``[slots, heads]`` and the weighted sum, both float32, after
+    the block that holds the longest slot's ``pos``: a shorter slot's
+    blocks past its own are read and masked."""
     # block 0 outside the loop: the running state then starts from data (a
     # finite maximum, and under shard_map the heads' varying type), not from
     # constants that the loop would have to reconcile with what it carries
@@ -238,25 +246,60 @@ def paged_decode_attention(kpool, vpool, tables, pos, q, k, v):
     ``1 / sqrt(head_dim)``.  Returns ``(kpool, vpool, out [slots, 1, heads,
     v_dim])``.
 
-    The read goes block by block (:func:`decode_block_pages` pages of every
-    slot at a time, gathered through the table) with an online softmax in
-    float32 — running maximum, denominator and weighted sum — and stops
-    after the block that holds the longest slot's ``pos``: pages past the
-    live length are never touched, and ``[slots, context, heads, head_dim]``
-    is never built.  Within a block the per-slot mask ``key_pos <= pos``
-    hides what a shorter slot has not written (or the scratch page that an
-    inactive slot's table points at).  Block 0 always holds position 0, so
-    every slot has a finite maximum before a fully masked block can meet it.
+    The read goes block by block (:func:`decode_block_pages` pages at a
+    time) with an online softmax in float32 — running maximum, denominator
+    and weighted sum — so pages past the live length are never touched,
+    and ``[slots, context, heads, head_dim]`` is never built.  Within a
+    block the per-slot mask ``key_pos <= pos`` hides what a slot has not
+    written (or the scratch page that an inactive slot's table points at).
+    Block 0 always holds position 0, so every slot has a finite maximum
+    before a fully masked block can meet it.
+
+    Two paths read the same blocks, chosen once a compiled step from what
+    the arrays show.  Lowered for a TPU, where the pools' type, widths and
+    page are ones the kernel takes (:func:`~distkeras_tpu.ops.pallas.
+    paged_attention_fits`: float32 or bfloat16, rows of whole lanes, pages
+    of whole tiles), one Pallas kernel
+    (:func:`~distkeras_tpu.ops.pallas.paged_attention`) copies each slot's
+    own pages through its table and stops after the block that holds that
+    slot's ``pos``, its products as precise as the other path's.
+    Everywhere else (the CPU, any other shape) the blocks of every slot are
+    gathered through the tables as far as the longest slot reaches
+    (:func:`_gathered_read`).
     """
-    slots, kv_heads, group, head_dim, v_dim = _grouped(q, k, v)
-    heads = kv_heads * group
-    page_size = kpool.shape[1]
-    pages_per_slot = tables.shape[1]
+    from distkeras_tpu.ops.pallas import paged_attention, paged_attention_fits
+
+    _, kv_heads, _, head_dim, v_dim = _grouped(q, k, v)
+    bp = decode_block_pages(kpool.shape[1], tables.shape[1])
     kpool = append_rows(kpool, tables, pos, k)
     vpool = append_rows(vpool, tables, pos, v)
+    gathered = functools.partial(_gathered_read, block_pages=bp,
+                                 kv_heads=kv_heads, v_dim=v_dim)
+    if paged_attention_fits(kpool, vpool, q[:, 0], bp):
+        kernel = lambda kpool, vpool, tables, pos, q: paged_attention(
+            kpool, vpool, tables, pos, q[:, 0], block_pages=bp,
+            interpret=False)[:, None]
+        out = jax.lax.platform_dependent(kpool, vpool, tables, pos, q,
+                                         tpu=kernel, default=gathered)
+    else:
+        out = gathered(kpool, vpool, tables, pos, q)
+    return kpool, vpool, out
 
-    bp = decode_block_pages(page_size, pages_per_slot)
-    span = bp * page_size  # positions a block
+
+@functools.partial(jax.jit,
+                   static_argnames=("block_pages", "kv_heads", "v_dim"))
+def _gathered_read(kpool, vpool, tables, pos, q, *, block_pages, kv_heads,
+                   v_dim):
+    """:func:`paged_decode_attention`'s read in XLA's own operations: each
+    block, ``block_pages`` pages of every slot gathered through the tables,
+    re-laid out head by head and taken into two products, until the block
+    that holds the longest slot's ``pos``.  Returns ``[slots, 1, heads,
+    v_dim]`` in ``q``'s dtype."""
+    slots, _, heads, head_dim = q.shape
+    group = heads // kv_heads
+    pages_per_slot = tables.shape[1]
+    bp = block_pages
+    span = bp * kpool.shape[1]  # positions a block
     nblocks = -(-pages_per_slot // bp)
     # a last, partial block reads the scratch page for the rows it lacks
     padded = jnp.pad(tables, ((0, 0), (0, nblocks * bp - pages_per_slot)))
@@ -280,8 +323,7 @@ def paged_decode_attention(kpool, vpool, tables, pos, q, k, v):
             jnp.float32)).reshape(slots, heads, v_dim)
 
     l, acc = _attend_live_blocks(block, weigh, pos, span)
-    out = (acc / l[..., None]).astype(q.dtype)
-    return kpool, vpool, out[:, None]
+    return (acc / l[..., None]).astype(q.dtype)[:, None]
 
 
 def sliding_prefill_rows(store, slot, rows, length):

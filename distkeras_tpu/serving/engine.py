@@ -29,10 +29,12 @@ continuous-batching formulation (Orca/vLLM):
   row_width]`` array a layer, donated to every program and written in
   place), per-slot page tables, pages allocated at admission and freed at
   retirement.  A single-token step writes one row a slot and layer and
-  reads the pages block by block as far as the longest live slot reaches
+  reads the pages block by block, on a TPU each slot as far as it reaches
   (:func:`~distkeras_tpu.serving.cache.paged_decode_attention` for keys and
-  values, :func:`~distkeras_tpu.serving.cache.paged_latent_attention` for a
-  latent row): its cost follows what the slots hold, not what they could.
+  values, one Pallas kernel there), every slot as far as the longest live
+  one reaches elsewhere and for a latent row
+  (:func:`~distkeras_tpu.serving.cache.paged_latent_attention`): its cost
+  follows what the slots hold, not what they could.
   A kind that a window bounds (``DecodeSpec.sliding``) lives in a ring of
   ``window`` rows a slot instead, set up whole by the admission's prefill
   and read whole by the step
@@ -485,12 +487,15 @@ class ServingEngine:
 
     What a step reads: the decode step (and the draft's) never gathers a
     slot's whole window.  In each layer it writes the step's rows (K and V,
-    or the one latent row) through the page table in place and attends over blocks of some 128
-    positions with an online softmax, stopping after the block that holds
-    the longest live slot's position; shorter slots are masked inside the
-    block.  ``serving_decode_kv_positions_read_total`` over
-    ``..._capacity_total`` says how much of the slots' capacity the steps
-    were told to cover.  The verify step (``spec_tokens`` rows a slot) and
+    or the one latent row) through the page table in place and attends over
+    blocks of some 128 positions with an online softmax.  On a TPU the keys
+    and values of pools the Pallas kernel takes are read slot by slot, each
+    up to the block that holds its own position
+    (``serving.cache.paged_decode_attention``); elsewhere, and for a latent
+    row, every slot is read up to the block of the longest live slot's
+    position and masked inside it.
+    ``serving_decode_kv_positions_read_total`` over ``..._capacity_total``
+    counts each active slot's own blocks against the slots' capacity.  The verify step (``spec_tokens`` rows a slot) and
     the prefill keep a dense attention of their own width.
 
     Fast-path knobs: ``prefill_buckets`` (width ladder; default
@@ -834,7 +839,7 @@ class ServingEngine:
         (``decode``) or the draft (``draft_step``, which also returns the
         *modified* distribution it sampled from, the q of the acceptance
         test).  Every layer is the block's own ``step``: the step's rows are
-        written in place and the read stops at the longest live slot.  After
+        written in place and read with the pages that each slot holds.  After
         the pools its outputs are the tokens, ``pos`` advanced for the
         active slots and the keys: the next step's ``last``, ``pos`` and
         ``keys``."""

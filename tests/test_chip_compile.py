@@ -22,7 +22,7 @@ from distkeras_tpu.ops.pallas import flash_attention
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     import os
 
     from jax.experimental import topologies
@@ -39,9 +39,14 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _kernel(q, k, v):
@@ -354,3 +359,93 @@ def test_a_12288_wide_prefill_holds_no_whole_score_matrix(one_chip):
         params, struct((1, width, 4096), jnp.float32),
         struct((1, width), jnp.bool_)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 10**9
+
+
+# ------------------------------------ the paged read: one kernel, pools in place
+
+
+def _paged_step_text(fn, kpool, vpool, tables, pos, q, k, v):
+    compiled = jax.jit(fn, donate_argnums=(0, 1)).lower(
+        kpool, vpool, tables, pos, q, k, v).compile()
+    return compiled.as_text()
+
+
+def _reads_the_pools_as_they_lie(text, pools, slots, widths):
+    """One ``tpu_custom_call``, the kernel's; no gathered block of every
+    slot (``[slots, 8 pages, 16, width]`` or ``[slots, 128, width]``); each
+    pool row-major everywhere it appears and never copied."""
+    import re
+
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert re.search(r"%paged_decode_attention[.\d]* = ", text)
+    for width in widths:
+        for block in (f"[{slots},8,16,{width}]", f"[{slots},128,{width}]"):
+            assert block not in text, block
+    for pool in pools:
+        assert set(re.findall(re.escape(pool) + r"\{([\d,]+)", text)) == {
+            "2,1,0"}, pool
+        assert not [line for line in text.splitlines()
+                    if " copy(" in line and pool in line]
+
+
+@pytest.mark.parametrize("slots,pages,widths,heads,head_dim,dtype", [
+    # the GPT-2 cell: 24 slots of 64 pages, 12 heads of 64, float32 pools
+    (24, 64, (768, 768), 12, 64, jnp.float32),
+    # the MiMo cell's global layer: 32 slots of 832 pages, 4 key-value
+    # heads of 192 / 128 serving 16 query heads each, bfloat16 pools
+    (32, 832, (768, 512), 64, 192, jnp.bfloat16),
+])
+def test_the_paged_read_is_one_kernel_over_the_pools_as_they_lie(
+        one_chip, slots, pages, widths, heads, head_dim, dtype):
+    """``paged_decode_attention`` lowered for the chip at the cells' shapes
+    takes the Pallas kernel (the CPU takes the gathered read: the same
+    function's lowering in ``test_serving_paged_attention.py`` holds its
+    loop), and the kernel reads each pool where the step's row was written
+    into it: in place, donated through the program (PERF.md)."""
+    from distkeras_tpu.serving.cache import paged_decode_attention
+
+    struct = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                    sharding=one_chip)
+    kv_heads = widths[0] // head_dim
+    v_dim = widths[1] // kv_heads
+    shapes = [(slots * pages + 1, 16, w) for w in widths]
+    text = _paged_step_text(
+        paged_decode_attention, *(struct(s, dtype) for s in shapes),
+        struct((slots, pages), jnp.int32), struct((slots,), jnp.int32),
+        struct((slots, 1, heads, head_dim), jnp.float32),
+        struct((slots, 1, kv_heads, head_dim), jnp.float32),
+        struct((slots, 1, kv_heads, v_dim), jnp.float32))
+    name = "f32" if dtype == jnp.float32 else "bf16"
+    _reads_the_pools_as_they_lie(
+        text, ["%s[%d,%d,%d]" % (name, *s) for s in set(shapes)], slots,
+        widths)
+    assert "input_output_alias={ {0}: (0, {}, may-alias), {1}: (1, {}" in text
+    assert " while(" not in text  # no loop of XLA's over blocks
+
+
+def test_the_paged_read_is_one_kernel_under_the_tensor_parallel_mesh(topo):
+    """As the engine's tensor-parallel build calls it: the GPT-2 cell's pools
+    and heads split over a one-axis ``shard_map`` of 2 described chips
+    (``check_vma``), each chip's kernel over its own 384-wide pools."""
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from distkeras_tpu.serving.cache import paged_decode_attention
+    from distkeras_tpu.utils import compat
+
+    mesh = Mesh(topo.devices[:2], ("model",))
+    pool, heads = P(None, None, "model"), P(None, None, "model", None)
+    fn = compat.shard_map(
+        paged_decode_attention, mesh,
+        in_specs=(pool, pool, P(), P(), heads, heads, heads),
+        out_specs=(pool, pool, heads), check_vma=True)
+    struct = lambda shape, dt, spec: jax.ShapeDtypeStruct(
+        shape, dt, sharding=NamedSharding(mesh, spec))
+    rows = struct((24, 1, 12, 64), jnp.float32, heads)
+    text = _paged_step_text(
+        fn, struct((1537, 16, 768), jnp.float32, pool),
+        struct((1537, 16, 768), jnp.float32, pool),
+        struct((24, 64), jnp.int32, P()), struct((24,), jnp.int32, P()),
+        rows, rows, rows)
+    _reads_the_pools_as_they_lie(text, ["f32[1537,16,384]"], 24, (384,))
+
