@@ -20,6 +20,7 @@ from distkeras_tpu.models.latent_moe import LatentMoELM
 from distkeras_tpu.models.scmoe import ShortcutMoELM
 from distkeras_tpu.models.staged import StagedLM, StagedTransformer
 from distkeras_tpu.models.window_moe import WindowMoELM
+from distkeras_tpu.models.jamba import JambaLM
 from distkeras_tpu.models.transformer import (
     TransformerClassifier,
     TransformerEncoderBlock,
@@ -44,6 +45,7 @@ __all__ = [
     "LatentMoELM",
     "ShortcutMoELM",
     "WindowMoELM",
+    "JambaLM",
     "StagedTransformer",
     "StagedLM",
     "greedy_generate",

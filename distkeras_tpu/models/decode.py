@@ -3,7 +3,7 @@
 :class:`~distkeras_tpu.serving.engine.ServingEngine` keeps slots, pages,
 programs, sampling and the loop; it knows nothing of what a layer computes.
 A model's ``decode_spec(params)`` hook returns a :class:`DecodeSpec`: what
-state a layer keeps a position (the pools that
+state a layer keeps, a position, a window or a slot (the stores that
 :class:`~distkeras_tpu.serving.cache.PagedKVCache` builds), and the model's
 own embedding, prefill layer, single-token step layer and head as functions
 the engine's programs call.  All functions are pure and are traced inside
@@ -43,8 +43,10 @@ class DecodeSpec:
         576))`` for a double layer of two latent attentions: two pools of the
         same kind, each written and read by its own sub-block within one
         ``prefill`` or ``step`` call (``num_layers`` then counts the double
-        layers).  A kind is paged a position (one pool a layer, through the
-        slots' page tables) unless ``sliding`` bounds it.
+        layers).  A kind is one of three: **paged** a position (one pool a
+        layer, through the slots' page tables), unless ``sliding`` bounds it
+        by a window (a ring of rows a slot) or ``recurrent`` makes it one
+        state a slot.
     ``layer_kinds``
         Optional: the names of the kinds each layer keeps, a tuple a layer
         (``(("k", "v"), ("sliding_k", "sliding_v"), ...)`` for a block of
@@ -64,6 +66,22 @@ class DecodeSpec:
         row in the batch; a slot that is not ``live`` writes to the scratch
         slot).  (Not the ``window`` function below, which is the speculative
         verify.)
+    ``recurrent``
+        Optional: ``(name, rows)`` of each kind that is a **recurrent
+        state**: one fixed-size state a slot and layer, whatever the
+        context's length (a Mamba layer's scan state, ``rows`` 16 of 5,120,
+        or its convolution's last inputs, 3 rows).  Such a kind is neither
+        paged nor a ring: it lives in a store ``[slots + 1, rows,
+        row_width]`` whose last slot is scratch.  ``prefill``'s ``write`` of
+        such a kind hands the state after the prompt's last position,
+        ``[rows, row_width]``, and it replaces the slot's state whole (so
+        nothing of the slot's previous request survives its admission);
+        ``step`` gets the whole store and replaces the state of each
+        ``live`` slot itself, leaving the others as they were.
+    ``dtypes``
+        Optional: ``(name, dtype)`` of each kind whose store has a type of
+        its own (a float32 scan state beside bfloat16 pages); every other
+        kind takes the engine's ``dtype``.
     ``weights``
         The pytree every program takes as its first argument (``params()``).
     ``num_layers``, ``max_len``, ``vocab_size``
@@ -82,7 +100,7 @@ class DecodeSpec:
     ``step(weights, layer, x, pools, tables, pos, live) -> (pools, x, aux)``
         One layer, one token a slot: ``x [slots, 1, dim]``, ``pools`` a dict
         ``name -> [pages, page_size, row_width]`` of this layer's kinds (a
-        sliding kind's store as above), ``tables
+        sliding or recurrent kind's store as above), ``tables
         [slots, pages_per_slot]``, ``pos [slots]``.  Writes the step's rows
         at ``pos`` and attends over positions ``0 .. pos``.  ``live [slots,
         1]`` marks the slots that hold a request.
@@ -126,6 +144,8 @@ class DecodeSpec:
     observe: Optional[Callable] = None
     layer_kinds: Optional[Tuple[Tuple[str, ...], ...]] = None
     sliding: Tuple[Tuple[str, int], ...] = ()
+    recurrent: Tuple[Tuple[str, int], ...] = ()
+    dtypes: Tuple[Tuple[str, Any], ...] = ()
 
     def params(self):
         """The pytree passed (not closed over) to the jitted programs, so
@@ -141,8 +161,10 @@ class DecodeSpec:
     @property
     def layout(self) -> Tuple:
         """What two specs must share for their programs to take the same
-        pools: the kinds, which layer keeps which, and their windows."""
-        return (self.state, self.layer_kinds, self.sliding)
+        pools: the kinds, which layer keeps which, their windows, their
+        recurrent states and their types."""
+        return (self.state, self.layer_kinds, self.sliding, self.recurrent,
+                tuple((name, str(jnp.dtype(dt))) for name, dt in self.dtypes))
 
 
 # ------------------------------------------------- the GPT-2-shaped block

@@ -6,11 +6,12 @@ On non-TPU backends the kernels can run under the Pallas interpreter
 references.  Their callers take the kernels on a TPU alone: the training
 attention's dispatcher (``distkeras_tpu.parallel.ring.attention``) routes
 non-TPU backends to the jnp path, and the serving step's paged read
-(``serving.cache.paged_decode_attention``) picks the kernel when it is
-lowered for a TPU at shapes the kernel takes, so the CPU device-mesh
-integration tests do NOT exercise these kernels — only the dedicated kernel
-tests (and the compiles for a described chip in ``tests/test_chip_compile.py``)
-do.
+(``serving.cache.paged_decode_attention``) and the Mamba layers' selective
+scan (``selective_scan.selective_scan`` / ``.selective_step``) pick their
+kernels when they are lowered for a TPU at shapes the kernels take, so the
+CPU device-mesh integration tests do NOT exercise these kernels — only the
+dedicated kernel tests (and the compiles for a described chip in
+``tests/test_chip_compile.py``) do.
 """
 
 from distkeras_tpu.ops.pallas.flash_attention import flash_attention
@@ -18,5 +19,7 @@ from distkeras_tpu.ops.pallas.paged_attention import (
     paged_attention,
     paged_attention_fits,
 )
+from distkeras_tpu.ops.pallas.selective_scan import ssm_scan, ssm_step
 
-__all__ = ["flash_attention", "paged_attention", "paged_attention_fits"]
+__all__ = ["flash_attention", "paged_attention", "paged_attention_fits",
+           "ssm_scan", "ssm_step"]

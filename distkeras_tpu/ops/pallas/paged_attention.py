@@ -22,8 +22,10 @@ What a block body hands the units:
   product of the weights ``[heads, span]`` against the value block, of which
   each head keeps its own key-value head's lanes.  Multi-head attention is
   the case of one query head a key-value head; grouped-query attention
-  shares a block among ``group`` query heads.  The zeros cost columns of
-  the products, not bytes.
+  shares a block among ``group`` query heads, whose rows are padded to whole
+  tiles of 8 (20 heads over one key-value head take 24 rows; the padding
+  rows are zeros, and dropped from the output).  The zeros cost columns and
+  rows of the products, not bytes.
 * **The precision of the read it replaces.**  The products' operands go
   to the MXU in the pools' type with float32 accumulation: bfloat16 pools
   at the default precision (the query and the weights rounded to bfloat16,
@@ -62,9 +64,10 @@ def paged_attention_fits(kpool, vpool, q, block_pages) -> bool:
     """Does the compiled kernel take these arrays?  Only what the shapes and
     types say: pools of float32 or bfloat16 whose rows are the heads side by
     side at a whole number of the 128 lanes, a page of whole tiles of rows,
-    a block of whole lanes of positions, and a number of query heads a
-    key-value head that is 1 or whole tiles of rows (the output keeps each
-    group's rows apart).  Arguments as :func:`paged_attention` takes them."""
+    a block of whole lanes of positions, and query heads that the key-value
+    heads divide (any number a key-value head: the kernel pads a group to
+    whole tiles of rows).  Arguments as :func:`paged_attention` takes
+    them."""
     dtype = jnp.dtype(kpool.dtype)
     heads, head_dim = q.shape[-2:]
     width, v_width = kpool.shape[-1], vpool.shape[-1]
@@ -76,8 +79,7 @@ def paged_attention_fits(kpool, vpool, q, block_pages) -> bool:
     span = page * block_pages
     group = heads // kv_heads
     return (heads == kv_heads * group and v_width % kv_heads == 0
-            and page % _SUBLANES[dtype] == 0 and span % _LANES == 0
-            and (group == 1 or group % 8 == 0))
+            and page % _SUBLANES[dtype] == 0 and span % _LANES == 0)
 
 
 class _Geometry(NamedTuple):
@@ -86,6 +88,7 @@ class _Geometry(NamedTuple):
     rows: int            # query heads padded to whole tiles of rows
     kv_heads: int
     group: int           # query heads a key-value head
+    group_rows: int      # rows a group takes: 1, or whole tiles of 8
     v_dim: int
     page: int            # positions a page
     block_pages: int
@@ -178,15 +181,16 @@ def _kernel(pos_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
     base_ref[0] = (base + blocks) % 2
 
     out = acc_ref[...] / pltpu.repeat(l_ref[...], acc_ref.shape[1] // _LANES, 1)
-    # row h keeps the lanes of its own key-value head; the padding rows none
-    head = jax.lax.broadcasted_iota(jnp.int32, out.shape, 0)
+    # row r keeps the lanes of its own key-value head; the padding rows none
+    row = jax.lax.broadcasted_iota(jnp.int32, out.shape, 0)
     lane = jax.lax.broadcasted_iota(jnp.int32, out.shape, 1)
-    out = jnp.where((lane // g.v_dim == head // g.group) & (head < g.heads),
-                    out, 0.0)
+    owner = row // g.group_rows
+    out = jnp.where((lane // g.v_dim == owner) & (owner < g.kv_heads)
+                    & (row % g.group_rows < g.group), out, 0.0)
     if g.group == 1:  # one row: head h's lanes are its only non-zeros
         out = jnp.sum(out, axis=0, keepdims=True)
     else:  # row r of the group gathers its query head of every key-value head
-        out = sum(out[i * g.group:(i + 1) * g.group]
+        out = sum(out[i * g.group_rows:(i + 1) * g.group_rows]
                   for i in range(g.kv_heads))
     o_ref[0] = out.astype(o_ref.dtype)
 
@@ -221,17 +225,24 @@ def paged_attention(kpool, vpool, tables, pos, q, *, block_pages,
     page = kpool.shape[1]
     kv_heads = kpool.shape[2] // head_dim
     v_width = vpool.shape[2]
-    rows = -(-heads // 16) * 16
-    g = _Geometry(heads=heads, rows=rows, kv_heads=kv_heads,
-                  group=heads // kv_heads, v_dim=v_width // kv_heads,
+    group = heads // kv_heads
+    group_rows = 1 if group == 1 else -(-group // 8) * 8
+    rows = -(-(kv_heads * group_rows) // 16) * 16
+    g = _Geometry(heads=heads, rows=rows, kv_heads=kv_heads, group=group,
+                  group_rows=group_rows, v_dim=v_width // kv_heads,
                   page=page, block_pages=block_pages,
                   span=block_pages * page, pages_per_slot=pages_per_slot,
                   scale=1.0 / math.sqrt(head_dim))
-    # the block-diagonal query: row h, lanes of key-value head h // group
-    owner = jnp.arange(heads)[:, None] // g.group == jnp.arange(kv_heads)
+    # the block-diagonal query: query head h in row (h // group) x
+    # group_rows + h % group, in the lanes of key-value head h // group; a
+    # group's padding rows are zeros
+    owner = jnp.arange(heads)[:, None] // group == jnp.arange(kv_heads)
     qbd = (q[:, :, None, :] * owner[None, :, :, None].astype(q.dtype))
-    qbd = jnp.pad(qbd.reshape(slots, heads, kv_heads * head_dim),
-                  ((0, 0), (0, rows - heads), (0, 0))).astype(kpool.dtype)
+    qbd = qbd.reshape(slots, kv_heads, group, kv_heads * head_dim)
+    qbd = jnp.pad(qbd, ((0, 0), (0, 0), (0, group_rows - group), (0, 0)))
+    qbd = jnp.pad(qbd.reshape(slots, kv_heads * group_rows, -1),
+                  ((0, 0), (0, rows - kv_heads * group_rows), (0, 0))
+                  ).astype(kpool.dtype)
 
     slot_block = lambda shape: pl.BlockSpec(
         shape, lambda s, *_: (s, 0, 0), memory_space=pltpu.VMEM)
@@ -243,7 +254,7 @@ def paged_attention(kpool, vpool, tables, pos, q, *, block_pages,
             in_specs=[slot_block((1, rows, kpool.shape[2])),
                       pl.BlockSpec(memory_space=pl.ANY),
                       pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=slot_block((1, g.group, v_width)),
+            out_specs=slot_block((1, g.group_rows, v_width)),
             scratch_shapes=[
                 pltpu.VMEM((2, g.span, kpool.shape[2]), kpool.dtype),
                 pltpu.VMEM((2, g.span, v_width), vpool.dtype),
@@ -255,7 +266,7 @@ def paged_attention(kpool, vpool, tables, pos, q, *, block_pages,
             ]),
         # under shard_map the output varies along whatever its inputs vary on
         out_shape=jax.ShapeDtypeStruct(
-            (slots, g.group, v_width), q.dtype,
+            (slots, g.group_rows, v_width), q.dtype,
             vma=frozenset().union(*(jax.typeof(x).vma for x in (
                 kpool, vpool, tables, pos, q)))),
         # the slots in order: a slot starts copies of the next ones' blocks
@@ -265,6 +276,6 @@ def paged_attention(kpool, vpool, tables, pos, q, *, block_pages,
         name="paged_decode_attention",
     )(pos.astype(jnp.int32), tables.astype(jnp.int32).reshape(-1), qbd,
       kpool, vpool)
-    # [slots, group, kv_heads * v_dim] -> [slots, heads, v_dim]
-    out = out.reshape(slots, g.group, kv_heads, g.v_dim)
+    # [slots, group_rows, kv_heads * v_dim] -> [slots, heads, v_dim]
+    out = out[:, :group].reshape(slots, group, kv_heads, g.v_dim)
     return jnp.swapaxes(out, 1, 2).reshape(slots, heads, g.v_dim)

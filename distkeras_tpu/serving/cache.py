@@ -28,21 +28,33 @@ all addressed by the same tables:
 
 A block may keep different kinds in different layers
 (``DecodeSpec.layer_kinds``: a layer's entry of a kind it does not keep is
-None), and a kind may be **bounded by a window** (``DecodeSpec.sliding``): a
-sliding-window layer sees a slot's last ``window`` positions alone, so its
-rows live in a per-slot store of ``window`` rows, a ring indexed by
-``position % window``, and not in pages: its size does not grow with the
-context (``WindowMoELM``'s sliding layers: 128 rows a slot, where their
-global layers page up to 13,312).  The prefill sets a slot's ring up at
-admission (:func:`sliding_prefill_rows`), the step writes one row in place
-and reads the ring (:func:`sliding_decode_attention`).  What is paged counts
-a position (:meth:`PagedKVCache.bytes_per_position`), what is stored a slot
-(:meth:`PagedKVCache.bytes_per_slot`).
+None), and a kind is one of three:
+
+* **paged** (the default): a row a position, through the slots' tables;
+* **bounded by a window** (``DecodeSpec.sliding``): a sliding-window layer
+  sees a slot's last ``window`` positions alone, so its rows live in a
+  per-slot store of ``window`` rows, a ring indexed by ``position % window``,
+  and not in pages: its size does not grow with the context
+  (``WindowMoELM``'s sliding layers: 128 rows a slot, where their global
+  layers page up to 13,312).  The prefill sets a slot's ring up at admission
+  (:func:`sliding_prefill_rows`), the step writes one row in place and reads
+  the ring (:func:`sliding_decode_attention`);
+* **recurrent** (``DecodeSpec.recurrent``): one fixed-size state a slot,
+  ``rows`` rows that the step replaces whole (``JambaLM``'s Mamba layers: a
+  16 x 5,120 float32 scan state and the convolution's last 3 inputs).  The
+  prefill hands the state after the prompt and it replaces the slot's whole
+  (:func:`set_slot_state`); the step reads and replaces the live slots'.
+
+A kind's store may have a type of its own (``DecodeSpec.dtypes``: a float32
+scan state beside bfloat16 pages).  What is paged counts a position
+(:meth:`PagedKVCache.bytes_per_position`), what is stored a slot
+(:meth:`PagedKVCache.bytes_per_slot`, the rings and the recurrent states).
 
 Layout::
 
     pools[name] : num_layers arrays [num_pages, page_size, pool_width(row_width)]
-                  (a sliding kind: [num_slots + 1, window, pool_width(row_width)];
+                  (a sliding kind: [num_slots + 1, window, pool_width(row_width)],
+                  a recurrent one [num_slots + 1, rows, pool_width(row_width)];
                   None in a layer that does not keep the kind)
     tables      : [num_slots, pages_per_slot] int32 (host, numpy)
 
@@ -99,7 +111,7 @@ import numpy as np
 __all__ = ["PagedKVCache", "append_rows", "rollback_rows",
            "paged_decode_attention", "paged_latent_attention",
            "sliding_decode_attention", "sliding_prefill_rows",
-           "decode_block_pages", "pool_width", "fit_rows"]
+           "set_slot_state", "decode_block_pages", "pool_width", "fit_rows"]
 
 
 # ------------------------------------------------------- traced pool writes
@@ -341,6 +353,15 @@ def sliding_prefill_rows(store, slot, rows, length):
     return store.at[slot, at % window].set(fit_rows(kept, store))
 
 
+def set_slot_state(store, slot, state):
+    """Replace ``slot``'s whole state in one recurrent kind's ``store
+    [slots + 1, rows, width]`` by ``state [rows, ...]`` (the prefill's hand-
+    over at admission): nothing of the request that held the slot before
+    survives."""
+    return store.at[slot].set(fit_rows(state.reshape(store.shape[1], -1),
+                                       store))
+
+
 def sliding_decode_attention(kstore, vstore, pos, live, q, k, v, sinks,
                              scale):
     """The width-1 step of one **sliding-window** layer over all slots: the
@@ -450,14 +471,19 @@ class PagedKVCache:
 
     ``layer_kinds`` (``DecodeSpec.layer_kinds``) names the kinds each layer
     keeps; a layer's entry of a kind it does not keep is None (default:
-    every layer keeps every kind).  A kind named in ``sliding`` (``(name,
-    window)`` pairs) keeps a slot's last ``window`` positions alone, in a
-    **store** ``[num_slots + 1, window, row_width]`` a layer instead of
-    pages: a ring indexed by slot and ``position % window``, whose last row
-    is a scratch slot for the slots that take no part in a step.  It is the
-    slot's from its admission (the prefill writes all ``window`` rows:
-    :func:`sliding_prefill_rows`) to its retirement, so nothing of it is
-    allocated or freed by the tables.
+    every layer keeps every kind).  A kind is paged unless it is named in
+    ``sliding`` or ``recurrent``.  A **sliding** kind (``(name, window)``
+    pairs) keeps a slot's last ``window`` positions alone, in a **store**
+    ``[num_slots + 1, window, row_width]`` a layer instead of pages: a ring
+    indexed by slot and ``position % window``, whose last row is a scratch
+    slot for the slots that take no part in a step.  A **recurrent** kind
+    (``(name, rows)`` pairs) keeps one state a slot, a store ``[num_slots +
+    1, rows, row_width]`` with the same scratch slot.  Either is the slot's
+    from its admission (the prefill writes all of it:
+    :func:`sliding_prefill_rows`, :func:`set_slot_state`) to its retirement,
+    so nothing of it is allocated or freed by the tables.  ``dtypes``
+    (``(name, dtype)`` pairs) gives a kind a type of its own; the others
+    take ``dtype``.
 
     ``pages_per_slot`` rows of the table bound each slot's context to
     ``pages_per_slot * page_size`` tokens; ``num_pages`` bounds the fleet of
@@ -467,7 +493,8 @@ class PagedKVCache:
 
     def __init__(self, *, num_layers, num_slots, page_size, pages_per_slot,
                  state=None, heads=None, head_dim=None, num_pages=None,
-                 dtype=jnp.float32, layer_kinds=None, sliding=()):
+                 dtype=jnp.float32, layer_kinds=None, sliding=(),
+                 recurrent=(), dtypes=()):
         if page_size < 1 or pages_per_slot < 1 or num_slots < 1:
             raise ValueError("page_size, pages_per_slot, num_slots must be >= 1")
         if state is None:
@@ -479,27 +506,33 @@ class PagedKVCache:
         self.page_size = int(page_size)
         self.pages_per_slot = int(pages_per_slot)
         self.sliding = {str(name): int(window) for name, window in sliding}
+        self.recurrent = {str(name): int(rows) for name, rows in recurrent}
         names = tuple(name for name, _ in self.state)
         self.layer_kinds = (tuple(tuple(kinds) for kinds in layer_kinds)
                             if layer_kinds is not None
                             else (names,) * self.num_layers)
-        if (len(self.layer_kinds) != self.num_layers or not set(
-                self.sliding) | {k for kinds in self.layer_kinds
-                                 for k in kinds} <= set(names)):
-            raise ValueError(f"layer_kinds {self.layer_kinds} and sliding "
-                             f"{sliding} name kinds of {names}, a layer each")
+        if (len(self.layer_kinds) != self.num_layers
+                or set(self.sliding) & set(self.recurrent)
+                or not set(self.sliding) | set(self.recurrent) | {
+                    k for kinds in self.layer_kinds for k in kinds} <= set(
+                        names)):
+            raise ValueError(f"layer_kinds {self.layer_kinds}, sliding "
+                             f"{sliding} and recurrent {recurrent} name kinds "
+                             f"of {names}, a layer each and one class a kind")
         if num_pages is None:
             num_pages = num_slots * pages_per_slot + 1  # +1 scratch
         if num_pages < 2:
             raise ValueError("need at least one real page beyond scratch")
         self.num_pages = int(num_pages)
         self.dtype = jnp.dtype(dtype)
+        self.dtypes = {name: jnp.dtype(dict(dtypes).get(name, dtype))
+                       for name in names}
 
         def store(name, width):
-            rows = ((self.num_slots + 1, self.sliding[name])
-                    if name in self.sliding
+            per_slot = self._per_slot_rows(name)
+            rows = ((self.num_slots + 1, per_slot) if per_slot
                     else (self.num_pages, self.page_size))
-            return jnp.zeros(rows + (pool_width(width),), dtype)
+            return jnp.zeros(rows + (pool_width(width),), self.dtypes[name])
 
         self.pools = {
             name: tuple(store(name, width) if name in kinds else None
@@ -523,20 +556,31 @@ class PagedKVCache:
     def _layers_keeping(self, name) -> int:
         return sum(name in kinds for kinds in self.layer_kinds)
 
+    def _per_slot_rows(self, name) -> int:
+        """Rows a slot keeps of a sliding or recurrent kind; 0 for a paged
+        one."""
+        return self.sliding.get(name, self.recurrent.get(name, 0))
+
+    def _bytes(self, name, width, rows) -> int:
+        return (width * self.dtypes[name].itemsize * rows
+                * self._layers_keeping(name))
+
     def bytes_per_position(self) -> int:
         """Bytes of paged state the block keeps a position, over all layers
-        and paged kinds: its declared rows in the pools' type (a pool's lane
+        and paged kinds: its declared rows in each pool's type (a pool's lane
         padding, :func:`pool_width`, is not the block's)."""
-        return sum(width * self.dtype.itemsize * self._layers_keeping(name)
-                   for name, width in self.state if name not in self.sliding)
+        return sum(self._bytes(name, width, 1) for name, width in self.state
+                   if not self._per_slot_rows(name))
 
-    def bytes_per_slot(self) -> int:
+    def bytes_per_slot(self, of=None) -> int:
         """Bytes of fixed state the block keeps a slot, whatever its length:
-        the sliding kinds' ``window`` declared rows over the layers that keep
-        them, in the stores' type."""
-        return sum(width * self.dtype.itemsize * self.sliding[name]
-                   * self._layers_keeping(name)
-                   for name, width in self.state if name in self.sliding)
+        the declared rows of the sliding kinds' rings and of the recurrent
+        kinds' states over the layers that keep them, each in its store's
+        type.  ``of``: ``"sliding"`` or ``"recurrent"`` alone."""
+        kinds = {None: {**self.sliding, **self.recurrent},
+                 "sliding": self.sliding, "recurrent": self.recurrent}[of]
+        return sum(self._bytes(name, width, kinds[name])
+                   for name, width in self.state if name in kinds)
 
     def pages_needed(self, length: int) -> int:
         """Pages required to hold ``length`` tokens of context."""

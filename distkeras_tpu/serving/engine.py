@@ -17,9 +17,11 @@ continuous-batching formulation (Orca/vLLM):
   names no submodule and holds no attention arithmetic of any block.
   ``TransformerLM`` and ``StagedLM`` (two pools a layer, keys and values),
   ``LatentMoELM`` (one latent pool a layer), ``ShortcutMoELM`` (two latent
-  pools a double layer) and ``WindowMoELM`` (paged keys and values in its
+  pools a double layer), ``WindowMoELM`` (paged keys and values in its
   global layers, a ring of the window's rows a slot in its sliding ones)
-  are served by the same loop and the same programs' builders;
+  and ``JambaLM`` (paged keys and values in its attention layers, a
+  recurrent state a slot in its Mamba layers) are served by the same loop
+  and the same programs' builders;
 * ONE jitted single-token **decode step** over all slots — every
   per-request quantity (position, last token, RNG key,
   temperature/top-k/top-p, active flag, speculative opt-in) is *data*, so
@@ -38,7 +40,10 @@ continuous-batching formulation (Orca/vLLM):
   A kind that a window bounds (``DecodeSpec.sliding``) lives in a ring of
   ``window`` rows a slot instead, set up whole by the admission's prefill
   and read whole by the step
-  (:func:`~distkeras_tpu.serving.cache.sliding_decode_attention`);
+  (:func:`~distkeras_tpu.serving.cache.sliding_decode_attention`); a
+  recurrent kind (``DecodeSpec.recurrent``) in one state a slot, replaced
+  whole by the admission's prefill and advanced by the step, each store in
+  its own type (``DecodeSpec.dtypes``);
 * between decode steps the host loop **admits** queued requests into free
   slots (prefill) and **retires** finished ones (EOS / max-new-tokens), so
   a long request never convoys short ones;
@@ -125,6 +130,7 @@ from distkeras_tpu.serving.cache import (
     decode_block_pages,
     fit_rows,
     rollback_rows,
+    set_slot_state,
     sliding_prefill_rows,
 )
 from distkeras_tpu.serving.frontend import (
@@ -251,7 +257,8 @@ def serving_metrics(registry=None) -> dict:
             "serving_state_per_slot_bytes",
             help="bytes of fixed state one slot keeps whatever its length: "
                  "the rows of the kinds that a window bounds (sliding-window "
-                 "layers), over the layers that keep them",
+                 "layers) and of the recurrent states (state-space layers), "
+                 "over the layers that keep them, each in its store's type",
         ),
         # the loop thread's account of its own time: one observation a span
         # of the same name in the flight-recorder ring (serving.loop*)
@@ -325,7 +332,8 @@ def _resolve_spec(model, params):
     if hook is None:
         raise TypeError(
             f"{type(model).__name__} has no decode_spec hook; serving "
-            "supports TransformerLM, StagedLM and LatentMoELM"
+            "supports TransformerLM, StagedLM, LatentMoELM, ShortcutMoELM, "
+            "WindowMoELM and JambaLM"
         )
     if params is None:
         raise ValueError(
@@ -502,8 +510,8 @@ class ServingEngine:
     power-of-two), ``draft_model``/``draft_params``/``spec_tokens``
     (speculative decoding), ``mesh`` (a 1-D tensor-parallel
     ``jax.sharding.Mesh``; ``heads`` must divide by its size).  The last two
-    need the block's ``window`` and ``shard``; ``LatentMoELM`` brings
-    neither yet, and the constructor says so.
+    need the block's ``window`` and ``shard``; the expert blocks and
+    ``JambaLM`` bring neither yet, and the constructor says so.
 
     The loop thread accounts for all of its own time, whether or not
     telemetry is on: every pass is a ``serving.loop`` span in the
@@ -527,8 +535,9 @@ class ServingEngine:
     few small arrays back beside the tokens, read one program behind with
     them.  ``serving_state_per_position_bytes`` is the declared paged state's
     size for any block, ``serving_state_per_slot_bytes`` the fixed state a
-    slot keeps whatever its length (the rings of sliding-window layers);
-    ``stats()`` has both.
+    slot keeps whatever its length (the rings of sliding-window layers and
+    the recurrent states of state-space layers); ``stats()`` has both, and
+    the per-slot bytes of the rings and the recurrent states apart.
     """
 
     def __init__(self, model, params=None, *, num_slots: int = 4,
@@ -549,12 +558,13 @@ class ServingEngine:
                     f"got axes {mesh.axis_names}"
                 )
             self._tp_axis = mesh.axis_names[0]
-            spec = self._sharded(spec)
+            spec = self._sharded(spec, type(model).__name__)
         if draft_model is not None and spec.window is None:
             raise ValueError(
-                f"{type(model).__name__}'s block has no multi-token window "
-                "step (DecodeSpec.window), so it cannot be the target of "
-                "speculative decoding: build the engine without draft_model="
+                f"{type(model).__name__}'s block has no speculative-verify "
+                "twin (DecodeSpec.window, a step over several tokens a "
+                "slot), so it cannot be the target of speculative decoding: "
+                "build the engine without draft_model="
             )
         self._spec = spec
         if pages_per_slot is None:
@@ -565,6 +575,7 @@ class ServingEngine:
             page_size=page_size, pages_per_slot=pages_per_slot,
             state=spec.state, num_pages=num_pages, dtype=dtype,
             layer_kinds=spec.layer_kinds, sliding=spec.sliding,
+            recurrent=spec.recurrent, dtypes=spec.dtypes,
         )
         if mesh is not None:
             from jax.sharding import NamedSharding
@@ -608,11 +619,11 @@ class ServingEngine:
             if self._spec_tokens < 1:
                 raise ValueError("spec_tokens must be >= 1")
             dspec = _resolve_spec(draft_model, draft_params)
-            if dspec.sliding:
+            if dspec.sliding or dspec.recurrent:
                 raise ValueError(
-                    "the draft's block keeps sliding-window state, which its "
-                    "cache-only prefill cannot set up: pick a draft without "
-                    "(draft_model=)")
+                    "the draft's block keeps sliding-window or recurrent "
+                    "state, which its cache-only prefill cannot set up: pick "
+                    "a draft without (draft_model=)")
             if dspec.vocab_size != spec.vocab_size:
                 raise ValueError(
                     f"draft vocab {dspec.vocab_size} != target vocab "
@@ -707,11 +718,11 @@ class ServingEngine:
 
     # ------------------------------------------------------- traced programs
 
-    def _sharded(self, spec):
+    def _sharded(self, spec, model):
         """The spec's tensor-parallel twin for this engine's mesh."""
         if spec.shard is None:
             raise ValueError(
-                "this model's block has no tensor-parallel build "
+                f"{model}'s block has no tensor-parallel twin "
                 "(DecodeSpec.shard): build the engine without mesh="
             )
         return spec.shard(self._tp_axis, int(self._mesh.devices.size))
@@ -782,6 +793,7 @@ class ServingEngine:
         npages = width // ps
         counted = sample and spec.observe is not None
         windows = dict(spec.sliding)
+        recurrent = dict(spec.recurrent)
 
         def trunk(params, pools, tokens, table, live, length=None, slot=None):
             # tokens [1, width] right-padded; table [npages].
@@ -795,6 +807,10 @@ class ServingEngine:
                         # the prompt's last rows into the slot's ring
                         pools[name][li] = sliding_prefill_rows(
                             pool, slot, rows, length)
+                        return
+                    if name in recurrent:
+                        # the state after the prompt replaces the slot's
+                        pools[name][li] = set_slot_state(pool, slot, rows)
                         return
                     # the whole padded chunk into this slot's pages; rows
                     # past the prompt land on scratch/overwritten pages
@@ -1039,6 +1055,10 @@ class ServingEngine:
             "state_per_position_bytes": float(
                 self._cache.bytes_per_position()),
             "state_per_slot_bytes": float(self._cache.bytes_per_slot()),
+            "state_per_slot_sliding_bytes": float(
+                self._cache.bytes_per_slot("sliding")),
+            "state_per_slot_recurrent_bytes": float(
+                self._cache.bytes_per_slot("recurrent")),
         }
 
     @property
@@ -1143,7 +1163,7 @@ class ServingEngine:
     def _hot_swap(self, model, params, timeout: float) -> None:
         new = _resolve_spec(model, params)
         if self._mesh is not None:
-            new = self._sharded(new)
+            new = self._sharded(new, type(model).__name__)
         with self._cv:
             old = self._spec
         if new.geometry != old.geometry or new.layout != old.layout:

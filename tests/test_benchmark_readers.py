@@ -163,7 +163,7 @@ def test_every_reader_without_a_test_of_its_own_is_here(harness):
     (``test_benchmark_decode_kv_reader.py``,
     ``test_benchmark_decode_chained_reader.py``,
     ``test_benchmark_mla_moe.py``, ``test_benchmark_scmoe.py``,
-    ``test_benchmark_window_moe.py``,
+    ``test_benchmark_window_moe.py``, ``test_benchmark_jamba.py``,
     ``test_benchmark_serve_loop_reader.py``)."""
     elsewhere = {"gather_ms", "h2d_ms", "dispatch_ms", "host_slack_ms",
                  "feed_gap_ms", "gap_unattributed_share", "decode_kv_read_share",
@@ -174,7 +174,8 @@ def test_every_reader_without_a_test_of_its_own_is_here(harness):
                  "loop_wait_share", "step_dispatch_ms", "emit_ms",
                  "queue_wait_ms", "dispatch_starved_share", "gc_pause_share",
                  "feed_wait_ms", "sliding_kv_read_share",
-                 "prefill_attn_pairs_share"}
+                 "prefill_attn_pairs_share", "ssm_step_roofline",
+                 "ssm_scan_time_share"}
     names = {m["name"] for m in harness.load_manifest()["per_layer"]}
     assert names == set(ANSWERS) | elsewhere
 

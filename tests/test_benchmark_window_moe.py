@@ -116,9 +116,13 @@ def test_the_manifest_lists_the_cell_under_what_it_reports(harness):
         "moe_held_share", "moe_load_max_over_mean", "state_bytes_per_position",
         "moe_tiles_per_expert", "sliding_kv_read_share",
         "prefill_attn_pairs_share"}
+    # appended behind every entry before them (later PRs append behind)
     names = [m["name"] for m in manifest["per_layer"]]
-    assert names[-2:] == ["sliding_kv_read_share", "prefill_attn_pairs_share"]
-    for metric in manifest["per_layer"][-2:]:
+    at = names.index("sliding_kv_read_share")
+    assert names[at:at + 2] == ["sliding_kv_read_share",
+                                "prefill_attn_pairs_share"]
+    assert at == names.index("feed_wait_ms") + 1
+    for metric in manifest["per_layer"][at:at + 2]:
         assert metric["workloads"] == [CELL]
         assert (metric["unit"], metric["better"], metric["source"],
                 metric["moves"]) == ("%", "lower", "program_counter",
@@ -126,8 +130,8 @@ def test_the_manifest_lists_the_cell_under_what_it_reports(harness):
     cell = next(w for w in manifest["workloads"] if w["name"] == CELL)
     assert (cell["chips"], cell["config"], cell["traffic"]) == (
         1, "mimo_v2_5", "serve_closed_longdoc")
-    assert manifest["workloads"][-1] is cell  # appended
-    assert manifest["configs"][-1]["name"] == "mimo_v2_5"
+    assert manifest["workloads"].index(cell) == 5  # appended behind five
+    assert [c["name"] for c in manifest["configs"]].index("mimo_v2_5") == 4
 
 
 def test_the_configuration_is_the_published_one_cut_as_it_says(harness):

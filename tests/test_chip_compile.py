@@ -394,6 +394,9 @@ def _reads_the_pools_as_they_lie(text, pools, slots, widths):
     # the MiMo cell's global layer: 32 slots of 832 pages, 4 key-value
     # heads of 192 / 128 serving 16 query heads each, bfloat16 pools
     (32, 832, (768, 512), 64, 192, jnp.bfloat16),
+    # the Jamba cell's attention layer: 256 slots of 160 pages, one
+    # key-value head of 128 serving 20 query heads (a group padded to 24)
+    (256, 160, (128, 128), 20, 128, jnp.bfloat16),
 ])
 def test_the_paged_read_is_one_kernel_over_the_pools_as_they_lie(
         one_chip, slots, pages, widths, heads, head_dim, dtype):
@@ -449,3 +452,39 @@ def test_the_paged_read_is_one_kernel_under_the_tensor_parallel_mesh(topo):
         rows, rows, rows)
     _reads_the_pools_as_they_lie(text, ["f32[1537,16,384]"], 24, (384,))
 
+
+
+# --------------------------- the Mamba layers' selective scan: two kernels
+
+
+def test_the_selective_scan_kernels_compile_at_the_cells_widths(one_chip):
+    """``ssm_scan`` over the cell's widest prefill chunk (512 positions of
+    5,120 channels, a state of 16) and ``ssm_step`` over its 256 slots of a
+    store of 257 rows (the last the engine's scratch slot), lowered for the
+    chip: each one kernel under its own name, the step's store written where
+    it lies (aliased to the output, donated through the program, never
+    copied)."""
+    import re
+
+    from distkeras_tpu.ops.pallas import ssm_scan, ssm_step
+
+    struct = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+    rows, width, n, slots = 512, 5120, 16, 256
+    scan = jax.jit(ssm_scan).lower(
+        struct((rows, width)), struct((rows, width)), struct((rows, n)),
+        struct((rows, n)), struct((n, width)), struct((width,))
+    ).compile().as_text()
+    assert scan.count('custom_call_target="tpu_custom_call"') == 1
+    assert re.search(r"%ssm_scan[.\d]* = ", scan)
+    step = jax.jit(ssm_step, donate_argnums=0).lower(
+        struct((slots + 1, n, width)), struct((slots, width)),
+        struct((slots, width)), struct((slots, n)), struct((slots, n)),
+        struct((n, width)), struct((width,)), struct((slots,), jnp.bool_)
+    ).compile().as_text()
+    assert step.count('custom_call_target="tpu_custom_call"') == 1
+    assert re.search(r"%ssm_step[.\d]* = ", step)
+    assert "input_output_alias={ {0}: (0, {}, may-alias)" in step
+    store = "f32[%d,%d,%d]" % (slots + 1, n, width)
+    assert not [line for line in step.splitlines()
+                if " copy(" in line and store in line]

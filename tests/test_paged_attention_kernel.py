@@ -5,7 +5,9 @@ semaphores simulated) after the step's rows are written, as
 that function's own gathered read (the path the CPU takes) and against a
 dense float32 reference written here, for multi-head float32 pools in
 GPT-2's layout and grouped-query bfloat16 pools whose keys and values
-differ in width; and which shapes the compiled kernel is chosen for."""
+differ in width, and for 20 query heads over one key-value head (a group
+that the kernel pads to whole tiles of rows); and which shapes the compiled
+kernel is chosen for."""
 
 import math
 
@@ -44,6 +46,9 @@ GEOMETRIES = {
     "multi_head_float32": (2, 1, 64, 64, jnp.float32, 1e-5),
     # the global layers' layout at a small size: keys 2 x 192, values 2 x 128
     "grouped_query_bfloat16": (2, 4, 192, 128, jnp.bfloat16, 5e-3),
+    # the Jamba attention layers' layout: 20 query heads over one key-value
+    # head of 128, a group the kernel pads to 24 rows
+    "twenty_over_one_bfloat16": (1, 20, 128, 128, jnp.bfloat16, 5e-3),
 }
 
 
@@ -164,10 +169,12 @@ def _shapes(pages, width, v_width, heads, head_dim, dtype, page=16):
     # a page that is not whole tiles of rows of its type
     (_shapes(41, 768, 768, 12, 64, jnp.bfloat16, page=8), False),
     (_shapes(41, 768, 768, 12, 64, jnp.float32, page=4), False),
-    # 4 query heads a key-value head: not whole tiles of the output's rows
-    (_shapes(41, 384, 256, 8, 192, jnp.bfloat16), False),
+    # 4 query heads a key-value head: the kernel pads the group to 8 rows
+    (_shapes(41, 384, 256, 8, 192, jnp.bfloat16), True),
     # a type the kernel does not take
     (_shapes(41, 768, 768, 12, 64, jnp.float16), False),
+    # the Jamba cell's attention layers: 20 query heads over one of 128
+    (_shapes(40961, 128, 128, 20, 128, jnp.bfloat16), True),
 ])
 def test_the_kernel_is_chosen_from_the_shapes_alone(shapes, takes):
     assert paged_attention_fits(*shapes) is takes
